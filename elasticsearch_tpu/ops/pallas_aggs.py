@@ -203,21 +203,15 @@ def segment_aggregate(
     out_shape = [jax.ShapeDtypeStruct((LANE, o_sub), jnp.float32)
                  for _ in range(n_outs)]
 
-    kwargs = {}
-    try:
-        params = pltpu.CompilerParams(dimension_semantics=("arbitrary",))
-        if not interpret:
-            kwargs["compiler_params"] = params
-    except (TypeError, AttributeError):
-        pass
     out = pl.pallas_call(
         _make_segsum_kernel(o_sub, with_sum, with_count),
         grid=(n_chunks,),
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=tuple(out_shape),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
-        **kwargs,
     )(*operands)
 
     # accT[lo, hi] -> flat [o_pad] -> [n_ords]

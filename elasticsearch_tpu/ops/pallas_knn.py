@@ -16,7 +16,7 @@ the serving machinery (micro-batching, plane ladder, quarantine):
   f32 layout — bf16 storage is the codec), converts it to f32 in VMEM
   and contracts it against the whole query batch on the MXU:
 
-      scoresT[W, Q] = emb_tile[W, d_pad] . qvecs[Q, d_pad]^T
+      scores[Q, W] = qvecs[Q, d_pad] . emb_tile[W, d_pad]^T
 
   ONE corpus stream serves all Q queries of the batch — exactly the
   cross-query amortization the MicroBatcher exists for (``q_batch`` is
@@ -32,8 +32,9 @@ the serving machinery (micro-batching, plane ladder, quarantine):
   uses; the [n_tiles * K] candidate pools merge with one tiny
   ``lax.top_k`` per query. The dense score matrix never reaches HBM.
 - Live/tombstone masking rides a staged ``[nd_pad, 1]`` f32 mask column
-  (live AND has-vector): dead docs score -inf before the top-k, so
-  deletes are honored without touching the embedding staging.
+  (live AND has-vector), streamed lane-major as ``(1, W)`` rows: dead
+  docs score -inf before the top-k, so deletes are honored without
+  touching the embedding staging.
 - The matmul runs ``Precision.HIGHEST``: the recall@10 == 1.0 gate vs
   the exact f32 numpy oracle is the bench's acceptance bar, and the
   default single-pass bf16 MXU rounding (~2^-8 relative) can reorder
@@ -63,7 +64,7 @@ LANE = 128
 NEG_INF = float("-inf")
 
 # default tile = 8192 docs: the [W, d_pad] f32-converted block must live
-# in VMEM next to the bf16 copy and the [W, Q] score slab; at d=128 that
+# in VMEM next to the bf16 copy and the [Q, W] score slab; at d=128 that
 # is ~6.3 MB — comfortably under the ~16 MB/core budget while keeping
 # the per-grid-step fixed cost (which dominates the BM25 kernel too)
 # amortized over big tiles
@@ -152,51 +153,48 @@ def _make_knn_kernel(sub: int, d_pad: int, k: int, q_batch: int):
     they shaped the BM25 kernel (see ops/pallas_scoring._make_kernel):
     every scalar literal is an explicit int32/float32 (weak python
     scalars trace to i64/f64 under the engine's x64 mode and crash the
-    TPU compile), the top-k builds whole (k, Q) blocks with masked
-    selects instead of scalar stores, and the score slab keeps docs on
-    the SUBLANE axis so the live-mask column broadcasts along lanes."""
+    TPU compile), and the top-k builds whole (Q, k) blocks with masked
+    selects instead of scalar stores. The score slab is [Q, W] — docs on
+    the LANE axis — so the per-doc scale and live-mask rows arrive
+    lane-major as (1, W) blocks and broadcast along sublanes. A (W, 1)
+    column block would pad every doc to a full 128-lane row in VMEM
+    (4 MB per column per buffer at W = 8192), which the v5e compiler
+    refuses at the default tile."""
     w = sub * LANE
 
     def kernel(emb_ref, scale_ref, mask_ref, q_ref, out_s_ref, out_d_ref):
         t = pl.program_id(0)
         base = jnp.int32(t) * jnp.int32(w)
         # [W, d_pad] bf16 -> f32 in VMEM, then ONE MXU contraction for
-        # the whole query batch: scoresT[W, Q]. HIGHEST precision — see
+        # the whole query batch: scores[Q, W]. HIGHEST precision — see
         # module docstring (the recall gate is the acceptance bar).
         emb = emb_ref[...].astype(jnp.float32)
-        sT = lax.dot_general(
-            emb, q_ref[...], (((1,), (1,)), ((), ())),
+        s = lax.dot_general(
+            q_ref[...], emb, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
             precision=lax.Precision.HIGHEST)
-        # metric scale column (cosine: 1/|x|; dot: ones) + the reference
-        # affine rescale (1 + sim) / 2 — [W, 1] broadcasts over Q
-        sT = sT * scale_ref[...] * jnp.float32(0.5) + jnp.float32(0.5)
-        live = mask_ref[...] > jnp.float32(0.0)  # [W, 1]
+        # metric scale row (cosine: 1/|x|; dot: ones) + the reference
+        # affine rescale (1 + sim) / 2 — [1, W] broadcasts over Q
+        s = s * scale_ref[...] * jnp.float32(0.5) + jnp.float32(0.5)
+        live = mask_ref[...] > jnp.float32(0.0)  # [1, W]
         ninf = jnp.float32(NEG_INF)
-        masked = jnp.where(live, sT, ninf)  # [W, Q]
-        lin = lax.broadcasted_iota(jnp.int32, (w, q_batch), 0)
-        outv_s = jnp.full((k, q_batch), NEG_INF, jnp.float32)
-        outv_d = jnp.full((k, q_batch), -1, jnp.int32)
-        k_iota = lax.broadcasted_iota(jnp.int32, (k, q_batch), 0)
+        masked = jnp.where(live, s, ninf)  # [Q, W]
+        lin = lax.broadcasted_iota(jnp.int32, (q_batch, w), 1)
+        outv_s = jnp.full((q_batch, k), NEG_INF, jnp.float32)
+        outv_d = jnp.full((q_batch, k), -1, jnp.int32)
+        k_iota = lax.broadcasted_iota(jnp.int32, (q_batch, k), 1)
         for i in range(k):
-            mx = jnp.max(masked, axis=0, keepdims=True)  # [1, Q]
+            mx = jnp.max(masked, axis=1, keepdims=True)  # [Q, 1]
             sel = jnp.where(masked == mx, lin, jnp.int32(w))
-            idx = jnp.min(sel, axis=0, keepdims=True)  # [1, Q]
+            idx = jnp.min(sel, axis=1, keepdims=True)  # [Q, 1]
             outv_s = jnp.where(k_iota == jnp.int32(i), mx, outv_s)
             doc = jnp.where(mx == ninf, jnp.int32(-1), base + idx)
             outv_d = jnp.where(k_iota == jnp.int32(i), doc, outv_d)
             masked = jnp.where(lin == idx, ninf, masked)
-        out_s_ref[...] = outv_s.reshape(1, k, q_batch)
-        out_d_ref[...] = outv_d.reshape(1, k, q_batch)
+        out_s_ref[...] = outv_s.reshape(1, q_batch, k)
+        out_d_ref[...] = outv_d.reshape(1, q_batch, k)
 
     return kernel
-
-
-def _compiler_params():
-    try:
-        return pltpu.CompilerParams(dimension_semantics=("arbitrary",))
-    except (TypeError, AttributeError):  # older/newer API drift
-        return None
 
 
 @functools.partial(
@@ -216,8 +214,8 @@ def knn_score_tiles(
 ):
     """Run the MXU kNN kernel over a staged embedding matrix.
 
-    Returns (tile_scores [n_tiles, k, q_batch] f32, tile_docs
-    [n_tiles, k, q_batch] i32, -1 = empty) — per-tile fused top-k
+    Returns (tile_scores [n_tiles, q_batch, k] f32, tile_docs
+    [n_tiles, q_batch, k] i32, -1 = empty) — per-tile fused top-k
     candidates, merged per query by ``merge_knn_topk``. The match TOTAL
     (live docs carrying a vector) is metric- and query-independent, so
     callers count it from the mask column instead of a kernel output.
@@ -238,42 +236,38 @@ def knn_score_tiles(
 
     in_specs = [
         pl.BlockSpec((w, d_pad), lambda t: (t, zero())),
-        pl.BlockSpec((w, 1), lambda t: (t, zero())),
-        pl.BlockSpec((w, 1), lambda t: (t, zero())),
+        pl.BlockSpec((1, w), lambda t: (zero(), t)),
+        pl.BlockSpec((1, w), lambda t: (zero(), t)),
         pl.BlockSpec((q_batch, d_pad), lambda t: (zero(), zero())),
     ]
     out_specs = [
-        pl.BlockSpec((1, k, q_batch), lambda t: (t, zero(), zero())),
-        pl.BlockSpec((1, k, q_batch), lambda t: (t, zero(), zero())),
+        pl.BlockSpec((1, q_batch, k), lambda t: (t, zero(), zero())),
+        pl.BlockSpec((1, q_batch, k), lambda t: (t, zero(), zero())),
     ]
     out_shape = [
-        jax.ShapeDtypeStruct((n_tiles, k, q_batch), jnp.float32),
-        jax.ShapeDtypeStruct((n_tiles, k, q_batch), jnp.int32),
+        jax.ShapeDtypeStruct((n_tiles, q_batch, k), jnp.float32),
+        jax.ShapeDtypeStruct((n_tiles, q_batch, k), jnp.int32),
     ]
-    kernel = _make_knn_kernel(sub, d_pad, k, q_batch)
-    kwargs = {}
-    params = _compiler_params()
-    if params is not None and not interpret:
-        kwargs["compiler_params"] = params
     return pl.pallas_call(
-        kernel,
+        _make_knn_kernel(sub, d_pad, k, q_batch),
         grid=(n_tiles,),
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=tuple(out_shape),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
-        **kwargs,
-    )(emb, scale, mask, qvecs)
+    )(emb, scale.reshape(1, nd_pad), mask.reshape(1, nd_pad), qvecs)
 
 
 @functools.partial(jax.jit, static_argnames=("k",))
 def merge_knn_topk(tile_scores, tile_docs, k: int):
     """Merge per-tile candidates per query: tile_scores/tile_docs are
-    [n_tiles, kk, Q]; returns (top_s [Q, k'], top_d [Q, k'] i32) with
+    [n_tiles, Q, kk]; returns (top_s [Q, k'], top_d [Q, k'] i32) with
     k' = min(k, n_tiles * kk)."""
-    n_tiles, kk, q = tile_scores.shape
-    pool_s = tile_scores.transpose(2, 0, 1).reshape(q, -1)
-    pool_d = tile_docs.transpose(2, 0, 1).reshape(q, -1)
+    n_tiles, q, kk = tile_scores.shape
+    pool_s = tile_scores.transpose(1, 0, 2).reshape(q, -1)
+    pool_d = tile_docs.transpose(1, 0, 2).reshape(q, -1)
     k2 = min(int(k), pool_s.shape[1])
     top_s, top_i = lax.top_k(pool_s, k2)
     top_d = jnp.take_along_axis(pool_d, top_i, axis=1)

@@ -856,13 +856,6 @@ def _make_kernel(t_pad: int, cb: int, sub: int, k: int, dense: bool,
     return kernel
 
 
-def _compiler_params():
-    try:
-        return pltpu.CompilerParams(dimension_semantics=("arbitrary",))
-    except (TypeError, AttributeError):  # older/newer API drift
-        return None
-
-
 @functools.partial(
     jax.jit,
     static_argnames=("t_pad", "cb", "sub", "k", "dense", "with_counts",
@@ -1057,18 +1050,15 @@ def score_tiles(
     )
     kernel = _make_kernel(t_pad, cb, sub, k, dense, with_counts, tps,
                           q_batch, codec, with_sel)
-    kwargs = {}
-    params = _compiler_params()
-    if params is not None and not interpret:
-        kwargs["compiler_params"] = params
     prefetch = ((row_lo, row_hi, jnp.asarray(tile_ids, jnp.int32))
                 if with_sel else (row_lo, row_hi))
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=tuple(out_shape),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
-        **kwargs,
     )(*prefetch, *operands)
     return out
 

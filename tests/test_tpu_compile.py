@@ -1,0 +1,111 @@
+"""Compile the served path's kernels for a described (not attached) v5e.
+
+Interpret mode cannot see what the TPU compiler refuses: VMEM budgets,
+tiling alignment, mosaic legalization. These cases lower and compile the
+kernels at the 1M-doc geometry the chip serves, against a ``v5e:2x2``
+topology description, so a refusal costs a test failure here instead of
+a chip call. Nothing runs: a compile that passes is not a chip run.
+
+The topology is described inside a module-scoped fixture (never at
+import: only one process may load libtpu, and every xdist worker imports
+every test file).
+"""
+
+import os
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import SingleDeviceSharding
+
+from elasticsearch_tpu.ops import pallas_aggs as pag
+from elasticsearch_tpu.ops import pallas_knn as pkn
+from elasticsearch_tpu.ops import pallas_scoring as psc
+
+ND_PAD = 1 << 20
+# 1M docs x ~80 tokens / 128 postings per block, rounded up
+N_BLOCKS = 1 << 19
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # whatever the plugin raises when it cannot
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+SCORE_TILES_CASES = {
+    "raw_q1": dict(),
+    "raw_q8": dict(q_batch=8),
+    "packed_q1": dict(codec="packed"),
+    "dense_counts": dict(dense=True, with_counts=True),
+    "raw_tps2": dict(tiles_per_step=2),
+    "tile_ids": dict(n_sel=16),
+}
+
+
+@pytest.mark.parametrize("case", sorted(SCORE_TILES_CASES))
+def test_score_tiles_compiles_at_1m_docs(one_chip, case):
+    kw = dict(SCORE_TILES_CASES[case])
+    n_sel = kw.pop("n_sel", None)
+    geom = psc.tile_geometry(ND_PAD)
+    t_pad, cb = 4, psc.CB_MAX // 2  # 3-term match; widest DMA window
+    q_batch = kw.get("q_batch", 1)
+    n_rows = n_sel if n_sel is not None else geom.n_tiles
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    blocks = (N_BLOCKS + psc.CB_MAX, psc.LANE)
+    docs = sds(blocks, jnp.int32)
+    frac = None if kw.get("codec") == "packed" else sds(blocks, jnp.float32)
+    args = [docs, frac,
+            sds((geom.n_tiles * psc.LANE, geom.tile_sub), jnp.float32),
+            sds((n_rows, t_pad), jnp.int32),
+            sds((n_rows, t_pad), jnp.int32),
+            sds((q_batch, t_pad), jnp.float32)]
+    if n_sel is not None:
+        kw["tile_ids"] = sds((n_sel,), jnp.int32)
+    text = psc.score_tiles.lower(
+        *args, t_pad=t_pad, cb=cb, sub=geom.tile_sub, k=10,
+        **kw).compile().as_text()
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("q_batch", [1, 8])
+@pytest.mark.parametrize("dims", [128, 768, 1536])
+def test_knn_score_tiles_compiles_at_1m_docs(one_chip, dims, q_batch):
+    d_pad = pkn.pad_dims(dims)
+    geom = pkn.knn_geometry(ND_PAD, d_pad)  # the production geometry
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    text = pkn.knn_score_tiles.lower(
+        sds((ND_PAD, d_pad), jnp.bfloat16),
+        sds((ND_PAD, 1), jnp.float32),
+        sds((ND_PAD, 1), jnp.float32),
+        sds((q_batch, d_pad), jnp.float32),
+        sub=geom.tile_sub, k=16, q_batch=q_batch).compile().as_text()
+    assert "tpu_custom_call" in text
+
+
+def test_segment_aggregate_compiles_at_1m_docs(one_chip):
+    def sds(dtype):
+        return jax.ShapeDtypeStruct((ND_PAD,), dtype, sharding=one_chip)
+
+    text = pag.segment_aggregate.lower(
+        sds(jnp.int32), sds(jnp.float32), sds(jnp.float32),
+        n_ords=2000, with_sum=True).compile().as_text()
+    assert "tpu_custom_call" in text
