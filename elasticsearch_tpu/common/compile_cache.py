@@ -57,6 +57,8 @@ _EVENT_RING = 64
 _WARMING: contextvars.ContextVar[bool] = contextvars.ContextVar(
     "es_tpu_compile_warming", default=False)
 
+# JAX's own variable: where it is set, the cache is placed from outside
+CACHE_DIR_ENV = "JAX_COMPILATION_CACHE_DIR"
 _CACHE_PATH: Optional[str] = None
 
 
@@ -76,46 +78,49 @@ def warming():
         _WARMING.reset(token)
 
 
+def checkout_cache_dir() -> str:
+    """``<checkout>/.jax_cache``: where the entry scripts that run on
+    the chip (``chip_smoke.py``, ``bench.py``) keep the cache when
+    ``JAX_COMPILATION_CACHE_DIR`` does not place it. Fixed, because the
+    path is part of the cache key: a directory that moves never hits."""
+    package = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    return os.path.join(os.path.dirname(package), ".jax_cache")
+
+
 def configure_compile_cache(path: Optional[str]) -> bool:
     """Enable JAX's persistent compilation cache at ``path``
-    (``search.compile.cache_path``). Thresholds are dropped to zero so
+    (``search.compile.cache_path``); ``None`` turns it off. Where
+    ``JAX_COMPILATION_CACHE_DIR`` is set the cache was placed from
+    outside: JAX reads the variable itself, ``path`` yields to it and
+    no directory is set in code. Thresholds are dropped to zero so
     every mesh program caches — the 2–27 s stalls this kills are
-    exactly the big-program compiles. Returns False (and stays
-    disabled) when this jax build has no persistent cache."""
+    exactly the big-program compiles. Returns whether the cache is
+    on."""
     global _CACHE_PATH
-    if not path:
-        _CACHE_PATH = None
-        try:  # also disable the XLA-side cache (bench cold leg)
-            import jax
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
 
-            jax.config.update("jax_compilation_cache_dir", None)
-        except Exception:  # noqa: BLE001 — best-effort
-            pass
-        return False
-    try:
-        import jax
-
-        os.makedirs(path, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", path)
-        for opt, val in (("jax_persistent_cache_min_compile_time_secs", 0),
-                         ("jax_persistent_cache_min_entry_size_bytes", -1)):
-            try:
-                jax.config.update(opt, val)
-            except Exception:  # noqa: BLE001 — older jax: keep defaults
-                pass
-    except Exception:  # noqa: BLE001 — no jax / no cache support
-        _CACHE_PATH = None
-        return False
-    _CACHE_PATH = path
-    return True
+    placed = os.environ.get(CACHE_DIR_ENV)
+    if not placed:
+        if path:
+            os.makedirs(path, exist_ok=True)
+        if (path or None) != jax.config.jax_compilation_cache_dir:
+            jax.config.update("jax_compilation_cache_dir", path or None)
+            # JAX opens its cache once; a new directory needs a reset
+            compilation_cache.reset_cache()
+        _CACHE_PATH = path or None
+    if compile_cache_enabled():
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    return compile_cache_enabled()
 
 
 def compile_cache_enabled() -> bool:
-    return _CACHE_PATH is not None
+    return compile_cache_path() is not None
 
 
 def compile_cache_path() -> Optional[str]:
-    return _CACHE_PATH
+    return os.environ.get(CACHE_DIR_ENV) or _CACHE_PATH
 
 
 def variant_key(family: str, *parts) -> str:
