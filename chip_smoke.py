@@ -463,12 +463,14 @@ def parse_args(argv):
 
 def main(argv=None) -> int:
     args = parse_args(argv)
+    t0 = time.perf_counter()
     try:
         devices = require_tpu(args.chips)
         say("device", platform=devices[0].platform,
             kind=devices[0].device_kind, count=len(devices))
         build_native()
         run(args, devices)
+        say("done", seconds=time.perf_counter() - t0)
     except SmokeFailure as e:
         print(f"chip_smoke FAILED: {e}", file=sys.stderr, flush=True)
         return 1

@@ -32,9 +32,8 @@ from typing import Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as PS
-
-from elasticsearch_tpu.parallel.compat import shard_map
 
 from elasticsearch_tpu.ops.scoring import B, K1, bm25_idf
 

@@ -36,9 +36,8 @@ from typing import Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as PS
-
-from elasticsearch_tpu.parallel.compat import shard_map
 
 from elasticsearch_tpu.search.plan import EmitCtx, PlanNode
 
@@ -1299,7 +1298,9 @@ class IndexMeshSearch:
             return False
         mesh = self._mesh_or_default()
         if len(pairs) > mesh.devices.size * max(self.max_slots, 1):
-            return False  # packing bound (not a one-segment-per-device cap)
+            # packing bound (not a one-segment-per-device cap)
+            self.staging_denied_reason = "slots_exceeded"
+            return False
         key = self._key_for(pairs)
         # the "or executor is None" leg self-heals any state where the
         # staged key survived but the executor didn't (an eviction
@@ -1494,9 +1495,10 @@ class IndexMeshSearch:
         the same register-then-commit rebuild as any staging."""
         pairs = self._current_pairs()
         mesh = self._mesh_or_default()
-        if (not pairs
-                or len(pairs) > mesh.devices.size * max(self.max_slots,
-                                                        1)):
+        if not pairs:
+            return False
+        if len(pairs) > mesh.devices.size * max(self.max_slots, 1):
+            self.staging_denied_reason = "slots_exceeded"
             return False
         key = self._key_for(pairs)
         with self._stage_lock:

@@ -802,6 +802,31 @@ class TestMeshPallasPlane:
         assert r["_plane"] == "host"
         idx.close()
 
+    def test_fifth_segment_on_one_device_reports_slots_exceeded(self):
+        # a default one-chip index: 1 device x 4 slots. Its fifth
+        # segment leaves the fast plane, and _stats says why.
+        from elasticsearch_tpu.common.settings import Settings
+        from elasticsearch_tpu.index.index_service import IndexService
+        from elasticsearch_tpu.parallel.mesh import shard_mesh
+        from elasticsearch_tpu.parallel.plan_exec import IndexMeshSearch
+
+        idx = IndexService("meshslots", Settings({
+            "index.number_of_shards": 2,
+            "index.search.mesh": True,
+            "index.refresh_interval": -1,
+        }), mapping=self.MAPPING)
+        idx._mesh_search = IndexMeshSearch(idx, mesh=shard_mesh(1))
+        for batch in range(3):
+            for d in range(batch * 40, batch * 40 + 40):
+                idx.index_doc(str(d), {"body": f"w{d % 5} w1"})
+            idx.refresh()  # 2 shards x 3 segments > 1 device x 4 slots
+        r = idx.search({"query": {"match": {"body": "w1"}}, "size": 5})
+        assert r["_plane"] == "host"
+        decisions = idx.search_stats()["phases"]["decisions"]
+        assert decisions["host.slots_exceeded"] == 1, decisions
+        assert "host.staging_unavailable" not in decisions
+        idx.close()
+
 
 class TestExecutionPlaneObservability:
     """VERDICT r4 weak 3: 'did we use the chip?' must be observable —
