@@ -18,6 +18,7 @@ Contracts under test (docs/RESILIENCE.md "Rollout & drain"):
   compiles after a warmed restart (compile_cache counters prove it).
 """
 
+import os
 import threading
 import time
 
@@ -371,6 +372,49 @@ class TestCompileCachePlane:
             {"kind": "search", "bodies": [{"size": 1}]}]
         reg2.forget_index("idx")
         assert cc.VariantRegistry(path).warm_entries("idx") == []
+
+    @pytest.mark.parametrize("placed", [False, True])
+    def test_cache_directory_is_placed_from_outside(self, tmp_path,
+                                                    monkeypatch, placed):
+        """Where JAX_COMPILATION_CACHE_DIR is set the setting yields to
+        it and no directory is set in code; unset, the setting places
+        the cache, and a compile lands in the chosen directory only."""
+        import jax
+        import jax.numpy as jnp
+
+        from elasticsearch_tpu.common import compile_cache as cc
+
+        outside, setting = tmp_path / "outside", tmp_path / "setting"
+        set_dirs = []
+        update = jax.config.update
+
+        def spy(name, value):
+            if name == "jax_compilation_cache_dir":
+                set_dirs.append(value)
+            update(name, value)
+
+        monkeypatch.setattr(jax.config, "update", spy)
+        if placed:
+            monkeypatch.setenv(cc.CACHE_DIR_ENV, str(outside))
+            # what JAX does with the variable when it is imported
+            update("jax_compilation_cache_dir", str(outside))
+        else:
+            monkeypatch.delenv(cc.CACHE_DIR_ENV, raising=False)
+        try:
+            assert cc.configure_compile_cache(str(setting))
+            want = outside if placed else setting
+            assert cc.compile_cache_path() == str(want)
+            jax.jit(lambda x: x * 3 + len(str(tmp_path)))(
+                jnp.arange(7)).block_until_ready()
+            assert os.listdir(want)
+            assert not (setting if placed else outside).exists()
+            # None turns the setting's cache off, never the outside one
+            assert cc.configure_compile_cache(None) is placed
+            assert set_dirs == ([] if placed else [str(setting), None])
+        finally:
+            monkeypatch.delenv(cc.CACHE_DIR_ENV, raising=False)
+            cc.configure_compile_cache(None)
+            update("jax_compilation_cache_dir", None)
 
     def test_instrument_program_counts_first_call_once(self):
         from elasticsearch_tpu.common import compile_cache as cc

@@ -109,3 +109,95 @@ def test_segment_aggregate_compiles_at_1m_docs(one_chip):
         sds(jnp.int32), sds(jnp.float32), sds(jnp.float32),
         n_ords=2000, with_sum=True).compile().as_text()
     assert "tpu_custom_call" in text
+
+
+def _for_tpu(plan):
+    """Copy of a template plan with its kernel nodes out of interpret
+    mode: what the same query traces to on a TPU backend."""
+    import copy
+
+    from elasticsearch_tpu.search.plan import PlanNode
+
+    plan = copy.copy(plan)
+    for name, val in vars(plan).items():
+        if name == "interpret":
+            setattr(plan, name, False)
+        elif isinstance(val, PlanNode):
+            setattr(plan, name, _for_tpu(val))
+        elif isinstance(val, list) and val and all(
+                isinstance(v, PlanNode) for v in val):
+            setattr(plan, name, [_for_tpu(v) for v in val])
+    return plan
+
+
+def test_serial_mesh_program_compiles_for_four_chips(topo, monkeypatch):
+    """The serial mesh program (shard_map + tile kernel + ICI merge) on a
+    Mesh of the four described devices. A scratch harness: a small index
+    is searched on four virtual CPU devices in interpret mode, the call
+    into ``_mesh_query_program`` is recorded, and the same program is
+    built again on the TPU mesh and compiled from the recorded shapes.
+    The shapes are the small index's: this finds what a mesh of real
+    chips refuses, not what 1M documents need."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as PS
+
+    from elasticsearch_tpu.common.settings import Settings
+    from elasticsearch_tpu.index.index_service import IndexService
+    from elasticsearch_tpu.parallel import plan_exec
+    from elasticsearch_tpu.parallel.mesh import shard_mesh
+
+    monkeypatch.setenv("ES_TPU_PALLAS", "interpret")
+    build_program = plan_exec._mesh_query_program
+    seen = {}
+
+    def recording(mesh, holder, *args, **kwargs):
+        program = build_program(mesh, holder, *args, **kwargs)
+
+        def call(*arrays):
+            seen.update(holder=holder, args=args, kwargs=kwargs,
+                        arrays=arrays)
+            return program(*arrays)
+
+        return call
+
+    monkeypatch.setattr(plan_exec, "_mesh_query_program", recording)
+    idx = IndexService("tpucompile", Settings({
+        "index.number_of_shards": 8,
+        "index.search.mesh": True,
+        "index.refresh_interval": -1,
+    }), mapping={"properties": {
+        "body": {"type": "text", "analyzer": "whitespace"}}})
+    idx._mesh_search = plan_exec.IndexMeshSearch(idx, mesh=shard_mesh(4))
+    rng = np.random.RandomState(5)
+    try:
+        for d in range(6000):  # ~750 docs a shard: 8-sublane tiles
+            idx.index_doc(str(d), {"body": " ".join(
+                f"w{t}" for t in rng.zipf(1.3, 12) % 200)})
+        idx.refresh()
+        resp = idx.search({"query": {"match": {"body": "w1 w2 w3"}},
+                           "size": 10})
+    finally:
+        idx.close()
+    assert resp["_plane"] == "mesh_pallas"
+
+    holder = seen["holder"]
+    tpu_mesh = Mesh(np.asarray(topo.devices), ("shards",))
+    program = build_program(
+        tpu_mesh,
+        plan_exec._TemplateHolder(_for_tpu(holder.plan),
+                                  holder._key + "|tpu"),
+        *seen["args"], **seen["kwargs"])
+    sharded = NamedSharding(tpu_mesh, PS("shards"))
+    replicated = NamedSharding(tpu_mesh, PS())
+
+    def shapes(tree, sharding):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(np.shape(a), a.dtype,
+                                           sharding=sharding), tree)
+
+    *per_slot, scalars = seen["arrays"]
+    text = program.__wrapped__.lower(
+        *(shapes(t, sharded) for t in per_slot),
+        shapes(scalars, replicated)).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert "all-gather" in text
