@@ -1493,6 +1493,7 @@ class IndexMeshSearch:
         fresh ones and releases the old generation. Off the query path
         (the owner's single-flight pass calls it); ledger-exact through
         the same register-then-commit rebuild as any staging."""
+        self.staging_denied_reason = None
         pairs = self._current_pairs()
         mesh = self._mesh_or_default()
         if not pairs:

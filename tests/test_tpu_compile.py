@@ -41,8 +41,14 @@ def topo():
 
 
 @pytest.fixture(scope="module")
-def one_chip(topo):
-    return SingleDeviceSharding(topo.devices[0])
+def sds(topo):
+    """Shape of an array that lives on the first described chip."""
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    return sds
 
 
 SCORE_TILES_CASES = {
@@ -56,17 +62,13 @@ SCORE_TILES_CASES = {
 
 
 @pytest.mark.parametrize("case", sorted(SCORE_TILES_CASES))
-def test_score_tiles_compiles_at_1m_docs(one_chip, case):
+def test_score_tiles_compiles_at_1m_docs(sds, case):
     kw = dict(SCORE_TILES_CASES[case])
     n_sel = kw.pop("n_sel", None)
     geom = psc.tile_geometry(ND_PAD)
     t_pad, cb = 4, psc.CB_MAX // 2  # 3-term match; widest DMA window
     q_batch = kw.get("q_batch", 1)
     n_rows = n_sel if n_sel is not None else geom.n_tiles
-
-    def sds(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-
     blocks = (N_BLOCKS + psc.CB_MAX, psc.LANE)
     docs = sds(blocks, jnp.int32)
     frac = None if kw.get("codec") == "packed" else sds(blocks, jnp.float32)
@@ -85,13 +87,9 @@ def test_score_tiles_compiles_at_1m_docs(one_chip, case):
 
 @pytest.mark.parametrize("q_batch", [1, 8])
 @pytest.mark.parametrize("dims", [128, 768, 1536])
-def test_knn_score_tiles_compiles_at_1m_docs(one_chip, dims, q_batch):
+def test_knn_score_tiles_compiles_at_1m_docs(sds, dims, q_batch):
     d_pad = pkn.pad_dims(dims)
     geom = pkn.knn_geometry(ND_PAD, d_pad)  # the production geometry
-
-    def sds(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-
     text = pkn.knn_score_tiles.lower(
         sds((ND_PAD, d_pad), jnp.bfloat16),
         sds((ND_PAD, 1), jnp.float32),
@@ -101,12 +99,10 @@ def test_knn_score_tiles_compiles_at_1m_docs(one_chip, dims, q_batch):
     assert "tpu_custom_call" in text
 
 
-def test_segment_aggregate_compiles_at_1m_docs(one_chip):
-    def sds(dtype):
-        return jax.ShapeDtypeStruct((ND_PAD,), dtype, sharding=one_chip)
-
+def test_segment_aggregate_compiles_at_1m_docs(sds):
     text = pag.segment_aggregate.lower(
-        sds(jnp.int32), sds(jnp.float32), sds(jnp.float32),
+        sds((ND_PAD,), jnp.int32), sds((ND_PAD,), jnp.float32),
+        sds((ND_PAD,), jnp.float32),
         n_ords=2000, with_sum=True).compile().as_text()
     assert "tpu_custom_call" in text
 
