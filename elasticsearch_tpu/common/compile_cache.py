@@ -336,6 +336,8 @@ def instrument_program(run, family: str, key: str):
 
     wrapped.__wrapped__ = run
     wrapped.variant_key = key
+    # the launch site marks its kernel.dispatch span first_call with it
+    wrapped.first_call_pending = lambda: not state["done"]
     return wrapped
 
 

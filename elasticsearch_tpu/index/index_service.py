@@ -742,6 +742,30 @@ class IndexService:
             tracer.annotate("opaque_id", oid)
         return tracer
 
+    def _request_tracer(self):
+        """The tracer of a top-level search and whether this call owns
+        it: the one the HTTP front door opened at the socket where there
+        is one (rest/http_server.py; its spans drain into this index
+        when the front door finishes it), else a new one as ``_tracer``
+        makes it (direct callers, tests), finished by ``search``."""
+        from elasticsearch_tpu.search.telemetry import (
+            NULL_TRACER,
+            get_opaque_id,
+            request_tracer,
+        )
+
+        if not self._telemetry_enabled():
+            return NULL_TRACER, False
+        tracer = request_tracer()
+        owned = not tracer.enabled
+        if owned:
+            tracer = self._tracer()
+        elif get_opaque_id():
+            tracer.annotate("opaque_id", get_opaque_id())
+        if tracer.sink is None:
+            tracer.sink = self.telemetry
+        return tracer, owned
+
     @staticmethod
     def _annotate_batch_member(item, wait_s: float, batch_size: int,
                                member_index: int) -> None:
@@ -755,6 +779,9 @@ class IndexService:
         if tracer is not None and getattr(tracer, "enabled", False):
             tracer.annotate("batch_window_wait_ms",
                             round(wait_s * 1000.0, 3))
+            now = time.monotonic_ns()
+            tracer.record("batch.window_wait",
+                          now - int(wait_s * 1e9), now)
 
     def _maybe_search_slowlog(self, took_s: float, body: dict,
                               plane: str, tracer) -> None:
@@ -773,6 +800,9 @@ class IndexService:
         """One choke point for per-query observability: drain the
         tracer into the phase histograms, attach the plane-truthful
         profile section, and emit the (mesh-plane) slowlog line."""
+        # from here to the end of search.request: this drain, the warm
+        # spec, the profile, the slowlog, the admission slot's release
+        tracer.fill("search.respond")
         self.telemetry.record_query(plane, tracer)
         # program-variant warm spec (ISSUE 14, docs/RESILIENCE.md): a
         # mesh-served query shape joins the index's recorded lattice so
@@ -784,6 +814,8 @@ class IndexService:
             prof["plane"] = plane
             prof["phases"] = tracer.spans()
             prof["annotations"] = tracer.annotations()
+            prof["request_id"] = tracer.request_id
+            prof["spans"] = tracer.span_tree()
         if plane != "host":
             self._maybe_search_slowlog(took_s, body, plane, tracer)
         return resp
@@ -1034,12 +1066,27 @@ class IndexService:
         deadline: SearchDeadline threaded from the coordinator — expiry
         degrades to partial results (timed_out: true), cancellation
         raises TaskCancelledException at the next checkpoint."""
+        tracer, owned = self._request_tracer()
+        t_request = tracer.start_parent("search.request")
+        try:
+            return self._search_traced(body, preference_shards,
+                                       pinned_segments, deadline, tracer)
+        finally:
+            tracer.stop("search.request", t_request)
+            if owned:
+                tracer.finish()
+
+    def _search_traced(self, body, preference_shards, pinned_segments,
+                       deadline, tracer) -> dict:
         from elasticsearch_tpu.index.request_cache import (
             RequestCache,
             cacheable,
             shard_epoch,
         )
 
+        # request-cache lookup, admission, brownout shaping: up to
+        # where _admitted_dispatch opens search.route
+        tracer.fill("search.admit")
         t0 = time.monotonic()
         body = body or {}
         if deadline is None and body.get("timeout") is not None:
@@ -1066,7 +1113,8 @@ class IndexService:
                     cached["took"] = int((time.monotonic() - t0) * 1000)
                     return cached
         resp = self._search_dispatch(body, preference_shards,
-                                     pinned_segments, deadline=deadline)
+                                     pinned_segments, deadline=deadline,
+                                     tracer=tracer)
         if (cache_key is not None and not resp.get("timed_out")
                 and not resp["_shards"].get("failed")
                 and not resp.get("_degraded")):
@@ -1079,7 +1127,7 @@ class IndexService:
     def _search_dispatch(self, body: dict,
                          preference_shards: Optional[List[int]] = None,
                          pinned_segments: Optional[Dict[int, list]] = None,
-                         deadline=None) -> dict:
+                         deadline=None, tracer=None) -> dict:
         """Overload-control choke point (search/admission.py, ISSUE 12):
         every top-level search acquires an admission slot here BEFORE
         any staging/launch work. Overflow raises the 429 rejection; a
@@ -1089,7 +1137,7 @@ class IndexService:
         shed rescore / shed aggs+suggest, marked ``_degraded``)."""
         from elasticsearch_tpu.search.service import expired_queue_response
 
-        token = self.admission.acquire(deadline=deadline)
+        token = self.admission.acquire(deadline=deadline, tracer=tracer)
         if token.shed_expired:
             if deadline is not None:
                 deadline.timed_out = True
@@ -1099,7 +1147,7 @@ class IndexService:
             shaped, degraded = self.admission.apply_brownout(body, token)
             resp = self._admitted_dispatch(shaped, preference_shards,
                                            pinned_segments,
-                                           deadline=deadline)
+                                           deadline=deadline, tracer=tracer)
             if degraded and isinstance(resp, dict):
                 # the degradation marker ALSO keeps the response out of
                 # the request cache (IndexService.search): a browned-out
@@ -1112,7 +1160,7 @@ class IndexService:
     def _admitted_dispatch(self, body: dict,
                            preference_shards: Optional[List[int]] = None,
                            pinned_segments: Optional[Dict[int, list]]
-                           = None, deadline=None) -> dict:
+                           = None, deadline=None, tracer=None) -> dict:
         """Route the query phase through the cross-query micro-batcher
         when eligible (search/batching.py): a concurrent burst of
         compatible queries shares one batched kernel launch; a lone query
@@ -1120,7 +1168,10 @@ class IndexService:
         from elasticsearch_tpu.search.batching import batchable_body
         from elasticsearch_tpu.search.telemetry import get_opaque_id
 
-        tracer = self._tracer()
+        if tracer is None:
+            tracer = self._tracer()
+        # from here to the serving plane's first phase: batcher, ladder
+        tracer.fill("search.route")
         if (not self._batcher.enabled or preference_shards is not None
                 or pinned_segments is not None or body.get("scroll")
                 or not batchable_body(body)):
@@ -1875,6 +1926,9 @@ class IndexService:
             # per-plane × per-phase log2 latency histograms, byte/tile
             # counters, and plane-ladder decision counters with reasons
             "phases": self.telemetry.phases_dict(),
+            # the request span trees, per span name (ISSUE 25): exact
+            # {count, sum_ns, self_ns}, so two readings subtract
+            "spans": self.telemetry.spans_dict(),
             # device-memory ledger (ISSUE 9, docs/OBSERVABILITY.md):
             # per-kind staged bytes (sum EXACTLY to staged_bytes_total),
             # staging/eviction lifecycle event rings, and the

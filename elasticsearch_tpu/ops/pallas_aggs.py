@@ -212,6 +212,9 @@ def segment_aggregate(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
+        # stated, not inherited from a Python function: the device
+        # trace's readers find the custom call by this name
+        name="agg_tiles",
     )(*operands)
 
     # accT[lo, hi] -> flat [o_pad] -> [n_ords]

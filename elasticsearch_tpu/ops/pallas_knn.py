@@ -257,6 +257,9 @@ def knn_score_tiles(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
+        # stated, not inherited from a Python function: the device
+        # trace's readers find the custom call by this name
+        name="knn_tiles",
     )(emb, scale.reshape(1, nd_pad), mask.reshape(1, nd_pad), qvecs)
 
 

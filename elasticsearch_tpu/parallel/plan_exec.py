@@ -70,10 +70,48 @@ _plane_logger = logging.getLogger("elasticsearch_tpu.parallel.plane")
 # rendezvous on the multi-device CPU backend (all_gather participants
 # from different run_ids wait on each other — observed as a hang when
 # concurrent REST threads each launch a shard_map program). A single
-# chip executes programs serially anyway, so serializing mesh-program
-# EXECUTION process-wide costs nothing on TPU and makes concurrent
-# search traffic safe everywhere. Compilation/staging stay unlocked.
+# chip executes programs serially anyway, so mesh-program EXECUTION is
+# serialized process-wide, which makes concurrent search traffic safe
+# everywhere. What that costs on TPU is not measured until a concurrent
+# cell reads ``kernel.lock_wait`` (the lock is held through completion,
+# so a second query cannot even enqueue). Staging stays unlocked.
 _MESH_EXEC_LOCK = threading.Lock()
+
+
+def _launch_locked(tracer, run, *args):
+    """One mesh program under ``_MESH_EXEC_LOCK``, the one launch site
+    of all five programs: the ``kernel`` span and what it is made of —
+    ``kernel.lock_wait`` (asking for the lock to holding it),
+    ``kernel.dispatch`` (the jitted call until it returns: enqueue, and
+    trace + compile on a first call, marked ``first_call``) and
+    ``kernel.device_wait`` (``block_until_ready``). Dispatch is async:
+    the collectives execute after ``run`` returns, so completion must
+    happen INSIDE the lock (callers fetch the results at once anyway)."""
+    t_kernel = tracer.start_parent("kernel")
+    try:
+        first_call = getattr(run, "first_call_pending", bool)()
+        t = tracer.start("kernel.lock_wait")
+        with _MESH_EXEC_LOCK:
+            t = t_dispatch = tracer.switch("kernel.lock_wait", t,
+                                           "kernel.dispatch")
+            outs = run(*args)
+            t = tracer.switch("kernel.dispatch", t, "kernel.device_wait")
+            jax.block_until_ready(outs)
+        tracer.stop("kernel.device_wait", t)
+        if first_call:
+            tracer.mark(t_dispatch, "first_call", True)
+    finally:
+        tracer.stop("kernel", t_kernel)
+    return outs
+
+
+def _fetch(tracer, outs):
+    """The program's outputs as numpy arrays: the device-to-host copies,
+    as the ``merge.d2h`` span."""
+    t = tracer.start("merge.d2h")
+    arrays = [np.asarray(o) for o in outs]
+    tracer.stop("merge.d2h", t)
+    return arrays
 
 
 class PlaneHealth:
@@ -544,7 +582,10 @@ def _mesh_query_program(mesh: Mesh, holder: _TemplateHolder, k: int,
 
     @jax.jit
     def run(seg, plan_arrays, pf_arrays, rs_arrays, scalars):
-        outs = mapped(seg, plan_arrays, pf_arrays, rs_arrays, scalars)
+        # (the scope puts the program's name into the op metadata of
+        # every device operation: a trace's fusions say whose they are)
+        with jax.named_scope("mesh_query"):
+            outs = mapped(seg, plan_arrays, pf_arrays, rs_arrays, scalars)
         # merged outputs are replicated (row 0 == row i); view outputs
         # keep their sharded leading axis
         merged = tuple(o[0] for o in outs[:n_merged])
@@ -627,7 +668,8 @@ def _mesh_batched_kernel_program(mesh: Mesh, spd: int, q_batch: int,
 
     @jax.jit
     def run(*args):
-        outs = mapped(*args)
+        with jax.named_scope("mesh_batched_kernel"):
+            outs = mapped(*args)
         return tuple(o[0] for o in outs)  # replicated: row 0 == row i
 
     from elasticsearch_tpu.common.compile_cache import (
@@ -739,7 +781,8 @@ def _mesh_batched_dense_agg_program(mesh: Mesh, spd: int, q_batch: int,
 
     @jax.jit
     def run(*args):
-        outs = mapped(*args)
+        with jax.named_scope("mesh_batched_dense_agg"):
+            outs = mapped(*args)
         # merged outputs replicated; agg partials stay sharded per slot
         return tuple(o[0] for o in outs[:4]) + tuple(outs[4:])
 
@@ -879,7 +922,8 @@ def _mesh_batched_pruned_program(mesh: Mesh, spd: int, q_batch: int,
 
     @jax.jit
     def run(*args):
-        outs = mapped(*args)
+        with jax.named_scope("mesh_batched_pruned"):
+            outs = mapped(*args)
         return tuple(o[0] for o in outs)  # replicated: row 0 == row i
 
     from elasticsearch_tpu.common.compile_cache import (
@@ -949,7 +993,8 @@ def _mesh_knn_program(mesh: Mesh, spd: int, q_pad: int, kk: int,
 
     @jax.jit
     def run(*args):
-        outs = mapped(*args)
+        with jax.named_scope("mesh_knn"):
+            outs = mapped(*args)
         return tuple(o[0] for o in outs)  # replicated: row 0 == row i
 
     from elasticsearch_tpu.common.compile_cache import (
@@ -1779,13 +1824,8 @@ class IndexMeshSearch:
                 # deadline before committing to the launch
                 deadline.checkpoint()
             on_kernel_launch(self.svc.name, "knn")
-            t_kernel = bt.start("kernel")
-            with _MESH_EXEC_LOCK:
-                outs = run(*args)
-                # async dispatch: completion inside the lock
-                jax.block_until_ready(outs)
-            bt.stop("kernel", t_kernel)
-            keys, docs, slots, totals = (np.asarray(o) for o in outs)
+            outs = _launch_locked(bt, run, *args)
+            keys, docs, slots, totals = _fetch(bt, outs)
         except (PlanStructureMismatch, NotImplementedError):
             self._note("mesh_pallas", "shape_mismatch", q_batch)
             return None  # shape ineligibility: next rung, no penalty
@@ -2219,12 +2259,13 @@ class IndexMeshSearch:
         if outs is None:
             self._note("host", "no_mesh_plane")
             return None
-        t_merge = tracer.start("merge")
-        keys, slots, docs, total, scores, raws, seg_counts = outs[:7]
-        keys = np.asarray(keys)
-        scores = np.asarray(scores)
-        raws = np.asarray(raws)
+        # (no finally: an exception in here ends the request)
+        t_merge = tracer.start_parent("merge")
+        # (the total too: int() of a device scalar is one more fetch)
+        keys, slots, docs, total, scores, raws = _fetch(tracer, outs[:6])
+        seg_counts = outs[6]
         total = int(total)
+        t_assemble = tracer.start("merge.assemble")
         # terminate_after caps per SHARD (each shard's collector stops
         # after N docs) while a mesh device holds one SEGMENT: group the
         # per-device counts by shard before capping — host-path contract
@@ -2253,8 +2294,7 @@ class IndexMeshSearch:
                      or {}).get("vocab")
         refs = []
         max_score = None
-        for i, (key, slot, d) in enumerate(zip(keys, np.asarray(slots),
-                                               np.asarray(docs))):
+        for i, (key, slot, d) in enumerate(zip(keys, slots, docs)):
             if key == -np.inf:
                 continue
             sid, seg = executor.pairs[int(slot)]
@@ -2281,6 +2321,7 @@ class IndexMeshSearch:
             refs.append(DocRef(sid, seg.name, int(d), score, sv))
             if max_score is None and sort_spec is None:
                 max_score = score
+        tracer.stop("merge.assemble", t_assemble)
         tracer.stop("merge", t_merge)
         aggregations = None
         if agg_specs:
@@ -2659,13 +2700,9 @@ class IndexMeshSearch:
                     # honor the deadline before committing to the launch
                     deadline.checkpoint()
                 on_kernel_launch(self.svc.name, "pruned")
-                t_kernel = bt.start("kernel")
-                with _MESH_EXEC_LOCK:
-                    outs = run(*args)
-                    jax.block_until_ready(outs)
-                bt.stop("kernel", t_kernel)
-                keys, docs, slots, totals, scored, tiles_total = (
-                    np.asarray(o) for o in outs)
+                outs = _launch_locked(bt, run, *args)
+                keys, docs, slots, totals, scored, tiles_total = _fetch(
+                    bt, outs)
                 pruned_stats = {
                     "tiles_scored": int(scored),
                     "tiles_pruned": int(tiles_total) - int(scored),
@@ -2710,14 +2747,8 @@ class IndexMeshSearch:
                 if deadline is not None:
                     deadline.checkpoint()
                 on_kernel_launch(self.svc.name, "batched")
-                t_kernel = bt.start("kernel")
-                with _MESH_EXEC_LOCK:
-                    outs = run(*args)
-                    jax.block_until_ready(outs)
-                bt.stop("kernel", t_kernel)
-                keys, docs, slots, totals = (np.asarray(o)
-                                             for o in outs[:4])
-                agg_raw = [np.asarray(o) for o in outs[4:]]
+                outs = _launch_locked(bt, run, *args)
+                keys, docs, slots, totals, *agg_raw = _fetch(bt, outs)
                 wb = 4 if codec == "packed" else 8
                 launch_adds = {
                     "postings_bytes_streamed":
@@ -2738,13 +2769,8 @@ class IndexMeshSearch:
                 if deadline is not None:
                     deadline.checkpoint()
                 on_kernel_launch(self.svc.name, "batched")
-                t_kernel = bt.start("kernel")
-                with _MESH_EXEC_LOCK:
-                    outs = run(*args)
-                    # async dispatch: completion inside the lock (above)
-                    jax.block_until_ready(outs)
-                bt.stop("kernel", t_kernel)
-                keys, docs, slots, totals = (np.asarray(o) for o in outs)
+                outs = _launch_locked(bt, run, *args)
+                keys, docs, slots, totals = _fetch(bt, outs)
                 wb = 4 if codec == "packed" else 8
                 launch_adds = {
                     "postings_bytes_streamed":
@@ -4152,13 +4178,5 @@ class MeshPlanExecutor:
         jscalars = {name: jnp.float32(v)
                     for name, v in (scalars or {}).items()}
         tracer.stop("staging", t_stage)
-        t_kernel = tracer.start("kernel")
-        with _MESH_EXEC_LOCK:
-            outs = run(self._seg_staged, staged_plan, staged_pf, staged_rs,
-                       jscalars)
-            # dispatch is async: the collectives execute after run()
-            # returns, so completion must happen INSIDE the lock (the
-            # caller fetches the results immediately anyway)
-            jax.block_until_ready(outs)
-        tracer.stop("kernel", t_kernel)
-        return outs
+        return _launch_locked(tracer, run, self._seg_staged, staged_plan,
+                              staged_pf, staged_rs, jscalars)

@@ -151,6 +151,13 @@ _SEARCH_MARKERS = ("_search", "_count", "_msearch", "_explain",
 _GET_MARKERS = ("_doc", "_mget", "_source", "_termvectors")
 
 
+def is_search_path(path: str) -> bool:
+    """The front door's test for opening a request tracer: the paths
+    of the search family (the routes ``_executor_for`` puts on the
+    search pool)."""
+    return any(m in path for m in _SEARCH_MARKERS)
+
+
 def _executor_for(method: str, pattern: str) -> str:
     """Route -> named pool, mirroring the per-action executor choices of
     the reference's transport actions (ThreadPool.Names)."""

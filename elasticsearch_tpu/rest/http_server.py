@@ -15,7 +15,16 @@ from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Optional
 from urllib.parse import parse_qsl, urlparse
 
-from elasticsearch_tpu.rest.controller import RestController
+from elasticsearch_tpu.rest.controller import (
+    RestController,
+    is_search_path,
+)
+from elasticsearch_tpu.search.telemetry import (
+    NULL_TRACER,
+    QueryTracer,
+    reset_request_tracer,
+    set_request_tracer,
+)
 
 
 class _Handler(BaseHTTPRequestHandler):
@@ -23,7 +32,28 @@ class _Handler(BaseHTTPRequestHandler):
     protocol_version = "HTTP/1.1"
 
     def _handle(self, method: str) -> None:
+        """One request, request line parsed to response written. A
+        search carries one span tree all the way (search/telemetry.py):
+        the root opens here, the controller's copied context takes the
+        tracer across the thread-pool hop, IndexService.search adopts
+        it, and it is drained after the last byte. Every other request
+        carries NULL_TRACER and pays nothing."""
         parsed = urlparse(self.path)
+        tracer = (QueryTracer() if is_search_path(parsed.path)
+                  else NULL_TRACER)
+        t_root = tracer.start_parent("http.request")
+        # body read, route match, pool hop, body decode, the node's
+        # resolving of the index: up to where search.request begins
+        tracer.fill("http.inbound")
+        ctx_token = set_request_tracer(tracer)
+        try:
+            self._serve(method, parsed, tracer)
+        finally:
+            reset_request_tracer(ctx_token)
+            tracer.stop("http.request", t_root)
+            tracer.finish()
+
+    def _serve(self, method: str, parsed, tracer) -> None:
         query = dict(parse_qsl(parsed.query, keep_blank_values=True))
         length = int(self.headers.get("Content-Length") or 0)
         body = self.rfile.read(length) if length else b""
@@ -31,6 +61,7 @@ class _Handler(BaseHTTPRequestHandler):
             method, parsed.path, query, body,
             content_type=self.headers.get("Content-Type"),
             headers=dict(self.headers.items()))
+        t_out = tracer.start("http.outbound")
         from elasticsearch_tpu.common.deprecation import (
             collect_warnings,
             warning_header_value,
@@ -69,6 +100,7 @@ class _Handler(BaseHTTPRequestHandler):
         self.end_headers()
         if method != "HEAD":
             self.wfile.write(data)
+        tracer.stop("http.outbound", t_out)
 
     def do_GET(self):
         self._handle("GET")

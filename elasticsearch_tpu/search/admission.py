@@ -384,7 +384,8 @@ class SearchAdmissionController:
         self._last_retry_after_s = ra
         return ra
 
-    def acquire(self, deadline=None, tenant: Optional[str] = None):
+    def acquire(self, deadline=None, tenant: Optional[str] = None,
+                tracer=None):
         """Admit one search dispatch. Returns an :class:`AdmissionToken`
         (``shed_expired`` set when the entry's deadline expired while
         queued — the caller serves the partial timed-out response
@@ -459,7 +460,16 @@ class SearchAdmissionController:
             q.append(entry)
             self._queued_total += 1
             self._tenant_bucket(tenant)["queued"] += 1
-        return self._wait(entry)
+        if tracer is None:
+            from elasticsearch_tpu.search.telemetry import NULL_TRACER
+
+            tracer = NULL_TRACER
+        # the queue wait, as a span of the request (only where it queued)
+        t_wait = tracer.start("admission.wait")
+        try:
+            return self._wait(entry)
+        finally:
+            tracer.stop("admission.wait", t_wait)
 
     def _grant_locked(self, tenant: str) -> AdmissionToken:
         self.in_flight += 1

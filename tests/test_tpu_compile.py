@@ -85,6 +85,32 @@ def test_score_tiles_compiles_at_1m_docs(sds, case):
     assert "tpu_custom_call" in text
 
 
+def test_score_tiles_custom_call_keeps_the_name_the_roofline_reads(sds):
+    """``bm25_roofline.serial`` finds the kernel in a device trace by
+    the pattern its metric file holds. The name is the one
+    ``pl.pallas_call(..., name="score_tiles")`` states: a rename must
+    fail here, not empty the metric on the chip."""
+    import json
+    import re
+
+    held = json.load(open(os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        "benchmark", "layer_metrics", "bm25_roofline.serial.json")))
+    pattern = re.compile(held["params"]["op_pattern"])
+    geom = psc.tile_geometry(1 << 17)
+    blocks = ((1 << 16) + psc.CB_MAX, psc.LANE)
+    text = psc.score_tiles.lower(
+        sds(blocks, jnp.int32), sds(blocks, jnp.float32),
+        sds((geom.n_tiles * psc.LANE, geom.tile_sub), jnp.float32),
+        sds((geom.n_tiles, 4), jnp.int32),
+        sds((geom.n_tiles, 4), jnp.int32), sds((1, 4), jnp.float32),
+        t_pad=4, cb=psc.CB_MAX // 2, sub=geom.tile_sub,
+        k=10).compile().as_text()
+    calls = [line.strip() for line in text.splitlines()
+             if "custom-call(" in line and "tpu_custom_call" in line]
+    assert calls and all(pattern.search(c) for c in calls), calls
+
+
 @pytest.mark.parametrize("q_batch", [1, 8])
 @pytest.mark.parametrize("dims", [128, 768, 1536])
 def test_knn_score_tiles_compiles_at_1m_docs(sds, dims, q_batch):
