@@ -882,6 +882,8 @@ def score_tiles(
     codec: str = "raw",
     tile_ids=None,  # [n_sel] i32: score ONLY these tiles (row tables
     # pre-gathered in the same order); fused top-k variant only
+    row_base=None,  # i32 scalar: first row of this segment's postings
+    # inside docs_padded/frac_padded, where they hold several segments
 ):
     """Run the tile-scoring kernel over a segment.
 
@@ -915,7 +917,20 @@ def score_tiles(
     ISSUE 6): row_lo/row_hi arrive pre-gathered in subset order, outputs
     have one candidate row per subset entry, and a runtime-zeroed row
     (row_lo == row_hi == 0) is skipped without DMA or compute.
+
+    row_base reads one segment out of a table that holds several,
+    concatenated along rows (the mesh executor stages every slot of a
+    device that way): row_lo/row_hi arrive relative to the segment and
+    become rows of the table here. The index maps and the kernel's
+    masks both read them, so nothing else moves — slicing the segment
+    out first would copy it per call. A traced operand, not a static
+    one: the slots of a device then share ONE trace of the kernel
+    (a static offset costs a trace and a mosaic compile per slot).
     """
+    if row_base is not None:
+        row_base = jnp.asarray(row_base, jnp.int32)
+        row_lo = row_lo + row_base
+        row_hi = row_hi + row_base
     with_sel = tile_ids is not None
     if with_sel and (dense or with_counts):
         # dense / match-count consumers need every tile's output —

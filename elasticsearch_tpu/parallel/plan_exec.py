@@ -373,6 +373,19 @@ class _TemplateHolder:
         return isinstance(other, _TemplateHolder) and self._key == other._key
 
 
+# The tile kernel's posting tables. A device holds each as ONE 2-D array
+# [slots_per_dev * n_rows_pad, LANE], its slots concatenated along rows,
+# and the kernel reads a slot's rows in place (score_tiles' row_base):
+# a slice of a stacked [slots, n_rows, LANE] table is a copy of the
+# slot's whole table, on the device, in every query.
+_KERNEL_TABLES = ("k_docs", "k_frac", "k_packed")
+
+
+def _slot_row_base(table, i: int, spd: int) -> int:
+    """First row of a device's slot ``i`` in its flat kernel table."""
+    return i * (table.shape[0] // spd)
+
+
 @functools.lru_cache(maxsize=128)
 def _mesh_query_program(mesh: Mesh, holder: _TemplateHolder, k: int,
                         spd: int = 1,
@@ -418,11 +431,12 @@ def _mesh_query_program(mesh: Mesh, holder: _TemplateHolder, k: int,
     pf_plan = holder.pf_plan
     rs_plan = holder.rs_plan
 
-    def per_slot(seg, plan_arrays, pf_arrays, rs_arrays, scalars):
+    def per_slot(seg, row_base, plan_arrays, pf_arrays, rs_arrays,
+                 scalars):
         """One segment's query phase: emit -> mask stages -> local top-k.
         Returns (loc_keys, loc_docs, loc_scores, loc_raw|None,
         local_count, agg_matched, scores)."""
-        ctx = EmitCtx(seg, plan_arrays)
+        ctx = EmitCtx(seg, plan_arrays, row_base)
         scores, matched = plan.emit(ctx)
         matched = matched & seg["live1"]
         # stage order mirrors the host path (search/service.py query()):
@@ -434,7 +448,7 @@ def _mesh_query_program(mesh: Mesh, holder: _TemplateHolder, k: int,
             matched = matched & seg[slice_col]
         agg_matched = matched
         if pf_plan is not None:
-            pf_ctx = EmitCtx(seg, pf_arrays)
+            pf_ctx = EmitCtx(seg, pf_arrays, row_base)
             _, pf_matched = pf_plan.emit(pf_ctx)
             matched = matched & pf_matched
         # per-slot matched count is also returned sharded: a slot is
@@ -464,7 +478,7 @@ def _mesh_query_program(mesh: Mesh, holder: _TemplateHolder, k: int,
             window, score_mode = rescore_static
             ksel = min(max(k, window), nd)
             sel_keys, sel_docs = jax.lax.top_k(masked, ksel)
-            rs_ctx = EmitCtx(seg, rs_arrays)
+            rs_ctx = EmitCtx(seg, rs_arrays, row_base)
             rs_scores, _ = rs_plan.emit(rs_ctx)
             w = min(window, ksel)
             rs_sel = rs_scores[sel_docs[:w]]
@@ -519,10 +533,14 @@ def _mesh_query_program(mesh: Mesh, holder: _TemplateHolder, k: int,
     def per_device(seg, plan_arrays, pf_arrays, rs_arrays, scalars):
         dev = jax.lax.axis_index("shards")
         slot_out = []
+        # (no kernel table staged: the scatter plane, no row to offset)
+        k_rows = next((seg[name].shape[0] // spd
+                       for name in _KERNEL_TABLES if name in seg), 0)
         for i in range(spd):
-            seg_i = {name: a[i] for name, a in seg.items()}
+            seg_i = {name: a if name in _KERNEL_TABLES else a[i]
+                     for name, a in seg.items()}
             slot_out.append(per_slot(
-                seg_i, [a[i] for a in plan_arrays],
+                seg_i, i * k_rows, [a[i] for a in plan_arrays],
                 [a[i] for a in pf_arrays], [a[i] for a in rs_arrays],
                 scalars))
         kk = slot_out[0][0].shape[0]
@@ -630,12 +648,13 @@ def _mesh_batched_kernel_program(mesh: Mesh, spd: int, q_batch: int,
         dev = jax.lax.axis_index("shards")
         cand_s, cand_d, cand_slot = [], [], []
         hits = None
+        corpus = (kp, None) if packed else (kd, kf)
         for i in range(spd):
-            corpus = (kp[i], None) if packed else (kd[i], kf[i])
             ts_, td_, th_ = psc.score_tiles(
                 corpus[0], corpus[1], lt[i], rl[i], rh[i], w[i],
                 t_pad=t_pad, cb=cb, sub=sub, k=kk, interpret=interpret,
-                tiles_per_step=tps, q_batch=q_batch, codec=codec)
+                tiles_per_step=tps, q_batch=q_batch, codec=codec,
+                row_base=_slot_row_base(corpus[0], i, spd))
             s_i, d_i, h_i = psc.merge_tile_topk_batched(ts_, td_, th_, kk)
             cand_s.append(s_i)  # [Q, kk']
             cand_d.append(d_i)
@@ -716,13 +735,14 @@ def _mesh_batched_dense_agg_program(mesh: Mesh, spd: int, q_batch: int,
         cand_s, cand_d, cand_slot = [], [], []
         counts = None
         agg_parts = None
+        corpus = (kp, None) if packed else (kd, kf)
         for i in range(spd):
-            corpus = (kp[i], None) if packed else (kd[i], kf[i])
             dense = psc.score_tiles(
                 corpus[0], corpus[1], lt[i], rl[i], rh[i], w[i],
                 t_pad=t_pad, cb=cb, sub=sub, dense=True,
                 interpret=interpret, tiles_per_step=tps,
-                q_batch=q_batch, codec=codec)[0]
+                q_batch=q_batch, codec=codec,
+                row_base=_slot_row_base(corpus[0], i, spd))[0]
             rows = dense.shape[1] // psc.LANE
             flat = dense.reshape(q_batch, rows, psc.LANE, sub).transpose(
                 0, 1, 3, 2).reshape(q_batch, -1)[:, : nd1 - 1]
@@ -843,12 +863,13 @@ def _mesh_batched_pruned_program(mesh: Mesh, spd: int, q_batch: int,
         dev = jax.lax.axis_index("shards")
         kw = dict(t_pad=t_pad, cb=cb, sub=sub, k=kk, interpret=interpret,
                   tiles_per_step=tps, q_batch=q_batch, codec=codec)
+        corpus = (kp, None) if packed else (kd, kf)
 
         def slot_pass(i, rl, rh, tid):
-            corpus = (kp[i], None) if packed else (kd[i], kf[i])
             ts_, td_, th_ = psc.score_tiles(
                 corpus[0], corpus[1], lt[i], rl, rh, w[i],
-                tile_ids=tid, **kw)
+                tile_ids=tid,
+                row_base=_slot_row_base(corpus[0], i, spd), **kw)
             s_i, d_i, h_i = psc.merge_tile_topk_batched(ts_, td_, th_, kk)
             slot = (jnp.zeros(s_i.shape, jnp.int32)
                     + (dev.astype(jnp.int32) * jnp.int32(spd)
@@ -3090,7 +3111,7 @@ class MeshPlanExecutor:
                 "k_packed" if kernel["codec"] == "packed" else "k_docs")
             if k_arr is None:
                 return False
-            n_rows = int(k_arr.shape[1]) - psc.CB_MAX
+            n_rows = int(k_arr.shape[0]) // old.n_slots - psc.CB_MAX
         for seg in new_segments:
             if (seg.nd_pad > old.nd_pad
                     or seg.block_docs.shape[0] > n_blocks
@@ -3226,8 +3247,18 @@ class MeshPlanExecutor:
 
             geom, codec = kernel["geom"], kernel["codec"]
             k_key = "k_packed" if codec == "packed" else "k_docs"
-            n_rows = int(base[k_key].shape[1])
+            # the flat table's rows per slot: the new slots are
+            # consecutive, so their rows are ONE range of it
+            n_rows = int(base[k_key].shape[0]) // self.n_slots
             meta = dict(kernel["meta"])
+
+            def with_new_slots(table, rows):
+                return jax.device_put(
+                    jax.lax.dynamic_update_slice(
+                        table, jnp.asarray(rows.reshape(-1, psc.LANE)),
+                        (jnp.int32(first_new * n_rows), jnp.int32(0))),
+                    self._sharding)
+
             if codec == "packed":
                 pk_rows = np.zeros((len(new_slots), n_rows, psc.LANE),
                                    np.int32)
@@ -3263,17 +3294,12 @@ class MeshPlanExecutor:
                 meta[id(seg)] = (bmin, bmax, bfmax)
                 amp_bounds += sum(int(b.nbytes) for b in meta[id(seg)])
             if codec == "packed":
-                staged["k_packed"] = jax.device_put(
-                    base["k_packed"].at[idx_new].set(
-                        jnp.asarray(pk_rows)), self._sharding)
+                staged["k_packed"] = with_new_slots(base["k_packed"],
+                                                    pk_rows)
                 amp_postings = int(pk_rows.nbytes)
             else:
-                staged["k_docs"] = jax.device_put(
-                    base["k_docs"].at[idx_new].set(
-                        jnp.asarray(dc_rows)), self._sharding)
-                staged["k_frac"] = jax.device_put(
-                    base["k_frac"].at[idx_new].set(
-                        jnp.asarray(fr_rows)), self._sharding)
+                staged["k_docs"] = with_new_slots(base["k_docs"], dc_rows)
+                staged["k_frac"] = with_new_slots(base["k_frac"], fr_rows)
                 amp_postings = int(dc_rows.nbytes + fr_rows.nbytes)
             for key in [k for k in base if k.startswith("k_live_t")]:
                 g = (geom if key == "k_live_t" else psc.tile_geometry(
@@ -3507,8 +3533,9 @@ class MeshPlanExecutor:
         """Stage the pallas tile-scoring plane over the stacked segment
         set: one SHARED tile geometry covering the stacked doc space, the
         per-segment posting windows (docs + per-posting BM25 norm factors,
-        sentinel-padded so every CB-aligned DMA window is in bounds)
-        packed per slot, and the per-slot transposed live masks. Returns
+        sentinel-padded so every CB-aligned DMA window is in bounds), one
+        slot after the other along the rows of ONE 2-D table (see
+        ``_KERNEL_TABLES``), and the per-slot transposed live masks. Returns
         the kernel session (plan builders consult it via
         ``ctx.mesh_kernel``) or None when the kernel can't run (pallas
         off / non-TPU backend without interpret mode).
@@ -3544,8 +3571,11 @@ class MeshPlanExecutor:
                 # slot's doc ids must fit the packed word's doc bits
                 codec = psc.resolve_postings_codec(
                     self.postings_codec_pref, geom.nd_pad)
-                n_rows = max(s.block_docs.shape[0]
-                             for s in self.segments) + psc.CB_MAX
+                # rows per slot: the longest segment and its sentinel
+                # rows, rounded up so that every slot of the flat table
+                # starts on a block boundary of every cb of the ladder
+                n_rows = max(s.block_docs.shape[0] for s in self.segments)
+                n_rows = (-(-n_rows // psc.CB_MAX) + 1) * psc.CB_MAX
                 # HBM budget gate: the kernel tables are the big mesh
                 # allocation — over budget (after LRU eviction) the
                 # ladder serves from the scatter mesh / host rung with
@@ -3630,13 +3660,13 @@ class MeshPlanExecutor:
             on_device_staging(self.index_name, kind_postings, "k_postings")
             if codec == "packed":
                 self._seg_staged["k_packed"] = jax.device_put(
-                    packed, self._sharding)
+                    packed.reshape(-1, psc.LANE), self._sharding)
                 self.postings_bytes_staged = int(packed.nbytes)
             else:
                 self._seg_staged["k_docs"] = jax.device_put(
-                    docs, self._sharding)
+                    docs.reshape(-1, psc.LANE), self._sharding)
                 self._seg_staged["k_frac"] = jax.device_put(
-                    frac, self._sharding)
+                    frac.reshape(-1, psc.LANE), self._sharding)
                 self.postings_bytes_staged = int(docs.nbytes + frac.nbytes)
             on_device_staging(self.index_name, "live_mask", "k_live_t")
             self._seg_staged["k_live_t"] = jax.device_put(

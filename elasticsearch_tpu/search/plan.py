@@ -91,12 +91,17 @@ class PlanNode:
 
 class EmitCtx:
     """Carries the segment device arrays + the flat plan-array iterator
-    during tracing."""
+    during tracing. ``row_base``: first row of this segment's postings
+    inside the kernel tables (k_docs / k_frac / k_packed) — None where
+    the tables are the segment's own, the slot's offset where the mesh
+    executor hands over a device's whole table."""
 
-    def __init__(self, seg_arrays: dict, plan_arrays: List):
+    def __init__(self, seg_arrays: dict, plan_arrays: List,
+                 row_base: Optional[int] = None):
         self.seg = seg_arrays
         self._arrays = plan_arrays
         self._pos = 0
+        self.row_base = row_base
 
     def take(self, n: int) -> List:
         out = self._arrays[self._pos : self._pos + n]
@@ -323,7 +328,8 @@ class PallasScoreTermsNode(PlanNode):
             t_pad=self.t_pad, cb=self.cb, sub=self.sub,
             dense=True, with_counts=self.with_counts,
             interpret=self.interpret,
-            tiles_per_step=self.tiles_per_step, codec=self.codec)
+            tiles_per_step=self.tiles_per_step, codec=self.codec,
+            row_base=ctx.row_base)
         nd = ctx.nd1 - 1
         scores = psc.dense_to_flat(outs[0], self.sub)[:nd]
         scores = jnp.concatenate([scores, jnp.zeros(1, jnp.float32)])

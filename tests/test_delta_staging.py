@@ -127,6 +127,106 @@ class TestDeltaAppend:
         finally:
             idx.close()
 
+    @pytest.mark.parametrize("codec", ["raw", "packed"])
+    def test_appended_segment_lands_in_its_slots_rows_of_the_flat_table(
+            self, codec):
+        """The kernel tables are ONE 2-D array, slots one after the
+        other (ISSUE 26): a delta append writes the new slots' row
+        range and nothing else, and the new segment is found."""
+        from elasticsearch_tpu.ops import pallas_scoring as psc
+
+        idx = build_index(
+            f"da-flat-{codec}", shards=2,
+            **{"index.search.pallas.postings_codec": codec})
+        key = "k_packed" if codec == "packed" else "k_docs"
+        try:
+            _fill(idx, 0, 40)
+            assert idx.search({"query": {"match": {"body": "common"}},
+                               "size": 5})["_plane"] == "mesh_pallas"
+            ms = idx._mesh_search
+            old = ms._executor
+            before = np.asarray(old._seg_staged[key])
+            rows = before.shape[0] // old.n_slots
+            assert before.ndim == 2 and before.shape[1] == psc.LANE
+            assert rows % psc.CB_MAX == 0 and rows * old.n_slots \
+                == before.shape[0]
+            # few enough new words that the new segments have no more
+            # blocks than the staged ones (else the refresh rebuilds)
+            for d in range(40, 44):
+                idx.index_doc(str(d), {"body": f"fresh{d} common",
+                                       "n": d, "tag": "red"})
+            idx.refresh()
+            r = idx.search({"query": {"match": {"body": "fresh42"}},
+                            "size": 5})
+            assert r["_plane"] == "mesh_pallas"
+            assert [h["_id"] for h in r["hits"]["hits"]] == ["42"]
+            assert ms.delta_restage_total == 1
+            new = ms._executor
+            after = np.asarray(new._seg_staged[key])
+            assert after.shape == before.shape  # no geometry rebuild
+            first_new = len(old.segments)
+            assert len(new.segments) > first_new
+            # every old slot's rows, and the still-free slots', are as
+            # they were; each new slot's rows are its segment's table
+            keep = np.ones(after.shape[0], bool)
+            keep[first_new * rows: len(new.segments) * rows] = False
+            np.testing.assert_array_equal(after[keep], before[keep])
+            for slot in range(first_new, len(new.segments)):
+                seg = new.segments[slot]
+                f = seg._block_frac()
+                if codec == "packed":
+                    want = psc.pack_segment_blocks(seg.block_docs, f,
+                                                   seg.nd_pad)
+                else:
+                    want = psc.pad_segment_blocks(seg.block_docs, f,
+                                                  seg.nd_pad)[0]
+                got = after[slot * rows: (slot + 1) * rows]
+                np.testing.assert_array_equal(got[: want.shape[0]], want)
+                if codec == "raw":
+                    np.testing.assert_array_equal(
+                        np.asarray(new._seg_staged["k_frac"])[
+                            slot * rows: slot * rows + f.shape[0]], f)
+        finally:
+            idx.close()
+
+    def test_segment_with_more_blocks_than_a_slots_rows_takes_the_rebuild(
+            self):
+        """A segment whose postings do not fit a slot's row range of
+        the staged table cannot delta-append: the refresh rebuilds the
+        generation at the larger geometry and serves it."""
+        from elasticsearch_tpu.ops import pallas_scoring as psc
+
+        idx = build_index("da-toolong", shards=2)
+        try:
+            _fill(idx, 0, 24)
+            idx.search({"query": {"match": {"body": "common"}},
+                        "size": 5})
+            ms = idx._mesh_search
+            old = ms._executor
+            rows = old._seg_staged["k_docs"].shape[0] // old.n_slots
+            assert old.free_slots() >= 1  # slots are not what runs out
+            # every distinct word takes a block of its own
+            n_words = rows  # more blocks than a slot's rows hold
+            for d in range(24, 24 + n_words // 4):
+                idx.index_doc(str(d), {"body": " ".join(
+                    f"u{d}x{j}" for j in range(8)) + " common",
+                    "n": d, "tag": "red"})
+            idx.refresh()
+            r = idx.search({"query": {"match": {"body": "u30x3"}},
+                            "size": 5})
+            assert r["_plane"] == "mesh_pallas"
+            assert [h["_id"] for h in r["hits"]["hits"]] == ["30"]
+            assert ms.delta_restage_total == 0
+            new = ms._executor
+            assert new.scope != old.scope
+            new_rows = new._seg_staged["k_docs"].shape[0] // new.n_slots
+            assert new_rows > rows and new_rows % psc.CB_MAX == 0
+            assert idx.search({"query": {"match": {"body": "common"}},
+                               "size": 1})["hits"]["total"] \
+                == 24 + n_words // 4
+        finally:
+            idx.close()
+
     def test_append_slots_exhausted_falls_back_to_rebuild(self):
         # packing allows 2 slots total: the second refresh cannot fit a
         # delta append — the classifier must fall back to the full

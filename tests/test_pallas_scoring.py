@@ -313,3 +313,121 @@ class TestHostGeometry:
         ref = reference_scores(docs, frac, lanes, nd_pad)
         assert int(hits) == nd
         assert_topk_valid(top_s, top_d, ref, 10)
+
+
+class TestRowBase:
+    """``row_base`` reads one segment's rows out of a table that holds
+    several (the mesh executor's flat staging): same bits as the
+    segment's own table, whatever the codec, variant, batch or cb."""
+
+    SLOTS = 2
+    LADDER = (8, 16, 32, CB_MAX // 2)
+
+    def _slots(self, codec, off_boundary=0):
+        """Per slot its own padded table(s) and query tables; plus the
+        flat table: the slots' tables, each padded to a CB_MAX multiple
+        of rows (and ``off_boundary`` rows more), one after the other."""
+        from elasticsearch_tpu.ops.pallas_scoring import pack_segment_blocks
+
+        rng = np.random.RandomState(26)
+        slots = []
+        for _ in range(self.SLOTS):
+            bd, bt, ts_, tc, nd_pad = build_corpus(rng, 2000, 40)
+            frac = compute_block_frac(
+                bd, bt, np.full(nd_pad + 1, 30.0, np.float32), avgdl=30.0)
+            if codec == "packed":
+                own = (pack_segment_blocks(bd, frac, nd_pad), None)
+            else:
+                own = pad_segment_blocks(bd, frac, nd_pad)
+            live = (rng.rand(nd_pad) > 0.1).astype(np.float32)
+            terms = rng.choice(40, size=3, replace=False)
+            lanes = [QueryLane(ts_[t], tc[t], float(rng.rand() + 0.5))
+                     for t in terms]
+            slots.append(dict(own=own, bd=bd, frac=frac, live=live,
+                              lanes=lanes, nd_pad=nd_pad))
+        n_rows_pad = -(-max(s["own"][0].shape[0] for s in slots)
+                       // CB_MAX) * CB_MAX + off_boundary
+        nd_pad = slots[0]["nd_pad"]
+        total = -(-self.SLOTS * n_rows_pad // CB_MAX) * CB_MAX
+        flat_docs = np.full((total, LANE),
+                            0 if codec == "packed" else nd_pad, np.int32)
+        flat_frac = np.zeros((total, LANE), np.float32)
+        for i, s in enumerate(slots):
+            lo = i * n_rows_pad
+            flat_docs[lo: lo + s["own"][0].shape[0]] = s["own"][0]
+            if codec != "packed":
+                flat_frac[lo: lo + s["own"][1].shape[0]] = s["own"][1]
+        flat = (flat_docs, None if codec == "packed" else flat_frac)
+        return slots, flat, n_rows_pad
+
+    @pytest.mark.parametrize("q_batch", [1, 8])
+    @pytest.mark.parametrize("variant", ["dense", "topk", "tile_ids"])
+    @pytest.mark.parametrize("codec", ["raw", "packed"])
+    def test_flat_table_scores_the_slots_own_bits(self, codec, variant,
+                                                  q_batch):
+        slots, flat, n_rows_pad = self._slots(codec)
+        case = (["raw", "packed"].index(codec) * 6
+                + ["dense", "topk", "tile_ids"].index(variant) * 2
+                + [1, 8].index(q_batch))
+        rng = np.random.RandomState(case)
+        for i, s in enumerate(slots):
+            if i == 0:
+                continue  # row_base 0: the table's first rows, as ever
+            cb = self.LADDER[case % len(self.LADDER)]
+            geom = tile_geometry(s["nd_pad"], tile_sub=4)
+            bmin, bmax = block_min_max(s["bd"], s["frac"], s["nd_pad"])
+            row_lo, row_hi, weights, cb = build_tile_tables(
+                s["lanes"], bmin, bmax, geom, cb=cb)
+            if q_batch > 1:
+                # one row per member; a zero is a lane dead for it
+                weights = (weights * rng.rand(q_batch, weights.shape[1])
+                           * (rng.rand(q_batch, weights.shape[1]) > 0.3)
+                           ).astype(np.float32)
+            kw = dict(t_pad=weights.shape[1], cb=cb, sub=geom.tile_sub,
+                      k=10, q_batch=q_batch, codec=codec, interpret=True)
+            if variant == "dense":
+                kw.update(dense=True, with_counts=True)
+            elif variant == "tile_ids":
+                sel = np.asarray([2, 0, 3], np.int32)
+                row_lo, row_hi = row_lo[sel], row_hi[sel]
+                kw["tile_ids"] = jnp.asarray(sel)
+            rest = (jnp.asarray(build_live_t(s["live"], geom)),
+                    jnp.asarray(row_lo), jnp.asarray(row_hi),
+                    jnp.asarray(weights))
+            own = score_tiles(
+                jnp.asarray(s["own"][0]),
+                None if codec == "packed" else jnp.asarray(s["own"][1]),
+                *rest, **kw)
+            shared = score_tiles(
+                jnp.asarray(flat[0]),
+                None if codec == "packed" else jnp.asarray(flat[1]),
+                *rest, row_base=i * n_rows_pad, **kw)
+            assert len(own) == len(shared)
+            for a, b in zip(own, shared):
+                np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+            assert float(np.max(np.asarray(own[0]))) > 0.0  # scored
+
+    def test_row_base_off_a_block_boundary_scores_the_same(self):
+        """The row tables carry the offset, and the kernel's windows are
+        the aligned blocks around wherever they point: a slot need not
+        start on a block boundary (the executor's do, to keep a block
+        one slot's)."""
+        slots, flat, n_rows_pad = self._slots("raw", off_boundary=8)
+        s = slots[1]
+        geom = tile_geometry(s["nd_pad"], tile_sub=4)
+        bmin, bmax = block_min_max(s["bd"], s["frac"], s["nd_pad"])
+        row_lo, row_hi, weights, cb = build_tile_tables(
+            s["lanes"], bmin, bmax, geom, cb=32)
+        assert n_rows_pad % cb == 8
+        kw = dict(t_pad=weights.shape[1], cb=cb, sub=geom.tile_sub,
+                  dense=True, with_counts=True, interpret=True)
+        rest = (jnp.asarray(build_live_t(s["live"], geom)),
+                jnp.asarray(row_lo), jnp.asarray(row_hi),
+                jnp.asarray(weights))
+        own = score_tiles(jnp.asarray(s["own"][0]),
+                          jnp.asarray(s["own"][1]), *rest, **kw)
+        shared = score_tiles(jnp.asarray(flat[0]), jnp.asarray(flat[1]),
+                             *rest, row_base=n_rows_pad, **kw)
+        for a, b in zip(own, shared):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        assert float(np.max(np.asarray(own[0]))) > 0.0

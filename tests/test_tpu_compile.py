@@ -111,6 +111,90 @@ def test_score_tiles_custom_call_keeps_the_name_the_roofline_reads(sds):
     assert calls and all(pattern.search(c) for c in calls), calls
 
 
+# msmarco-serial's staged kernel tables on its one chip: 4 slots (2 live,
+# 2 headroom) of 80,922 rows, each padded to a CB_MAX multiple
+CELL_SLOTS = 4
+CELL_ROWS = 80922
+CELL_ROWS_PAD = -(-CELL_ROWS // psc.CB_MAX) * psc.CB_MAX
+
+
+def _table_sized_ops(text):
+    """Instructions of a compiled module that produce or copy an array
+    with the cell's table row count (one slot's or the whole table's),
+    other than the kernel's custom call and bitcasts: each is a pass
+    over 41 MB or more that a query pays before it scores anything."""
+    import re
+
+    rows = {CELL_ROWS, CELL_ROWS_PAD, CELL_SLOTS * CELL_ROWS_PAD}
+    sized = re.compile(
+        r"[\[,](%s)[,\]]" % "|".join(str(n) for n in sorted(rows)))
+    instruction = re.compile(r"(ROOT )?%[\w.-]+ = ")
+    found = []
+    for line in text.splitlines():
+        line = line.strip()
+        if not instruction.match(line) or not sized.search(line):
+            continue  # module header, a computation's signature, small
+        if (" parameter(" in line or " bitcast(" in line
+                or ("custom-call(" in line and "tpu_custom_call" in line)):
+            continue
+        found.append(line[:200])
+    return found
+
+
+def _four_slot_scores(row_base_of):
+    """``score_tiles`` called once per slot, as the mesh programs'
+    ``per_device`` unrolls it; ``row_base_of`` None slices slot ``i``
+    out of a stacked table, the shape staged before ISSUE 26."""
+    geom = psc.tile_geometry(1 << 16)
+    kw = dict(t_pad=8, cb=16, sub=geom.tile_sub, dense=True)
+
+    def run(kd, kf, lt, rl, rh, w):
+        outs = []
+        for i in range(CELL_SLOTS):
+            if row_base_of is None:
+                outs.append(psc.score_tiles(
+                    kd[i], kf[i], lt[i], rl[i], rh[i], w[i], **kw)[0])
+            else:
+                outs.append(psc.score_tiles(
+                    kd, kf, lt[i], rl[i], rh[i], w[i],
+                    row_base=row_base_of(i), **kw)[0])
+        return outs
+
+    small = [((CELL_SLOTS, geom.n_tiles * psc.LANE, geom.tile_sub),
+              jnp.float32),
+             ((CELL_SLOTS, geom.n_tiles, 8), jnp.int32),
+             ((CELL_SLOTS, geom.n_tiles, 8), jnp.int32),
+             ((CELL_SLOTS, 1, 8), jnp.float32)]
+    return jax.jit(run), small
+
+
+def test_four_slots_read_a_flat_table_in_place(sds):
+    """The kernel reads each slot's rows of the flat table in place
+    (``row_base`` moves its row tables, which its index maps read): the
+    compiled program holds the four custom calls and no pass over the
+    table."""
+    run, small = _four_slot_scores(lambda i: i * CELL_ROWS_PAD)
+    flat = (CELL_SLOTS * CELL_ROWS_PAD, psc.LANE)
+    text = run.lower(sds(flat, jnp.int32), sds(flat, jnp.float32),
+                     *(sds(*a) for a in small)).compile().as_text()
+    assert text.count("tpu_custom_call") >= CELL_SLOTS
+    assert _table_sized_ops(text) == []
+
+
+def test_four_slots_sliced_from_a_stacked_table_are_copied(sds):
+    """The twin: the table stacked [slots, rows, 128] and sliced per
+    slot, as it was staged before. The TPU interleaves the slots row by
+    row, so every slice is a strided gather and a re-tiling; this is
+    the fault the case above must be able to see."""
+    run, small = _four_slot_scores(None)
+    stacked = (CELL_SLOTS, CELL_ROWS, psc.LANE)
+    text = run.lower(sds(stacked, jnp.int32), sds(stacked, jnp.float32),
+                     *(sds(*a) for a in small)).compile().as_text()
+    found = _table_sized_ops(text)
+    assert any(" copy(" in line or " slice(" in line for line in found), \
+        found
+
+
 @pytest.mark.parametrize("q_batch", [1, 8])
 @pytest.mark.parametrize("dims", [128, 768, 1536])
 def test_knn_score_tiles_compiles_at_1m_docs(sds, dims, q_batch):
@@ -152,16 +236,12 @@ def _for_tpu(plan):
     return plan
 
 
-def test_serial_mesh_program_compiles_for_four_chips(topo, monkeypatch):
-    """The serial mesh program (shard_map + tile kernel + ICI merge) on a
-    Mesh of the four described devices. A scratch harness: a small index
-    is searched on four virtual CPU devices in interpret mode, the call
-    into ``_mesh_query_program`` is recorded, and the same program is
-    built again on the TPU mesh and compiled from the recorded shapes.
-    The shapes are the small index's: this finds what a mesh of real
-    chips refuses, not what 1M documents need."""
+def _recorded_serial_launch(monkeypatch, n_shards, n_devices, n_docs):
+    """A scratch harness: a small index is searched on virtual CPU
+    devices in interpret mode and the call into ``_mesh_query_program``
+    is recorded. Returns (the real program builder, what was seen: the
+    template holder, the builder's arguments, the launch's arrays)."""
     import numpy as np
-    from jax.sharding import Mesh, NamedSharding, PartitionSpec as PS
 
     from elasticsearch_tpu.common.settings import Settings
     from elasticsearch_tpu.index.index_service import IndexService
@@ -184,15 +264,16 @@ def test_serial_mesh_program_compiles_for_four_chips(topo, monkeypatch):
 
     monkeypatch.setattr(plan_exec, "_mesh_query_program", recording)
     idx = IndexService("tpucompile", Settings({
-        "index.number_of_shards": 8,
+        "index.number_of_shards": n_shards,
         "index.search.mesh": True,
         "index.refresh_interval": -1,
     }), mapping={"properties": {
         "body": {"type": "text", "analyzer": "whitespace"}}})
-    idx._mesh_search = plan_exec.IndexMeshSearch(idx, mesh=shard_mesh(4))
+    idx._mesh_search = plan_exec.IndexMeshSearch(
+        idx, mesh=shard_mesh(n_devices))
     rng = np.random.RandomState(5)
     try:
-        for d in range(6000):  # ~750 docs a shard: 8-sublane tiles
+        for d in range(n_docs):  # ~750 docs a shard: 8-sublane tiles
             idx.index_doc(str(d), {"body": " ".join(
                 f"w{t}" for t in rng.zipf(1.3, 12) % 200)})
         idx.refresh()
@@ -201,13 +282,26 @@ def test_serial_mesh_program_compiles_for_four_chips(topo, monkeypatch):
     finally:
         idx.close()
     assert resp["_plane"] == "mesh_pallas"
+    return build_program, seen
+
+
+def _compiled_for_tpu(build_program, seen, devices, seg_shapes=None):
+    """The recorded serial program built again on a mesh of described
+    TPU devices and compiled from the recorded shapes (``seg_shapes``
+    replaces those of the staged arrays it names). The shapes are the
+    small index's: this finds what real chips refuse, not what 1M
+    documents need."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as PS
+
+    from elasticsearch_tpu.parallel import plan_exec
 
     holder = seen["holder"]
-    tpu_mesh = Mesh(np.asarray(topo.devices), ("shards",))
+    tpu_mesh = Mesh(np.asarray(devices), ("shards",))
     program = build_program(
         tpu_mesh,
         plan_exec._TemplateHolder(_for_tpu(holder.plan),
-                                  holder._key + "|tpu"),
+                                  holder._key + f"|tpu{len(devices)}"),
         *seen["args"], **seen["kwargs"])
     sharded = NamedSharding(tpu_mesh, PS("shards"))
     replicated = NamedSharding(tpu_mesh, PS())
@@ -217,9 +311,38 @@ def test_serial_mesh_program_compiles_for_four_chips(topo, monkeypatch):
             lambda a: jax.ShapeDtypeStruct(np.shape(a), a.dtype,
                                            sharding=sharding), tree)
 
-    *per_slot, scalars = seen["arrays"]
-    text = program.__wrapped__.lower(
-        *(shapes(t, sharded) for t in per_slot),
+    seg, *per_slot, scalars = seen["arrays"]
+    seg = shapes(seg, sharded)
+    for name, shape in (seg_shapes or {}).items():
+        seg[name] = jax.ShapeDtypeStruct(shape, seg[name].dtype,
+                                         sharding=sharded)
+    return program.__wrapped__.lower(
+        seg, *(shapes(t, sharded) for t in per_slot),
         shapes(scalars, replicated)).compile().as_text()
+
+
+def test_serial_mesh_program_compiles_for_four_chips(topo, monkeypatch):
+    """The serial mesh program (shard_map + tile kernel + ICI merge) on a
+    Mesh of the four described devices."""
+    build_program, seen = _recorded_serial_launch(
+        monkeypatch, n_shards=8, n_devices=4, n_docs=6000)
+    text = _compiled_for_tpu(build_program, seen, topo.devices)
     assert "tpu_custom_call" in text
     assert "all-gather" in text
+
+
+def test_serial_mesh_program_reads_the_cells_tables_in_place(
+        topo, monkeypatch):
+    """``msmarco-serial``'s program: 2 shards on one chip, so 4 slots
+    (one of headroom a shard), with the kernel tables at the cell's
+    size. Every query launches it, so a pass over a slot's table (41 MB)
+    in it is paid by every query: there is none, and one launch of the
+    kernel per slot."""
+    build_program, seen = _recorded_serial_launch(
+        monkeypatch, n_shards=2, n_devices=1, n_docs=1500)
+    assert seen["kwargs"]["spd"] == CELL_SLOTS
+    flat = (CELL_SLOTS * CELL_ROWS_PAD, psc.LANE)
+    text = _compiled_for_tpu(build_program, seen, topo.devices[:1],
+                             {"k_docs": flat, "k_frac": flat})
+    assert text.count("tpu_custom_call") >= CELL_SLOTS
+    assert _table_sized_ops(text) == []
