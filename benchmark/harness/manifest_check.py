@@ -16,6 +16,8 @@ import re
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 PATH = re.compile(r"^[A-Za-z0-9_.\-/]{1,200}$")
+PROGRAM_IMPORT = re.compile(
+    r"^\s*(from|import)\s+elasticsearch_tpu\b", re.MULTILINE)
 TOP_KEYS = {"command", "paths", "run_seconds", "configs", "workloads",
             "end_to_end", "per_layer"}
 SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
@@ -38,6 +40,13 @@ def _line(text, what, faults, limit=200):
 def load_json(path: str):
     with open(path, encoding="utf-8") as f:
         return json.load(f)
+
+
+def imports_program(path: str) -> bool:
+    """Whether a file of the benchmark imports the program under test:
+    a reference may not."""
+    with open(path, encoding="utf-8") as f:
+        return bool(PROGRAM_IMPORT.search(f.read()))
 
 
 def reporting_cells(metric: dict, cells: list) -> list:
@@ -117,6 +126,13 @@ def check(root: str, manifest: dict = None) -> list:
                                f"{held.get('generator')}.py")
             if not os.path.isfile(gen):
                 faults.append(f"config {c['name']}: no generator {gen}")
+            ref = os.path.join(root, paths[0], "references",
+                               f"{held.get('reference')}.py")
+            if not os.path.isfile(ref):
+                faults.append(f"config {c['name']}: no reference {ref}")
+            elif imports_program(ref):
+                faults.append(f"config {c['name']}: its reference {ref} "
+                              f"imports the program")
         if len(c["reduced"]) > 16 or not all(
                 NAME.match(k) for k in c["reduced"]):
             faults.append(f"config {c['name']}: reduced is at most 16 names")

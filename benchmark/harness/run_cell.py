@@ -14,7 +14,9 @@ import time
 
 import numpy as np
 
-from harness import manifest_check, reference, server, trace, work
+import references
+from harness import manifest_check, server, trace, work
+from harness.comparison import Comparison, compare_between
 from harness.corpus import rng_for
 
 BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -217,7 +219,7 @@ class Tracer:
         wanted = traffic.get("trace_seconds", trace.rules()["trace_seconds"])
         self.length = min(wanted, seconds * 0.8)
         self.delay = (seconds - self.length) / 2.0
-        self.interval = None
+        self.marks = []  # time.monotonic_ns() of each mark left in the trace
         self.error = None
         self.thread = threading.Thread(target=self._run)
 
@@ -228,28 +230,43 @@ class Tracer:
             time.sleep(self.delay)
             options = jax.profiler.ProfileOptions()
             options.python_tracer_level = 0
-            t0 = time.monotonic()
             jax.profiler.start_trace(self.dir, profiler_options=options)
-            time.sleep(self.length)
-            jax.profiler.stop_trace()
-            self.interval = (t0, time.monotonic())
+            try:
+                self._mark()
+                time.sleep(self.length)
+                self._mark()
+            finally:
+                jax.profiler.stop_trace()
         except Exception as e:  # the traced run reports it and goes on
             self.error = repr(e)
 
+    def _mark(self) -> None:
+        """An annotation in the trace whose time on the clients' clock
+        is known: what carries the device's window onto that clock."""
+        from jax.profiler import TraceAnnotation
+
+        self.marks.append(time.monotonic_ns())
+        with TraceAnnotation(trace.MARK):
+            time.sleep(0.001)
+
     def reduce(self, keep=None):
         self.thread.join()
-        if self.error or self.interval is None:
+        if self.error:
             say("trace", error=self.error)
             return None
         lines = trace.read_xplane(trace.find_xplane(self.dir))
         out = trace.reduce(lines, trace.rules())
+        out["clock_offset_ns"], disagree = trace.clock_offset_ns(
+            lines, trace.rules(), self.marks)
         if keep:  # a recording for tests/test_trace.py: a few events a line
             with open(keep, "w", encoding="utf-8") as f:
                 json.dump([dict(l, events=l["events"][:200])
                            for l in lines], f)
         say("trace", inventory=trace.inventory(lines)[:40],
             busy_s=out["busy_s"], window_s=out["window_s"],
-            devices=out["devices"])
+            devices=out["devices"], device_window_ns=out["device_window_ns"],
+            clock_offset_ns=out["clock_offset_ns"],
+            clock_marks_disagree_ns=disagree)
         return out
 
 
@@ -312,60 +329,39 @@ def _answer(r: dict) -> dict:
                      for name, buckets in r.get("aggs", {}).items()}}
 
 
-def _sample_ids(records: list, refs: dict, view: dict, cap: int, seed: int):
+def _sample_ids(records: list, refs: dict, weigh, cap: int, seed: int):
     """The distinct requests to compare: all, or a sample drawn from the
-    seed that keeps the one with most postings."""
+    seed that keeps the heaviest by ``weigh(ref)``."""
     ids = sorted({tuple(r["id"]) for r in records})
     if len(ids) <= cap:
         return set(ids)
-    heavy = max(ids, key=lambda i: work.match_postings_bytes(
-        view["text_fields"][refs[i]["field"]], refs[i]["terms"]))
+    heavy = max(ids, key=lambda i: weigh(refs[i]))
     pick = rng_for(seed, 7).choice(len(ids), cap - 1, replace=False)
     return {ids[int(j)] for j in pick} | {heavy}
 
 
-def compare_searches(cmp, records, refs, view, seed, cap, control=None):
-    """Every answered search of ``records`` against the reference on
-    ``view`` (exact: totals, hits, scores, buckets). ``control`` puts
-    the reference at that precision in the program's place."""
-    bm25 = {}
-    chosen = _sample_ids(records, refs, view, cap, seed)
+def compare_searches(cmp, records, refs, reference, weigh, seed, cap,
+                     control=None):
+    """Every answered search of ``records`` against ``reference``, the
+    configuration's own on the view the answers were given on.
+    ``control`` puts the reference's answer at that precision in the
+    program's place, once a request."""
+    chosen = _sample_ids(records, refs, weigh, cap, seed)
     by_id = {}
     for r in records:
         if tuple(r["id"]) in chosen:
             by_id.setdefault(tuple(r["id"]), []).append(r)
     for rid, recs in by_id.items():
-        ref = refs[rid]
-        field = ref["field"]
-        if field not in bm25:
-            bm25[field] = reference.Bm25(
-                view["text_fields"][field], view["shard"],
-                int(view["shard"].max()) + 1)
-        scores, matched = bm25[field].match(ref["terms"])
-        buckets = {name: reference.bucket_counts(spec, view["columns"],
-                                                 matched)
-                   for name, spec in ref["aggs"].items()}
-        if control:
-            low, _ = bm25[field].match(ref["terms"], precision=control)
-            ids, top = reference.top_k(low, matched, ref["size"])
-            recs = [{"total": int(matched.sum()), "ids": ids.tolist(),
-                     "scores": top.tolist(), "id": rid,
-                     "aggs": {n: list(b.items())
-                              for n, b in buckets.items()}}]
-        for r in recs:
-            what = f"{rid[0]}[{rid[1]}]"
-            answer = _answer(r)
-            reference.compare_hits(cmp, what, answer, scores, matched,
-                                   ref["size"])
-            for name, want in buckets.items():
-                reference.compare_buckets(cmp, f"{what}.{name}",
-                                          answer["aggs"].get(name, {}), want)
+        for r in recs[:1] if control else recs:
+            reference.compare(cmp, f"{rid[0]}[{rid[1]}]", _answer(r),
+                              refs[rid], control=control)
 
 
-def compare_appends(cmp, config, dataset, records, refs, after, seed):
+def compare_appends(cmp, config, dataset, base, records, refs, after):
     """The write path: every bulk acknowledged whole; after the final
     refresh the count and a seeded sample read back by id; answers under
-    ingest between the base's and the final state's."""
+    ingest between those of ``base``, the reference on the base's view,
+    and those of the reference on the final state's."""
     bulks = [r for r in records if r["kind"] == "bulk"]
     present = np.zeros(dataset.n_docs + dataset.append_pool, bool)
     present[: dataset.n_docs] = True
@@ -394,38 +390,27 @@ def compare_appends(cmp, config, dataset, records, refs, after, seed):
     cmp.note("readback_missing", missing, "read-back by id")
     cmp.note("readback_wrong_fields", wrong, "read-back by id")
     # answers: exact after the final refresh, bounded under ingest
-    base, final = dataset.view(dataset.n_docs), dataset.view(n_hi)
-    shards = int(final["shard"].max()) + 1
-    bm_base = reference.Bm25(base["text_fields"]["request"], base["shard"],
-                             shards)
-    bm_final = reference.Bm25(final["text_fields"]["request"],
-                              final["shard"], shards)
+    final = references.build(config, dataset.view(n_hi))
     window = [r for r in records if r["kind"] == "search" and _ok(r)
               and not r.get("before_append")]
     for r in window + after["searches"]:
         rid = tuple(r["id"])
         ref, what = refs[rid], f"{rid[0]}[{rid[1]}]"
-        lo_m = bm_base.matched(ref["terms"])
-        hi_m = bm_final.matched(ref["terms"]) & present
+        lo_m = base.matched(ref)
+        hi_m = final.matched(ref) & present
         answer = _answer(r)
         if r.get("after_refresh"):
-            reference.compare_hits(cmp, what, answer, None, hi_m,
-                                   ref["size"], check_scores=False)
-            for name, spec in ref["aggs"].items():
-                reference.compare_buckets(
-                    cmp, f"{what}.{name}", answer["aggs"].get(name, {}),
-                    reference.bucket_counts(spec, final["columns"], hi_m))
+            final.compare(cmp, what, answer, ref, among=hi_m)
             continue
         cmp.compared += 1
-        reference.compare_between(
+        compare_between(
             cmp, "total_out_of_range", what, {0: answer["total"]},
             {0: int(lo_m.sum())}, {0: int(hi_m.sum())})
-        for name, spec in ref["aggs"].items():
-            reference.compare_between(
+        lo_b, hi_b = base.buckets(ref, lo_m), final.buckets(ref, hi_m)
+        for name in hi_b:
+            compare_between(
                 cmp, "bucket_out_of_range", f"{what}.{name}",
-                answer["aggs"].get(name, {}),
-                reference.bucket_counts(spec, base["columns"], lo_m),
-                reference.bucket_counts(spec, final["columns"], hi_m))
+                answer["aggs"].get(name, {}), lo_b[name], hi_b[name])
 
 
 def read_back(served, config, dataset, records, refs, seed) -> dict:
@@ -481,6 +466,13 @@ def run(args, manifest: dict, t_process: float) -> dict:
     the result; raises ``HarnessFailure`` where there is none to give."""
     cell, config, traffic = load_cell(manifest, args.workload)
     chips = cell["chips"]
+    controls = set(references.named_by(config).controls)
+    if any(g["operations"] == ["append"] for g in traffic["clients"]):
+        controls.add("lost_ack")  # the harness's own, where a cell writes
+    if args.control and args.control not in controls:
+        raise server.HarnessFailure(
+            f"--control {args.control!r}: the cell's reference "
+            f"{config['reference']!r} states {sorted(controls)}")
     devices = server.require_tpu(chips)
     t_jax = time.monotonic()
     server.build_native()
@@ -558,27 +550,30 @@ def run(args, manifest: dict, t_process: float) -> dict:
             served.close()
     # the window has closed, the peak is read, the program's state freed
     t0 = time.monotonic()
-    base_view = dataset.view(dataset.n_docs)
+    reference = references.build(config, dataset.view(dataset.n_docs))
+    peaks = work.peaks_for(device["kind"])
     cap = config["check"]["max_distinct_requests"]
 
+    def weigh(ref):
+        return work.least_seconds(reference.work(ref), peaks, chips)
+
     def compare(control):
-        cmp = reference.Comparison(config["limits"])
+        cmp = Comparison(config["limits"])
         cmp.note("unanswered",
                  sum(1 for r in records if r["status"] == -1), "window")
+        # a cell that writes is exact on the base, before its first append
+        exact = ([r for r in warm if r.get("before_append")] if writers
+                 else records)
+        compare_searches(
+            cmp, [r for r in exact if r["kind"] == "search" and _ok(r)],
+            refs, reference, weigh, args.seed, cap,
+            control=None if control == "lost_ack" else control)
         if writers:
-            exact = [r for r in warm if r["kind"] == "search" and _ok(r)
-                     and r.get("before_append")]
-            compare_searches(cmp, exact, refs, base_view, args.seed, cap,
-                             control=control)
-            compare_appends(cmp, config, dataset, warm + records, refs,
-                            readback, args.seed)
+            compare_appends(cmp, config, dataset, reference, warm + records,
+                            refs, readback)
             if control == "lost_ack":
                 cmp.note("count_abs_diff", 1, "control: one acknowledged "
                          "document taken out of the count")
-        else:
-            compare_searches(
-                cmp, [r for r in records if r["kind"] == "search" and _ok(r)],
-                refs, base_view, args.seed, cap, control=control)
         return cmp
 
     cmp = compare(None)
@@ -597,8 +592,7 @@ def run(args, manifest: dict, t_process: float) -> dict:
     if args.trace:
         ctx = {"records": records, "refs": refs, "stats_before": before,
                "stats_after": after, "trace": traced,
-               "trace_interval": tracer.interval, "view": base_view,
-               "peaks": work.peaks_for(device["kind"]), "chips": chips,
+               "reference": reference, "peaks": peaks, "chips": chips,
                "window": {"start": window["start"], "seconds": args.seconds}}
         out_metrics = per_layer(manifest, args.workload, ctx)
     else:

@@ -5,6 +5,13 @@ operation and the longest idle gaps.
 (the only part that needs JAX); ``reduce`` is arithmetic on those lists
 and is checked against a small recorded trace kept beside this file
 (``recorded_trace.json``, ``tests/test_trace.py``).
+
+The profiler's clock starts with its session; the clients' records are
+on ``time.monotonic()``. The tracer (``run_cell.Tracer``) leaves marks in
+the trace, annotations named ``MARK`` whose ``time.monotonic_ns()`` it
+noted as it entered them; ``clock_offset_ns`` reads them back, and with
+it a reader can say which requests' kernel launches lie inside the
+device's traced window (``readers/trace_roofline.py``).
 """
 
 from __future__ import annotations
@@ -14,6 +21,7 @@ import json
 import os
 import re
 
+MARK = "bench:clock_mark"
 RULES = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                      "trace_rules.json")
 
@@ -53,7 +61,7 @@ def inventory(lines: list) -> list:
              sum(e[2] for e in l["events"]) / 1e9] for l in lines]
 
 
-def _union(intervals: list) -> list:
+def union(intervals: list) -> list:
     merged = []
     for lo, hi in sorted(intervals):
         if merged and lo <= merged[-1][1]:
@@ -67,7 +75,10 @@ def reduce(lines: list, rule: dict, top: int = 10) -> dict:
     """Busy seconds (union of the device-op intervals, averaged over the
     device planes), the traced window, seconds per operation name, and
     the longest gaps between operations on the first device with the
-    host event that overlapped each most."""
+    host event that overlapped each most. ``busy_events`` keeps each
+    device plane's operations as they were read and ``device_window_ns``
+    their first start and last end, for a reader that attributes them to
+    requests."""
     device = re.compile(rule["device_plane"])
     busy_line = [re.compile(p) for p in rule["busy_lines"]]
     host = re.compile(rule["host_plane"])
@@ -79,12 +90,13 @@ def reduce(lines: list, rule: dict, top: int = 10) -> dict:
     every = [e for l in lines for e in l["events"]]
     if not every:
         return {"busy_s": 0.0, "window_s": 0.0, "devices": 0,
-                "device_ops": [], "idle_gaps": [], "op_seconds": {}}
+                "device_ops": [], "idle_gaps": [], "op_seconds": {},
+                "busy_events": {}, "device_window_ns": None}
     start = min(e[1] for e in every)
     end = max(e[1] + e[2] for e in every)
     busy, op_seconds = [], {}
     for events in per_plane.values():
-        merged = _union([(e[1], e[1] + e[2]) for e in events])
+        merged = union([(e[1], e[1] + e[2]) for e in events])
         busy.append(sum(hi - lo for lo, hi in merged) / 1e9)
         for name, _, dur in events:
             op_seconds[name] = op_seconds.get(name, 0.0) + dur / 1e9
@@ -93,7 +105,7 @@ def reduce(lines: list, rule: dict, top: int = 10) -> dict:
     gaps = []
     if per_plane:
         first = per_plane[sorted(per_plane)[0]]
-        merged = _union([(e[1], e[1] + e[2]) for e in first])
+        merged = union([(e[1], e[1] + e[2]) for e in first])
         edges = [start] + [x for iv in merged for x in iv] + [end]
         holes = sorted(((edges[i + 1] - edges[i], edges[i])
                         for i in range(0, len(edges), 2)), reverse=True)
@@ -114,17 +126,27 @@ def reduce(lines: list, rule: dict, top: int = 10) -> dict:
             "window_s": (end - start) / 1e9, "devices": n_dev,
             "device_ops": [[k, v] for k, v in ops], "idle_gaps": gaps,
             "op_seconds": op_seconds,
-            "start_ns": start, "end_ns": end}
+            "start_ns": start, "end_ns": end,
+            "busy_events": per_plane,
+            "device_window_ns": [
+                min(e[1] for ev in per_plane.values() for e in ev),
+                max(e[1] + e[2] for ev in per_plane.values() for e in ev)]
+            if per_plane else None}
+
+
+def clock_offset_ns(lines: list, rule: dict, marks: list):
+    """(profiler's clock less ``time.monotonic_ns()``, how far the marks
+    disagree about it), from the tracer's marks in the order it left
+    them; (None, None) where the trace holds no mark for each."""
+    host = re.compile(rule["host_plane"])
+    found = sorted(e[1] for l in lines if host.search(l["plane"])
+                   for e in l["events"] if e[0] == MARK)
+    if not marks or len(found) != len(marks):
+        return None, None
+    offsets = [at - mono for at, mono in zip(found, marks)]
+    return offsets[0], max(offsets) - min(offsets)
 
 
 def short_name(op: str, limit: int = 120) -> str:
     """An HLO instruction's text cut to what names it."""
     return op if len(op) <= limit else op[:limit] + "..."
-
-
-def matching_seconds(op_seconds: dict, pattern: str):
-    """Summed device seconds of the operations whose name matches, or
-    None where none does: a reader with nothing to read says nothing."""
-    rx = re.compile(pattern)
-    found = [v for k, v in op_seconds.items() if rx.search(k)]
-    return sum(found) if found else None

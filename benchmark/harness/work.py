@@ -1,13 +1,14 @@
-"""The work a request needs, counted from the benchmark's own corpus and
-never from the program's tables: a packed codec, pruning or a fused
-kernel changes the time and not the count.
+"""The least time the chip could take for a piece of work, and the table
+of peaks it is held against.
 
-For a ``match`` query the work is its postings: the sum over the query's
-terms of the term's document frequency in the corpus, times 8 bytes (one
-i32 doc id and one f32 impact: the raw codec's posting), read once. The
-FLOPs (a multiply-add or two per posting) are negligible beside that on
-any chip whose FLOP/s exceed its bytes/s, so the bound is bytes over the
-chip's memory bandwidth.
+The work of a request is its reference's to count (``work(ref)`` in
+``references/<name>.py``): bytes read once and operations, from the
+benchmark's own corpus and never from the program's tables, so that a
+packed codec, pruning or a fused kernel changes the time and not the
+count, and a kernel's roofline reads the same work whatever implements
+it. The bound is the larger of bytes over the chip's memory bandwidth
+and operations over the peak the work names (``"peak"``: an entry of
+``peaks.json``, the rate of the data type the configuration states).
 """
 
 from __future__ import annotations
@@ -15,7 +16,6 @@ from __future__ import annotations
 import json
 import os
 
-POSTING_BYTES = 8
 PEAKS = os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "peaks.json")
 
@@ -29,10 +29,12 @@ def peaks_for(device_kind: str) -> dict:
     return table[device_kind]
 
 
-def match_postings_bytes(field, terms) -> int:
-    return int(field.doc_freq(terms).sum()) * POSTING_BYTES
+def least_seconds(work: dict, peaks: dict, chips: int) -> float:
+    """The least time ``chips`` chips could take for ``work``
+    (``{"bytes", "flops", "peak"}``; no ``peak`` where there are no
+    operations to hold against one)."""
+    seconds = work["bytes"] / peaks["hbm_bytes_per_s"]
+    if work.get("flops"):
+        seconds = max(seconds, work["flops"] / peaks[work["peak"]])
+    return seconds / chips
 
-
-def least_seconds(n_bytes: float, peaks: dict, chips: int) -> float:
-    """The least time ``chips`` chips could take to read the bytes."""
-    return n_bytes / (peaks["hbm_bytes_per_s"] * chips)

@@ -3,7 +3,8 @@ returns the number, or None where it finds nothing to read: the harness
 then leaves the metric out of the line. ``ctx`` is what the traced run
 gathered (``harness/run_cell.py``): ``records`` of the window, ``refs``
 of the requests, ``stats_before``/``stats_after``, ``trace``,
-``trace_interval``, ``view``, ``peaks``, ``chips``, ``window``."""
+``reference`` (the cell's, on the base's view), ``peaks``, ``chips``,
+``window``."""
 
 
 def searches(ctx, group=None):

@@ -1,4 +1,5 @@
-"""The plain reference and the comparison that decides ``correct``.
+"""The plain reference of lexical search: ``match`` queries (an OR of
+terms) scored by BM25, top-k, with terms and date-histogram buckets.
 
 A numpy BM25 (Lucene's idf, k1 1.2, b 0.75, exact f32 lengths) with one
 set of statistics per shard: the engine scores with its segment's
@@ -7,8 +8,12 @@ segment per shard. Bucket counts for the aggregations are plain numpy
 counts over the matched documents. Nothing here imports the program or
 takes anything the program has made.
 
-Every number compared has a limit of its own (``Limits``); the readings
-the limits were set from are in PERF.md section 2.
+``Reference`` is what the harness drives (the contract is in
+``references/__init__.py``). Every number it compares has a limit of its
+own in the configuration's ``limits``; the readings the limits were set
+from are in PERF.md section 2. The control is the same BM25 with every
+operand and every result rounded to bfloat16, the nearest precision
+below the float32 the scores are stated in.
 """
 
 from __future__ import annotations
@@ -21,6 +26,7 @@ from harness.corpus import TextField
 
 K1, B = 1.2, 0.75
 HOUR_MS = 3_600_000
+POSTING_BYTES = 8  # one i32 doc id and one f32 impact: the raw codec's
 
 
 def bf16(x) -> np.ndarray:
@@ -106,44 +112,11 @@ def bucket_counts(spec: dict, columns: dict, matched: np.ndarray) -> dict:
 
 
 # ----------------------------------------------------------------------
-# The comparison
+# The comparison of one answer
 # ----------------------------------------------------------------------
 
 
-class Comparison:
-    """Worst reading of every number compared, over all answers.
-
-    ``limits`` maps a number's name to its limit; a number above its
-    limit makes the run not correct. Names not in ``limits`` are
-    refused: a number is never compared without a limit of its own."""
-
-    def __init__(self, limits: dict):
-        self.limits = dict(limits)
-        self.worst = {name: 0.0 for name in limits}
-        self.where = {}
-        self.compared = 0
-
-    def note(self, name: str, value: float, what: str) -> None:
-        if name not in self.limits:
-            raise KeyError(f"no limit for compared number {name!r}")
-        if not value <= self.worst[name]:  # NaN counts as worse
-            self.worst[name] = float(value) if value == value else math.inf
-            self.where[name] = what
-
-    def numbers(self) -> dict:
-        """{name: {"value", "limit"}}; ``nothing_compared`` guards a run
-        whose window returned no answer to compare."""
-        out = {name: {"value": self.worst[name], "limit": self.limits[name]}
-               for name in self.limits}
-        out["nothing_compared"] = {
-            "value": 0 if self.compared else 1, "limit": 0}
-        return out
-
-    def correct(self) -> bool:
-        return all(n["value"] <= n["limit"] for n in self.numbers().values())
-
-
-def compare_hits(cmp: Comparison, what: str, answer: dict,
+def compare_hits(cmp, what: str, answer: dict,
                  scores: np.ndarray, matched: np.ndarray, size: int,
                  check_scores: bool = True) -> None:
     """One ``_search`` answer against the reference: ``hits.total``
@@ -178,19 +151,77 @@ def compare_hits(cmp: Comparison, what: str, answer: dict,
             np.abs(got[:m] - ref_top[:m]) / np.abs(ref_top[:m]))), what)
 
 
-def compare_buckets(cmp: Comparison, what: str, got: dict, ref: dict) -> None:
+def compare_buckets(cmp, what: str, got: dict, ref: dict) -> None:
     """Bucket counts equal; empty buckets count as absent."""
     keys = set(got) | set(ref)
     diff = max((abs(got.get(k, 0) - ref.get(k, 0)) for k in keys), default=0)
     cmp.note("bucket_abs_diff", diff, what)
 
 
-def compare_between(cmp: Comparison, name: str, what: str, got: dict,
-                    lo: dict, hi: dict) -> None:
-    """Counts that a reader under ingest may have seen: no fewer than
-    before the first append, no more than after the last."""
-    out = 0
-    for k in set(got) | set(lo):
-        v = got.get(k, 0)
-        out = max(out, lo.get(k, 0) - v, v - hi.get(k, 0))
-    cmp.note(name, max(out, 0), what)
+# ----------------------------------------------------------------------
+# What the harness drives
+# ----------------------------------------------------------------------
+
+
+class Reference:
+    """``match`` requests on one view: ``ref`` holds ``field``,
+    ``terms``, ``size`` and ``aggs`` ({name: {kind, column}})."""
+
+    controls = ("bfloat16",)
+
+    def __init__(self, view: dict, config: dict):
+        self.view = view
+        self.n_shards = int(view["shard"].max()) + 1
+        self._bm25 = {}
+        self._last = (None, None)
+
+    def _field(self, name: str) -> Bm25:
+        if name not in self._bm25:
+            self._bm25[name] = Bm25(self.view["text_fields"][name],
+                                    self.view["shard"], self.n_shards)
+        return self._bm25[name]
+
+    def matched(self, ref: dict) -> np.ndarray:
+        return self._field(ref["field"]).matched(ref["terms"])
+
+    def buckets(self, ref: dict, matched: np.ndarray) -> dict:
+        return {name: bucket_counts(spec, self.view["columns"], matched)
+                for name, spec in ref["aggs"].items()}
+
+    def _expected(self, ref: dict):
+        """(scores, matched, buckets) of one request; the last one is
+        kept, since a window asks the same request more than once."""
+        if self._last[0] is not ref:
+            scores, matched = self._field(ref["field"]).match(ref["terms"])
+            self._last = (ref, (scores, matched, self.buckets(ref, matched)))
+        return self._last[1]
+
+    def compare(self, cmp, what: str, answer: dict, ref: dict,
+                control=None, among=None) -> None:
+        if among is not None:
+            compare_hits(cmp, what, answer, None, among, ref["size"],
+                         check_scores=False)
+            buckets = self.buckets(ref, among)
+        else:
+            scores, matched, buckets = self._expected(ref)
+            if control:
+                low, _ = self._field(ref["field"]).match(ref["terms"],
+                                                         precision=control)
+                ids, top = top_k(low, matched, ref["size"])
+                answer = {"total": int(matched.sum()), "ids": ids.tolist(),
+                          "scores": top.tolist(), "aggs": buckets}
+            compare_hits(cmp, what, answer, scores, matched, ref["size"])
+        for name, want in buckets.items():
+            compare_buckets(cmp, f"{what}.{name}",
+                            answer["aggs"].get(name, {}), want)
+
+    def work(self, ref: dict) -> dict:
+        """A ``match`` query's postings, read once: the sum over its
+        terms of the term's document frequency in the corpus, times 8
+        bytes, and one f32 add a posting. The adds are held against the
+        chip's highest published floating-point rate, which no f32 rate
+        exceeds, so the bound stays a least time; the bytes set it."""
+        n = int(self.view["text_fields"][ref["field"]].doc_freq(
+            ref["terms"]).sum())
+        return {"bytes": n * POSTING_BYTES, "flops": n,
+                "peak": "bf16_flops_per_s"}

@@ -36,11 +36,12 @@ def parse_args(argv):
     p.add_argument("--check-manifest", action="store_true",
                    help="check BENCHMARK.json and the files it names; "
                         "no chip, no JAX")
-    p.add_argument("--control", default=None,
-                   choices=("bfloat16", "lost_ack"),
-                   help="put the control in the program's place in the "
+    p.add_argument("--control", default=None, metavar="NAME",
+                   help="put a control in the program's place in the "
                         "comparison: the run must come out not correct. "
-                        "Never set by the driver")
+                        "One the cell's reference states (references/"
+                        "<name>.py, controls), or lost_ack where the cell "
+                        "writes. Never set by the driver")
     p.add_argument("--keep-trace", default=None, metavar="FILE",
                    help="with --trace 1: write the first events of every "
                         "line of the trace there, as JSON")

@@ -10,7 +10,10 @@ import os
 import numpy as np
 import pytest
 
-from harness import manifest_check, reference, run_cell
+import references
+from harness import manifest_check, run_cell
+from harness.comparison import Comparison
+from references import bm25
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
@@ -29,24 +32,33 @@ def _dataset(name, docs=3000, seed=2**31 + 11):
 def _served_by_reference(dataset, refs, precision, n=64):
     """Window records as the reference itself would have answered."""
     view = dataset.view(dataset.n_docs)
-    records, bm25 = [], {}
+    records, bm25s = [], {}
     ops = sorted({rid[0] for rid in refs})
     for rid in [(op, i) for i in range(n // len(ops)) for op in ops]:
         ref = refs[rid]
-        if ref["field"] not in bm25:
-            bm25[ref["field"]] = reference.Bm25(
+        if ref["field"] not in bm25s:
+            bm25s[ref["field"]] = bm25.Bm25(
                 view["text_fields"][ref["field"]], view["shard"], 2)
-        bm = bm25[ref["field"]]
+        bm = bm25s[ref["field"]]
         scores, matched = bm.match(ref["terms"], precision=precision)
-        ids, top = reference.top_k(scores, matched, ref["size"])
+        ids, top = bm25.top_k(scores, matched, ref["size"])
         records.append({
             "id": list(rid), "kind": "search", "status": 200,
             "total": int(matched.sum()), "ids": [str(i) for i in ids],
             "scores": top.tolist(),
-            "aggs": {name: [[k, c] for k, c in reference.bucket_counts(
+            "aggs": {name: [[k, c] for k, c in bm25.bucket_counts(
                 spec, view["columns"], matched).items()]
                 for name, spec in ref["aggs"].items()}})
     return view, records
+
+
+def _compare(config, records, refs, view, control=None):
+    cmp = Comparison(config["limits"])
+    reference = references.build(config, view)
+    run_cell.compare_searches(
+        cmp, records, refs, reference,
+        lambda ref: reference.work(ref)["bytes"], 1, 10_000, control=control)
+    return cmp
 
 
 def _refs(dataset):
@@ -61,8 +73,7 @@ def test_reference_passes_and_the_bfloat16_control_fails(name):
     refs = _refs(dataset)
     for precision, want in (("float32", True), ("bfloat16", False)):
         view, records = _served_by_reference(dataset, refs, precision)
-        cmp = reference.Comparison(config["limits"])
-        run_cell.compare_searches(cmp, records, refs, view, 1, 10_000)
+        cmp = _compare(config, records, refs, view)
         assert cmp.correct() is want, cmp.numbers()
         if not want:
             worst = cmp.numbers()["score_rel_err"]
@@ -73,10 +84,8 @@ def test_control_flag_puts_the_control_in_the_programs_place():
     config, dataset = _dataset("msmarco-passage")
     refs = _refs(dataset)
     view, records = _served_by_reference(dataset, refs, "float32")
-    cmp = reference.Comparison(config["limits"])
-    run_cell.compare_searches(cmp, records, refs, view, 1, 10_000,
-                              control="bfloat16")
-    assert not cmp.correct()
+    assert not _compare(config, records, refs, view,
+                        control="bfloat16").correct()
 
 
 @pytest.mark.parametrize("fault", ["score", "swapped_hit", "total",
@@ -90,7 +99,7 @@ def test_an_altered_answer_is_not_correct(fault):
     if fault == "score":
         hit["scores"][3] *= 1.002
     elif fault == "swapped_hit":
-        matched = set(np.flatnonzero(reference.Bm25(
+        matched = set(np.flatnonzero(bm25.Bm25(
             view["text_fields"]["request"], view["shard"], 2).matched(
                 refs[tuple(hit["id"])]["terms"])).tolist())
         hit["ids"][9] = str(next(i for i in range(dataset.n_docs)
@@ -102,19 +111,17 @@ def test_an_altered_answer_is_not_correct(fault):
     elif fault == "dropped_hit":
         hit["ids"].pop()
         hit["scores"].pop()
-    cmp = reference.Comparison(config["limits"])
-    run_cell.compare_searches(cmp, records, refs, view, 1, 10_000)
-    assert not cmp.correct()
+    assert not _compare(config, records, refs, view).correct()
 
 
 def test_a_run_that_compared_nothing_is_not_correct():
     config, _ = _dataset("msmarco-passage", docs=200)
-    assert not reference.Comparison(config["limits"]).correct()
+    assert not Comparison(config["limits"]).correct()
 
 
 def test_bf16_rounding_is_round_to_nearest_even():
     x = np.asarray([1.0, 1.00390625, 1.005859375, 3.14159], np.float32)
-    got = reference.bf16(x)
+    got = bm25.bf16(x)
     assert got[0] == 1.0 and got[1] == 1.0  # tie to even
     assert got[2] == np.float32(1.0078125)
     assert abs(got[3] - 3.14159) < 3.14159 * 2 ** -8

@@ -32,7 +32,7 @@ def test_command_line_check_needs_no_jax():
 def test_layer_metric_in_a_cell_without_what_it_moves_is_refused():
     m = _manifest()
     metric = next(x for x in m["per_layer"]
-                  if x["name"] == "frontdoor.outside_phases_ms.serial")
+                  if x["name"] == "frontdoor.client_side_ms.serial")
     # PR 23's refusal: a cell that does not report search_p50_ms
     m["workloads"].append(dict(m["workloads"][0], name="http-logs-append",
                                traffic="append2x100-dash2qps"))

@@ -1,5 +1,5 @@
 """The readers of the program's span tree (``_stats`` ``search.spans``):
-arithmetic on a made-up window, the manifest's ten entries, and the
+arithmetic on a made-up window, the manifest's twelve entries, and the
 contract between what the program writes and what the readers expect,
 on a tiny node on the CPU (a check of counts: the values there are the
 sandbox's times and no device metric)."""
@@ -20,7 +20,8 @@ SPAN_METRICS = [
     "frontdoor.http_outbound_ms.serial", "frontdoor.admit_ms.serial",
     "frontdoor.unattributed_ms.serial", "frontdoor.client_side_ms.serial",
     "mesh.lock_wait_ms.serial", "mesh.dispatch_ms.serial",
-    "mesh.device_wait_ms.serial", "mesh.d2h_ms.serial"]
+    "mesh.device_wait_ms.serial", "mesh.d2h_ms.serial",
+    "frontdoor.route_ms.serial", "frontdoor.respond_ms.serial"]
 
 
 def _span(count, sum_ns, self_ns):
@@ -76,12 +77,13 @@ def test_client_side_is_the_round_trip_less_the_servers_span():
     assert client_side.read(dict(ctx, records=[]), {}) is None
 
 
-def test_manifest_holds_the_ten_span_metrics_and_is_sound():
+def test_manifest_holds_the_span_metrics_and_is_sound():
     assert manifest_check.check(ROOT) == []
     manifest = manifest_check.load_json(os.path.join(ROOT, "BENCHMARK.json"))
-    names = [m["name"] for m in manifest["per_layer"]]
-    assert names[-10:] == SPAN_METRICS and len(names) == 16
-    for m in manifest["per_layer"][-10:]:
+    by_name = {m["name"]: m for m in manifest["per_layer"]}
+    assert set(SPAN_METRICS) <= set(by_name) and len(by_name) == 18
+    assert "frontdoor.outside_phases_ms.serial" not in by_name  # retired
+    for m in (by_name[name] for name in SPAN_METRICS):
         assert (m["unit"], m["better"], m["source"], m["moves"],
                 m["workloads"]) == ("ms", "lower", "program_span",
                                     "search_p50_ms", ["msmarco-serial"])
@@ -118,7 +120,8 @@ def test_the_block_the_program_writes_is_the_block_the_readers_expect(
                         "sent": 0.0, "done": 1.0}] * n}
     spans = {"http.request", "http.inbound", "http.outbound",
              "search.request", "search.admit", "kernel.lock_wait",
-             "kernel.dispatch", "kernel.device_wait", "merge.d2h"}
+             "kernel.dispatch", "kernel.device_wait", "merge.d2h",
+             "search.route", "search.respond"}
     for name in spans:  # each once a request, all of them drained
         assert (after["spans"][name]["count"]
                 - before["spans"][name]["count"]) == n, name
