@@ -86,7 +86,9 @@ def _launch_locked(tracer, run, *args):
     trace + compile on a first call, marked ``first_call``) and
     ``kernel.device_wait`` (``block_until_ready``). Dispatch is async:
     the collectives execute after ``run`` returns, so completion must
-    happen INSIDE the lock (callers fetch the results at once anyway)."""
+    happen INSIDE the lock (callers fetch the results at once anyway).
+    (Asking for the host copies here, ``copy_to_host_async`` before the
+    wait, was measured and left out: 0.11 ms of 8.0, PERF.md 6 PR 30.)"""
     t_kernel = tracer.start_parent("kernel")
     try:
         first_call = getattr(run, "first_call_pending", bool)()
@@ -105,13 +107,50 @@ def _launch_locked(tracer, run, *args):
     return outs
 
 
-def _fetch(tracer, outs):
-    """The program's outputs as numpy arrays: the device-to-host copies,
-    as the ``merge.d2h`` span."""
+def _fetch(tracer, outs, telemetry):
+    """A program's output(s) as numpy: ONE array or a sequence of them,
+    every copy to the host asked for before the first is waited for
+    (``jax.device_get``), as the ``merge.d2h`` span. Counts the arrays
+    copied (``search.phases.counters`` ``d2h_arrays_total``): over
+    ``merge.d2h``'s count, the arrays fetched a query."""
     t = tracer.start("merge.d2h")
-    arrays = [np.asarray(o) for o in outs]
+    arrays = jax.device_get(outs)
     tracer.stop("merge.d2h", t)
+    if telemetry is not None:
+        telemetry.add_counters({"d2h_arrays": (
+            1 if isinstance(arrays, np.ndarray) else len(arrays))})
     return arrays
+
+
+def _pack_answer(keys, slots, docs, total, scores, raws):
+    """The serial program's merged answer as ONE ``int32[2 + 5k]``
+    (device side), so that it reaches the host in one transfer: the
+    total's two words, then five rows of ``k``. Everything goes in by
+    its BITS — the float32 rows (``-inf`` keys of unfilled ranks,
+    negative zero and all) and the int64 total (the engine runs x64: a
+    sum of int32 counts is an int64) — nothing is converted."""
+    def words(x, dtype):
+        if x.dtype != dtype:
+            raise TypeError(f"{x.dtype} where the packed answer holds "
+                            f"{jnp.dtype(dtype)}: it would not survive "
+                            "bit for bit")
+        if dtype == jnp.int32:
+            return x
+        return jax.lax.bitcast_convert_type(x, jnp.int32).reshape(-1)
+
+    return jnp.concatenate([
+        words(total, jnp.int64), words(keys, jnp.float32),
+        words(slots, jnp.int32), words(docs, jnp.int32),
+        words(scores, jnp.float32), words(raws, jnp.float32)])
+
+
+def _unpack_answer(packed: np.ndarray):
+    """``_pack_answer`` undone on the host: (keys, slots, docs, total,
+    scores, raws) as views of the one array, no copy, no arithmetic."""
+    rows = packed[2:].reshape(5, -1)
+    floats = rows.view(np.float32)
+    return (floats[0], rows[1], rows[2], packed[:2].view(np.int64)[0],
+            floats[3], floats[4])
 
 
 class PlaneHealth:
@@ -572,8 +611,8 @@ def _mesh_query_program(mesh: Mesh, holder: _TemplateHolder, k: int,
             cand_raw = jnp.concatenate([o[3] for o in slot_out])
             all_raw = jax.lax.all_gather(cand_raw, "shards").reshape(-1)
             top_raw = all_raw[top_idx]
-        outs = [top_keys[None], top_slot[None], top_doc[None],
-                total[None], top_score[None], top_raw[None],
+        outs = [_pack_answer(top_keys, top_slot, top_doc, total,
+                             top_score, top_raw)[None],
                 counts]
         if with_views:
             outs.extend([jnp.stack([o[5] for o in slot_out]),
@@ -584,12 +623,12 @@ def _mesh_query_program(mesh: Mesh, holder: _TemplateHolder, k: int,
                         for j in range(n_agg))
         return tuple(outs)
 
-    # 6 replicated merge outputs; local_count (index 6), the optional
-    # views, and the fused-agg partials stay SHARDED (a row per slot)
+    # ONE replicated merge output (the packed answer); local_count
+    # (index 1), the optional views, and the fused-agg partials stay
+    # SHARDED (a row per slot)
     from elasticsearch_tpu.search.fused_aggs import n_agg_outputs
 
-    n_merged = 6
-    n_out = 7 + (2 if with_views else 0) + n_agg_outputs(agg_static)
+    n_out = 2 + (2 if with_views else 0) + n_agg_outputs(agg_static)
     mapped = shard_map(
         per_device, mesh=mesh,
         in_specs=(PS("shards"), PS("shards"), PS("shards"), PS("shards"),
@@ -604,10 +643,9 @@ def _mesh_query_program(mesh: Mesh, holder: _TemplateHolder, k: int,
         # every device operation: a trace's fusions say whose they are)
         with jax.named_scope("mesh_query"):
             outs = mapped(seg, plan_arrays, pf_arrays, rs_arrays, scalars)
-        # merged outputs are replicated (row 0 == row i); view outputs
-        # keep their sharded leading axis
-        merged = tuple(o[0] for o in outs[:n_merged])
-        return merged + tuple(outs[n_merged:])
+        # the packed answer is replicated (row 0 == row i); the other
+        # outputs keep their sharded leading axis
+        return (outs[0][0],) + tuple(outs[1:])
 
     from elasticsearch_tpu.common.compile_cache import (
         instrument_program,
@@ -1157,11 +1195,16 @@ class IndexMeshSearch:
     def staging_denied_reason(self, value) -> None:
         self._denied.reason = value
 
+    @property
+    def _telemetry(self):
+        """The index's SearchTelemetry (None for a bare test double)."""
+        return getattr(self.svc, "telemetry", None)
+
     def _note(self, plane: str, reason: str, n: int = 1) -> None:
         """Plane-ladder decision counter (search.phases.decisions).
         ``n``: member count — batch-path decisions count per QUERY so
         they stay comparable with the serial ladder's counts."""
-        tel = getattr(self.svc, "telemetry", None)
+        tel = self._telemetry
         if tel is not None:
             tel.note_decision(plane, reason, n)
 
@@ -1846,7 +1889,7 @@ class IndexMeshSearch:
                 deadline.checkpoint()
             on_kernel_launch(self.svc.name, "knn")
             outs = _launch_locked(bt, run, *args)
-            keys, docs, slots, totals = _fetch(bt, outs)
+            keys, docs, slots, totals = _fetch(bt, outs, self._telemetry)
         except (PlanStructureMismatch, NotImplementedError):
             self._note("mesh_pallas", "shape_mismatch", q_batch)
             return None  # shape ineligibility: next rung, no penalty
@@ -1897,7 +1940,7 @@ class IndexMeshSearch:
                             "max_score": max_score,
                             "plane": "mesh_pallas"})
         bt.stop("merge", t_merge)
-        tel = getattr(self.svc, "telemetry", None)
+        tel = self._telemetry
         if tel is not None:
             tel.add_counters(launch_adds)
         for q, tr in enumerate(tracers or []):
@@ -2282,11 +2325,12 @@ class IndexMeshSearch:
             return None
         # (no finally: an exception in here ends the request)
         t_merge = tracer.start_parent("merge")
-        # (the total too: int() of a device scalar is one more fetch)
-        keys, slots, docs, total, scores, raws = _fetch(tracer, outs[:6])
-        seg_counts = outs[6]
-        total = int(total)
+        # (the answer is ONE array, the total in it: one transfer)
+        packed = _fetch(tracer, outs[0], self._telemetry)
+        seg_counts = outs[1]
         t_assemble = tracer.start("merge.assemble")
+        keys, slots, docs, total, scores, raws = _unpack_answer(packed)
+        total = int(total)
         # terminate_after caps per SHARD (each shard's collector stops
         # after N docs) while a mesh device holds one SEGMENT: group the
         # per-device counts by shard before capping — host-path contract
@@ -2352,12 +2396,12 @@ class IndexMeshSearch:
                     finalize_fused,
                 )
 
-                agg_outs = [np.asarray(o) for o in outs[7:]]
+                agg_outs = [np.asarray(o) for o in outs[2:]]
                 aggregations = finalize_fused(agg_plan, agg_outs,
                                               len(executor.pairs))
                 with self._counter_lock:
                     self.agg_fused_query_total += 1
-                tel = getattr(self.svc, "telemetry", None)
+                tel = self._telemetry
                 if tel is not None:
                     # doc-value column bytes the fused launch read in
                     # place of the host round-trip (docs/AGGS.md)
@@ -2365,8 +2409,8 @@ class IndexMeshSearch:
                         "doc_values_bytes_streamed":
                             agg_plan.staged_bytes(executor._seg_staged)})
             else:
-                matched_np = np.asarray(outs[7])
-                scores_np = np.asarray(outs[8])
+                matched_np = np.asarray(outs[2])
+                scores_np = np.asarray(outs[3])
                 views = []
                 for i, (sid, seg) in enumerate(executor.pairs):
                     nd1 = seg.nd_pad + 1
@@ -2723,7 +2767,7 @@ class IndexMeshSearch:
                 on_kernel_launch(self.svc.name, "pruned")
                 outs = _launch_locked(bt, run, *args)
                 keys, docs, slots, totals, scored, tiles_total = _fetch(
-                    bt, outs)
+                    bt, outs, self._telemetry)
                 pruned_stats = {
                     "tiles_scored": int(scored),
                     "tiles_pruned": int(tiles_total) - int(scored),
@@ -2769,7 +2813,8 @@ class IndexMeshSearch:
                     deadline.checkpoint()
                 on_kernel_launch(self.svc.name, "batched")
                 outs = _launch_locked(bt, run, *args)
-                keys, docs, slots, totals, *agg_raw = _fetch(bt, outs)
+                keys, docs, slots, totals, *agg_raw = _fetch(
+                    bt, outs, self._telemetry)
                 wb = 4 if codec == "packed" else 8
                 launch_adds = {
                     "postings_bytes_streamed":
@@ -2791,7 +2836,8 @@ class IndexMeshSearch:
                     deadline.checkpoint()
                 on_kernel_launch(self.svc.name, "batched")
                 outs = _launch_locked(bt, run, *args)
-                keys, docs, slots, totals = _fetch(bt, outs)
+                keys, docs, slots, totals = _fetch(bt, outs,
+                                                   self._telemetry)
                 wb = 4 if codec == "packed" else 8
                 launch_adds = {
                     "postings_bytes_streamed":
@@ -2893,7 +2939,7 @@ class IndexMeshSearch:
         # launch-level byte/tile totals fold into the registry ONCE (a
         # batch must not multiply them); members see them as profile
         # annotations of the launch they shared
-        tel = getattr(self.svc, "telemetry", None)
+        tel = self._telemetry
         if tel is not None:
             tel.add_counters(launch_adds)
         for q, tr in enumerate(tracers or []):
@@ -4156,9 +4202,10 @@ class MeshPlanExecutor:
                 slice_col: Optional[str] = None,
                 rescore_static: Optional[Tuple[int, str]] = None,
                 tracer=None, agg_static: tuple = ()):
-        """plans: one per shard, same query. Returns (top_keys [k],
-        top_shard [k], top_doc [k], total, top_score [k], top_raw [k]
-        [, matched [n_dev, nd1], scores [n_dev, nd1]]
+        """plans: one per shard, same query. Returns (packed int32
+        [2 + 5k] — ``_unpack_answer`` gives top_keys [k], top_slot [k],
+        top_doc [k], total, top_score [k], top_raw [k] —, counts
+        [n_slots] [, matched [n_slots, nd1], scores [n_slots, nd1]]
         [, fused-agg partials...]) — doc ids are in the STACKED doc
         space (valid per-shard ids since every shard zero-bases).
 

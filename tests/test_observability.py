@@ -781,6 +781,9 @@ class TestRequestSpanTree:
                 assert {r[0] for r in rows.values() if r[3] == kernel} == {
                     "kernel.lock_wait", "kernel.dispatch",
                     "kernel.device_wait"}
+                # the launch's one fetch, once, beside its kernel
+                assert [r[3] for r in rows.values()
+                        if r[0] == "merge.d2h"] == [0]
                 assert t.annotations()["batch_size"] == 3
         finally:
             idx.close()

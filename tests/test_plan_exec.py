@@ -18,6 +18,7 @@ from elasticsearch_tpu.parallel.mesh import shard_mesh
 from elasticsearch_tpu.parallel.plan_exec import (
     MeshPlanExecutor,
     PlanStructureMismatch,
+    _unpack_answer,
     stack_plans,
 )
 from elasticsearch_tpu.search import plan as P
@@ -92,10 +93,10 @@ def host_reference(segments, ctxs, query_body, k):
 def mesh_result(executor, segments, ctxs, query_body, k):
     qb = parse_query(query_body)
     plans = [qb.to_plan(ctx, seg) for seg, ctx in zip(segments, ctxs)]
-    scores, shards, docs, total = executor.execute(plans, k)[:4]
+    scores, shards, docs, total = _unpack_answer(
+        np.asarray(executor.execute(plans, k)[0]))[:4]
     got = [(float(s), int(sh), int(d))
-           for s, sh, d in zip(np.asarray(scores), np.asarray(shards),
-                               np.asarray(docs)) if s > -np.inf]
+           for s, sh, d in zip(scores, shards, docs) if s > -np.inf]
     return int(total), got
 
 

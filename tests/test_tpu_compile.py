@@ -318,7 +318,7 @@ def _compiled_for_tpu(build_program, seen, devices, seg_shapes=None):
                                          sharding=sharded)
     return program.__wrapped__.lower(
         seg, *(shapes(t, sharded) for t in per_slot),
-        shapes(scalars, replicated)).compile().as_text()
+        shapes(scalars, replicated)).compile()
 
 
 def test_serial_mesh_program_compiles_for_four_chips(topo, monkeypatch):
@@ -326,7 +326,7 @@ def test_serial_mesh_program_compiles_for_four_chips(topo, monkeypatch):
     Mesh of the four described devices."""
     build_program, seen = _recorded_serial_launch(
         monkeypatch, n_shards=8, n_devices=4, n_docs=6000)
-    text = _compiled_for_tpu(build_program, seen, topo.devices)
+    text = _compiled_for_tpu(build_program, seen, topo.devices).as_text()
     assert "tpu_custom_call" in text
     assert "all-gather" in text
 
@@ -343,6 +343,36 @@ def test_serial_mesh_program_reads_the_cells_tables_in_place(
     assert seen["kwargs"]["spd"] == CELL_SLOTS
     flat = (CELL_SLOTS * CELL_ROWS_PAD, psc.LANE)
     text = _compiled_for_tpu(build_program, seen, topo.devices[:1],
-                             {"k_docs": flat, "k_frac": flat})
+                             {"k_docs": flat, "k_frac": flat}).as_text()
     assert text.count("tpu_custom_call") >= CELL_SLOTS
     assert _table_sized_ops(text) == []
+
+
+@pytest.mark.parametrize("chips, n_shards, n_docs",
+                         [(1, 2, 1500), (4, 8, 6000)])
+def test_serial_mesh_program_has_one_replicated_merge_output(
+        topo, monkeypatch, chips, n_shards, n_docs):
+    """A query's merged answer leaves the device as ONE array: the
+    program's first output is the packed ``int32[2 + 5k]`` (the total's
+    two words, five rows of ``k``), on every chip alike; what follows
+    keeps a row per slot (the counts; views and fused partials where a
+    request asks for them) and is fetched only by the requests that
+    need it."""
+    import numpy as np
+
+    from elasticsearch_tpu.parallel import plan_exec
+
+    build_program, seen = _recorded_serial_launch(
+        monkeypatch, n_shards=n_shards, n_devices=chips, n_docs=n_docs)
+    compiled = _compiled_for_tpu(build_program, seen, topo.devices[:chips])
+    k = seen["args"][0]
+    n_slots = chips * seen["kwargs"]["spd"]
+    packed, counts = compiled.out_info
+    assert (packed.shape, packed.dtype) == ((2 + 5 * k,), np.int32)
+    assert (counts.shape, counts.dtype) == ((n_slots,), np.int64)
+    packed_sharding, counts_sharding = compiled.output_shardings
+    assert packed_sharding.is_fully_replicated
+    assert chips == 1 or not counts_sharding.is_fully_replicated
+    # the host's unpack reads that very layout
+    rows = plan_exec._unpack_answer(np.zeros(packed.shape, packed.dtype))
+    assert [r.shape for r in rows] == [(k,), (k,), (k,), (), (k,), (k,)]
