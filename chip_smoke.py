@@ -121,9 +121,9 @@ class Client:
 
 
 class Corpus:
-    """Seeded zipfian corpus in the shape bench.py's synthetic one has:
-    lognormal lengths around 80 tokens over a 50,000-term vocabulary,
-    one of 2,000 zipfian keyword values and a seeded integer per doc."""
+    """Seeded zipfian corpus: lognormal lengths around 80 tokens over a
+    50,000-term vocabulary, one of 2,000 zipfian keyword values and a
+    seeded integer per doc."""
 
     def __init__(self, n_docs: int, seed: int, n_shards: int):
         from elasticsearch_tpu.utils.murmur3 import shard_id_for

@@ -80,7 +80,7 @@ def warming():
 
 def checkout_cache_dir() -> str:
     """``<checkout>/.jax_cache``: where the entry scripts that run on
-    the chip (``chip_smoke.py``, ``bench.py``) keep the cache when
+    the chip (``chip_smoke.py``, ``benchmark/run.py``) keep the cache when
     ``JAX_COMPILATION_CACHE_DIR`` does not place it. Fixed, because the
     path is part of the cache key: a directory that moves never hits."""
     package = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -228,7 +228,7 @@ def variant_registry() -> VariantRegistry:
 
 def set_variant_registry(registry: VariantRegistry) -> VariantRegistry:
     """Install the node's persisted registry (last constructed node
-    wins, like the ES_TPU_* env exports — one registry per process)."""
+    wins — one registry per process)."""
     global _REGISTRY
     with _REGISTRY_LOCK:
         _REGISTRY = registry

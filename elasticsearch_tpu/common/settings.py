@@ -170,6 +170,56 @@ class Settings:
 Settings.EMPTY = Settings()
 
 
+class LayeredSettings:
+    """Live read view over ``Settings`` sources in precedence order: a
+    typed getter answers from the first source that HAS the key, else
+    the default. The one place the precedence "explicit cluster value,
+    then the index's (or node's) own Settings, then the default" is
+    written; a reader calls ``get_*`` and does not know there are layers.
+
+    Each source is a zero-argument callable returning the current
+    ``Settings`` (both layers are replaced, not mutated: a cluster PUT
+    swaps the explicit map, an index-settings update swaps the index's),
+    so a view handed out once stays live."""
+
+    def __init__(self, *sources: Callable[[], Settings]):
+        self._sources = sources
+
+    def _having(self, key: str) -> Settings:
+        for source in self._sources:
+            settings = source()
+            if settings.get(key) is not None:
+                return settings
+        return Settings.EMPTY
+
+    def get_str(self, key: str, default: Optional[str] = None) -> Optional[str]:
+        return self._having(key).get_str(key, default)
+
+    def get_int(self, key: str, default: Optional[int] = None) -> Optional[int]:
+        return self._having(key).get_int(key, default)
+
+    def get_float(self, key: str, default: Optional[float] = None) -> Optional[float]:
+        return self._having(key).get_float(key, default)
+
+    def get_bool(self, key: str, default: Optional[bool] = None) -> Optional[bool]:
+        return self._having(key).get_bool(key, default)
+
+    def get_time(self, key: str, default: Optional[float] = None) -> Optional[float]:
+        return self._having(key).get_time(key, default)
+
+    def get_bytes(self, key: str, default: Optional[int] = None) -> Optional[int]:
+        return self._having(key).get_bytes(key, default)
+
+
+# Node-scope key prefixes that Node.create_index seeds into every new
+# index's own Settings (node file under the live cluster value under the
+# index's own), so a per-index reader finds them in one map.
+INDEX_SEEDED_PREFIXES = (
+    "search.batch.", "search.pallas.", "search.knn.", "search.aggs.",
+    "search.telemetry.", "search.queue.", "search.admission.",
+    "search.drain.", "index.staging.")
+
+
 class Scope:
     NODE = "node"
     INDEX = "index"
@@ -469,14 +519,6 @@ RECOVERY_MAX_RETRIES = Setting.int_setting(
 RECOVERY_ACTION_TIMEOUT = Setting.time_setting(
     "indices.recovery.internal_action_timeout", "30s", dynamic=True
 )
-def _validate_tiles_per_step(v):
-    # must divide the power-of-two tile counts the kernel produces; the
-    # kernel helper only honors these values, so reject everything else
-    # here instead of silently running with 1
-    if v not in (1, 2, 4, 8):
-        raise IllegalArgumentException(
-            f"Failed to parse value [{v}] for setting "
-            f"[search.pallas.tiles_per_step]: must be one of 1, 2, 4, 8")
 
 
 # --- cross-query micro-batching (search/batching.py; docs/BATCHING.md) ---
@@ -550,15 +592,6 @@ SEARCH_ADMISSION_BROWNOUT_FEATURES = Setting.float_setting(
     dynamic=True
 )
 
-SEARCH_PALLAS_TILES_PER_STEP = Setting(
-    # TPU-specific DMA buffering toggle: tiles folded into one grid step
-    # of the tile-scoring kernel (ops/pallas_scoring.py) so their posting-
-    # window DMAs double-buffer against compute; exported to the kernel
-    # via ES_TPU_PALLAS_TPS at node startup. 1 = historical behavior.
-    "search.pallas.tiles_per_step", 1, int,
-    validator=_validate_tiles_per_step,
-)
-
 # --- postings codec + block-max pruned scoring (docs/PRUNING.md) ---
 
 SEARCH_PALLAS_POSTINGS_CODEC = Setting.str_setting(
@@ -567,7 +600,8 @@ SEARCH_PALLAS_POSTINGS_CODEC = Setting.str_setting(
     # (historical, bit-exact); "packed" = one bit-packed i32 word per
     # posting (half the staged bytes AND half the per-query posting DMA
     # traffic; frac quantized to 12 bits — see docs/PRUNING.md for the
-    # parity trade-off). Exported via ES_TPU_PALLAS_CODEC at startup;
+    # parity trade-off). Static: the Node lays its file's value over each
+    # index's Settings at creation and at recovery from disk;
     # index.search.pallas.postings_codec overrides per index.
     "search.pallas.postings_codec", "raw", choices={"raw", "packed"},
 )
@@ -575,11 +609,7 @@ SEARCH_PALLAS_POSTINGS_CODEC = Setting.str_setting(
 
 def _validate_probe_tiles(v):
     # probe counts are shape-bucketed into the compiled pruned program;
-    # powers of two keep the variant count bounded. NB the probe/rest
-    # subset sizes need not divide search.pallas.tiles_per_step — the
-    # kernel clamps tps down to a divisor per launch, so small probe
-    # values (2, 4) quietly reduce the DMA double-buffering depth of the
-    # pruned passes (see score_tiles).
+    # powers of two keep the variant count bounded.
     if v not in (2, 4, 8, 16, 32):
         raise IllegalArgumentException(
             f"Failed to parse value [{v}] for setting "
@@ -755,7 +785,6 @@ NODE_SETTINGS = [
     SEARCH_ADMISSION_BROWNOUT_PRUNED,
     SEARCH_ADMISSION_BROWNOUT_RESCORE,
     SEARCH_ADMISSION_BROWNOUT_FEATURES,
-    SEARCH_PALLAS_TILES_PER_STEP,
     SEARCH_PALLAS_POSTINGS_CODEC,
     SEARCH_PALLAS_PRUNING_ENABLED,
     SEARCH_PALLAS_PRUNING_PROBE_TILES,

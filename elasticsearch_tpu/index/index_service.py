@@ -26,7 +26,9 @@ from elasticsearch_tpu.common.errors import (
 from elasticsearch_tpu.common.settings import (
     INDEX_NUMBER_OF_REPLICAS,
     INDEX_NUMBER_OF_SHARDS,
+    INDEX_SEEDED_PREFIXES,
     INDEX_TRANSLOG_DURABILITY,
+    LayeredSettings,
     Settings,
 )
 from elasticsearch_tpu.common.integrity import integrity_service
@@ -46,6 +48,12 @@ class IndexService:
         # document/search/mapping responses; _doc canonical)
         self.doc_type = "_doc"
         self.settings = settings
+        # the one way a dynamic search setting reaches its reader: the
+        # explicit cluster values (set_cluster_overrides), then this
+        # index's own Settings, then the reader's default
+        self.cluster_explicit = Settings.EMPTY
+        self.live = LayeredSettings(lambda: self.cluster_explicit,
+                                    lambda: self.settings)
         self.creation_date = int(time.time() * 1000)
         self.uuid = f"{name}-{self.creation_date:x}"
         self.num_shards = INDEX_NUMBER_OF_SHARDS.get(settings)
@@ -75,6 +83,15 @@ class IndexService:
             "index.indexing.slowlog.threshold.index.info")
         idx_slow_source = settings.get_int("index.indexing.slowlog.source", 1000)
         gc_deletes = settings.get_time("index.gc_deletes")
+        # postings codec preference for the tile-kernel staging
+        # (docs/PRUNING.md): the index key unless "default", else the
+        # node's search.pallas.postings_codec, which the Node lays over
+        # these Settings at creation and at recovery, else raw
+        self.postings_codec_pref = settings.get_str(
+            "index.search.pallas.postings_codec", "default")
+        if self.postings_codec_pref == "default":
+            self.postings_codec_pref = settings.get_str(
+                "search.pallas.postings_codec", "raw")
         self.shards: Dict[int, IndexShard] = {}
         for sid in range(self.num_shards):
             shard_path = os.path.join(data_path, str(sid)) if data_path else None
@@ -88,11 +105,7 @@ class IndexService:
                                indexing_slowlog_source_chars=idx_slow_source)
             if gc_deletes is not None:
                 shard.engine.gc_deletes = gc_deletes
-            # postings codec preference for the tile-kernel staging
-            # (index.search.pallas.postings_codec; docs/PRUNING.md):
-            # "default" follows the node-wide ES_TPU_PALLAS_CODEC export
-            shard.engine.postings_codec = settings.get_str(
-                "index.search.pallas.postings_codec", "default")
+            shard.engine.postings_codec = self.postings_codec_pref
             # slice resolution is shard-count-aware (SliceBuilder)
             shard.searcher.num_shards = self.num_shards
             shard.searcher.max_slices = settings.get_int(
@@ -149,7 +162,7 @@ class IndexService:
             SearchAdmissionController,
         )
 
-        self.admission = SearchAdmissionController(name, settings)
+        self.admission = SearchAdmissionController(name, self.live)
         self._batcher.window_fn = (
             lambda: self.admission.effective_batch_window_s(
                 self._batcher.window_s))
@@ -218,12 +231,11 @@ class IndexService:
         # background store/device scrubber (ISSUE 16, docs/RESILIENCE.md
         # "Data integrity"): index.scrub.interval, off by default. The
         # thread always runs (cheap idle poll) so turning the knob on
-        # dynamically — via _settings or the cluster-level override —
+        # dynamically — via _settings or the cluster-level value —
         # needs no thread lifecycle management; each wake re-reads the
         # effective interval.
         import threading as _scrub_threading
 
-        self.scrub_interval_override: Optional[float] = None
         self._scrub_stop = _scrub_threading.Event()
         _scrub_threading.Thread(target=self._scrub_loop, daemon=True,
                                 name=f"scrub[{name}]").start()
@@ -231,8 +243,6 @@ class IndexService:
         # mesh plane nudges maybe_compact_async() after a delta commit;
         # the lock makes the pass single-flight (a second trigger while
         # one runs is a no-op, never a queue)
-        self.staging_delta_enabled_override: Optional[bool] = None
-        self.staging_compact_threshold_override: Optional[float] = None
         self._compact_lock = _scrub_threading.Lock()
         self._closing = False
 
@@ -300,13 +310,20 @@ class IndexService:
         store.clear_corruption_markers()
         shard.store_corrupted = False
 
+    def set_cluster_overrides(self, committed: Settings) -> None:
+        """Install the committed cluster settings' EXPLICIT per-index
+        keys as the top layer of ``self.live``: the whole map each time,
+        so a key cleared from the cluster settings hands control back to
+        this index's own Settings. Called by Node for every index on PUT
+        _cluster/settings and for a new index at creation."""
+        prefixes = INDEX_SEEDED_PREFIXES + ("index.scrub.interval",)
+        self.cluster_explicit = Settings({
+            key: committed.get(key) for key in committed.keys()
+            if key.startswith(prefixes)})
+
     def _scrub_effective_interval(self) -> Optional[float]:
-        """Cluster-level override wins when an operator committed one
-        (explicitness contract, mirroring the other dynamic knobs);
-        otherwise the index setting. None/<=0 disables."""
-        if self.scrub_interval_override is not None:
-            return self.scrub_interval_override
-        return self.settings.get_time("index.scrub.interval")
+        """index.scrub.interval; None/<=0 disables."""
+        return self.live.get_time("index.scrub.interval")
 
     def _scrub_loop(self) -> None:
         import logging
@@ -400,11 +417,8 @@ class IndexService:
     # ------------------------------------------------------------------
 
     def _compact_threshold(self) -> float:
-        """index.staging.compact.threshold with the explicitness-aware
-        cluster override on top; <= 0 disables compaction."""
-        if self.staging_compact_threshold_override is not None:
-            return float(self.staging_compact_threshold_override)
-        return float(self.settings.get_float(
+        """index.staging.compact.threshold; <= 0 disables compaction."""
+        return float(self.live.get_float(
             "index.staging.compact.threshold", 0.25))
 
     def _compaction_due(self) -> bool:
@@ -722,13 +736,8 @@ class IndexService:
 
     def _telemetry_enabled(self) -> bool:
         """search.telemetry.enabled — the dynamic kill switch for the
-        always-on phase tracer (docs/OBSERVABILITY.md). A cluster-level
-        PUT wins while explicitly set (same explicitness contract as
-        search.pallas.pruning.* — synced in put_cluster_settings)."""
-        override = getattr(self, "telemetry_enabled_override", None)
-        if override is not None:
-            return bool(override)
-        return self.settings.get_bool("search.telemetry.enabled", True)
+        always-on phase tracer (docs/OBSERVABILITY.md)."""
+        return self.live.get_bool("search.telemetry.enabled", True)
 
     def _tracer(self):
         """One QueryTracer per request (NULL_TRACER when the kill switch

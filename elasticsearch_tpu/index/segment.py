@@ -536,10 +536,10 @@ class Segment:
         # postings codec (ISSUE 6, docs/PRUNING.md): "packed" stages ONE
         # bit-packed i32 word per posting instead of the (docs i32,
         # frac f32) pair — half the staged postings bytes AND half the
-        # per-query posting-window DMA traffic. Preference order: the
-        # per-segment stamp (engine inherits the index setting), else
-        # the node default (ES_TPU_PALLAS_CODEC), demoted to raw when
-        # the doc space exceeds the packed word's doc capacity.
+        # per-query posting-window DMA traffic. The preference is the
+        # per-segment stamp (the engine inherits the index's resolved
+        # setting; no stamp is raw), demoted to raw when the doc space
+        # exceeds the packed word's doc capacity.
         codec = psc.resolve_postings_codec(
             getattr(self, "postings_codec", None), self.nd_pad)
         # stage fully, then publish atomically: a concurrent search thread

@@ -34,7 +34,10 @@ from elasticsearch_tpu.common import monitor
 from elasticsearch_tpu.common.settings import (
     CLUSTER_NAME,
     NODE_NAME,
+    INDEX_SEEDED_PREFIXES,
+    LayeredSettings,
     PATH_DATA,
+    SEARCH_PALLAS_POSTINGS_CODEC,
     Settings,
     cluster_settings,
     index_scoped_settings,
@@ -52,30 +55,22 @@ class Node:
                  data_path: Optional[str] = None,
                  plugins: Optional[list] = None):
         self.settings = settings
+        # process-level dynamic settings (REST search queue, HBM budget,
+        # staging retry): the cluster settings' explicit values win,
+        # clearing one reverts to the node file (put_cluster_settings)
+        self.live = LayeredSettings(self._committed_cluster_settings,
+                                    lambda: self.settings)
+        # search.pallas.postings_codec (docs/PRUNING.md) is static and
+        # node-scope: an index whose own key is "default" follows THIS
+        # node's file, so it is laid over every index's Settings, at
+        # creation and again at recovery from disk
+        self._node_codec = Settings({
+            SEARCH_PALLAS_POSTINGS_CODEC.key:
+                SEARCH_PALLAS_POSTINGS_CODEC.get(settings)})
         self.node_id = _uuid.uuid4().hex[:20]
         self.node_name = NODE_NAME.get(settings)
         self.cluster_settings = cluster_settings()
         self.index_scoped_settings = index_scoped_settings()
-        # kernel DMA-buffering toggle: exported once at startup; the
-        # pallas layer reads ES_TPU_PALLAS_TPS (see settings registry)
-        from elasticsearch_tpu.common.settings import (
-            SEARCH_PALLAS_TILES_PER_STEP,
-        )
-
-        # exported unconditionally: a later Node in the same process must
-        # not inherit a previous Node's value through a stale env var
-        # (the env var is process-global — the last-constructed Node wins)
-        os.environ["ES_TPU_PALLAS_TPS"] = str(
-            int(SEARCH_PALLAS_TILES_PER_STEP.get(settings)))
-        # node-wide postings-codec default for the kernel staging
-        # (search.pallas.postings_codec; per-index override via
-        # index.search.pallas.postings_codec — docs/PRUNING.md)
-        from elasticsearch_tpu.common.settings import (
-            SEARCH_PALLAS_POSTINGS_CODEC,
-        )
-
-        os.environ["ES_TPU_PALLAS_CODEC"] = str(
-            SEARCH_PALLAS_POSTINGS_CODEC.get(settings))
         # cross-query micro-batching knobs are DYNAMIC (docs/BATCHING.md):
         # a cluster-settings update must reach every index's live batcher
         # (an operator disabling batching mid-incident can't wait for a
@@ -102,44 +97,19 @@ class Node:
         self.cluster_settings.add_settings_update_consumer(
             SEARCH_BATCH_MAX_QUERIES,
             _batchers(lambda b, v: setattr(b, "max_queries", int(v))))
-        # (block-max pruning knobs are dynamic too, but they need
-        # EXPLICITNESS — an override must clear when the cluster key is
-        # removed so the index's own Settings win again — which the
-        # value-only consumer callback can't see; put_cluster_settings
-        # syncs svc.pruning_*_override from the committed merged
-        # settings instead. docs/PRUNING.md)
-        # device-staging retry knobs (search.staging.retry.* — ISSUE 10,
-        # docs/RESILIENCE.md): seed the process-level config from the
-        # node file and keep it live under PUT _cluster/settings (the
-        # explicitness-aware clear is synced in put_cluster_settings)
-        from elasticsearch_tpu.common.settings import (
-            SEARCH_STAGING_RETRY_BACKOFF_MS,
-            SEARCH_STAGING_RETRY_MAX_ATTEMPTS,
-        )
-
-        from elasticsearch_tpu.common.staging import configure_staging_retry
-
-        configure_staging_retry(
-            max_attempts=settings.get_int(
-                "search.staging.retry.max_attempts",
-                SEARCH_STAGING_RETRY_MAX_ATTEMPTS.default),
-            backoff_ms=settings.get_float(
-                "search.staging.retry.backoff_ms",
-                SEARCH_STAGING_RETRY_BACKOFF_MS.default))
-        self.cluster_settings.add_settings_update_consumer(
-            SEARCH_STAGING_RETRY_MAX_ATTEMPTS,
-            lambda v: configure_staging_retry(max_attempts=int(v)))
-        self.cluster_settings.add_settings_update_consumer(
-            SEARCH_STAGING_RETRY_BACKOFF_MS,
-            lambda v: configure_staging_retry(backoff_ms=float(v)))
+        # (every other dynamic search setting needs EXPLICITNESS — a
+        # cluster value must clear when its key is removed so the
+        # index's own Settings win again — which a value-only consumer
+        # can't see: put_cluster_settings hands each index the committed
+        # settings and readers go through IndexService.live)
         self.data_path = data_path or PATH_DATA.get(settings)
         self.persistent_path = data_path is not None or "path.data" in settings
         # zero-downtime rollout (ISSUE 14, docs/RESILIENCE.md "Rollout &
         # drain"): enable JAX's persistent compilation cache
         # (search.compile.cache_path) and install the program-variant
         # registry persisted beside the store, so restart never pays a
-        # query-path first compile. Like the ES_TPU_* exports, the
-        # process-global registry follows the last-constructed Node.
+        # query-path first compile. The process-global registry follows
+        # the last-constructed Node.
         from elasticsearch_tpu.common import compile_cache as _cc
 
         cache_path = settings.get_str("search.compile.cache_path", "")
@@ -173,26 +143,14 @@ class Node:
         # handler work on the action's pool; full queues reject with 429
         from elasticsearch_tpu.common.thread_pool import ThreadPool
 
-        # search.queue.size bounds BOTH backpressure points the same way
-        # (docs/OVERLOAD.md): the REST-layer search executor queue here
-        # and each index's admission queue (search/admission.py) — and
-        # a dynamic update below retargets the live pool too, so the
-        # contract survives PUT _cluster/settings mid-incident
-        self.thread_pool = ThreadPool(overrides={
-            "search": {"queue_size": settings.get_int(
-                "search.queue.size", 1000)}})
+        # (the search executor's queue is sized from search.queue.size
+        # by _sync_process_settings, here and on every cluster PUT)
+        self.thread_pool = ThreadPool()
         from elasticsearch_tpu.common.breaker import configure_breaker_service
 
         # hierarchical memory circuit breakers (indices.breaker.*)
         self.breaker_service = configure_breaker_service(settings)
-        # device-memory accountant budget (search.memory.hbm_budget_bytes,
-        # ISSUE 9): the exact HBM staging ledger is wired in as the real
-        # "accounting" breaker child; over budget, stagings LRU-evict then
-        # demote to the host rung (never 429/5xx) — docs/OBSERVABILITY.md
-        from elasticsearch_tpu.common.memory import memory_accountant
-
-        memory_accountant().set_budget(
-            settings.get_bytes("search.memory.hbm_budget_bytes", 0))
+        self._sync_process_settings()
         self.indices: Dict[str, IndexService] = {}
         self.ingest = IngestService(self)
         self.tasks = TaskManager(self.node_id)
@@ -296,40 +254,25 @@ class Node:
         # must honor the live value, not the node file's (the update
         # consumers only reach batchers alive at update time; the pruning
         # knobs are re-read per query from the index's Settings map)
-        state = self.cluster_service.state
+        cluster_dynamic = self._committed_cluster_settings()
         # (search.staging.retry.* deliberately NOT seeded per index: the
         # retry config is process-level — a create-time snapshot in the
         # index Settings would shadow later dynamic cluster updates)
-        for prefix in ("search.batch.", "search.pallas.", "search.knn.",
-                       "search.aggs.", "search.telemetry.",
-                       "search.queue.", "search.admission.",
-                       "search.drain.", "index.staging."):
-            cluster_dynamic = state.persistent_settings.merged_with(
-                state.transient_settings).filtered_by_prefix(prefix)
+        for prefix in INDEX_SEEDED_PREFIXES:
             merged_settings = self.settings.filtered_by_prefix(
-                prefix).merged_with(cluster_dynamic).merged_with(
+                prefix).merged_with(
+                cluster_dynamic.filtered_by_prefix(prefix)).merged_with(
                 merged_settings)
+        merged_settings = merged_settings.merged_with(self._node_codec)
 
         self.index_scoped_settings.validate(merged_settings, allow_unknown=True)
         svc = IndexService(name, merged_settings, merged_mappings,
                            self._index_data_path(name))
         svc.doc_type = doc_type  # 6.x custom type name echoed in responses
-        # an index created AFTER a cluster-level index.staging.* commit
-        # must honor the live override like its older peers (the
-        # put_cluster_settings sync only reaches indices alive then)
-        from elasticsearch_tpu.common.settings import (
-            INDEX_STAGING_COMPACT_THRESHOLD,
-            INDEX_STAGING_DELTA_ENABLED,
-        )
-
-        committed = state.persistent_settings.merged_with(
-            state.transient_settings)
-        if committed.get(INDEX_STAGING_DELTA_ENABLED.key) is not None:
-            svc.staging_delta_enabled_override = (
-                INDEX_STAGING_DELTA_ENABLED.get(committed))
-        if committed.get(INDEX_STAGING_COMPACT_THRESHOLD.key) is not None:
-            svc.staging_compact_threshold_override = (
-                INDEX_STAGING_COMPACT_THRESHOLD.get(committed))
+        # an index created AFTER a cluster-level commit follows the live
+        # explicit values like its older peers (the put_cluster_settings
+        # sync only reaches indices alive then)
+        svc.set_cluster_overrides(cluster_dynamic)
         if self._draining:
             # an index created while the node drains (auto-create from a
             # straggling write) joins the drain: its searches get the
@@ -521,7 +464,10 @@ class Node:
                 continue
             with open(meta_path, encoding="utf-8") as f:
                 meta = json.load(f)
-            settings = Settings(meta.get("settings", {}))
+            # (what was seeded under another node file does not outlive
+            # the restart: see _node_codec)
+            settings = Settings(meta.get("settings", {})).merged_with(
+                self._node_codec)
             svc = IndexService(name, settings, meta.get("mappings"),
                                self._index_data_path(name))
             self.indices[name] = svc
@@ -1679,9 +1625,18 @@ class Node:
         self.cluster_service.submit_state_update_task("update-aliases", update)
         return {"acknowledged": True}
 
+    def _committed_cluster_settings(self) -> Settings:
+        state = self.cluster_service.state
+        return state.persistent_settings.merged_with(
+            state.transient_settings)
+
     def put_cluster_settings(self, body: dict) -> dict:
         persistent = Settings.from_dict(body.get("persistent") or {})
         transient = Settings.from_dict(body.get("transient") or {})
+        # a malformed value is refused before anything is committed
+        for scoped in (self.cluster_settings, self.index_scoped_settings):
+            scoped.validate(persistent, allow_unknown=True)
+            scoped.validate(transient, allow_unknown=True)
 
         def update(state: ClusterState) -> ClusterState:
             new = state.copy()
@@ -1694,127 +1649,49 @@ class Node:
 
         self.cluster_service.submit_state_update_task("update-settings", update)
         state = self.cluster_service.state
+        committed = self._committed_cluster_settings()
         # dynamic remote-cluster registration (search.remote.<alias>.seeds)
-        self.remote_clusters.apply_settings(
-            state.persistent_settings.merged_with(state.transient_settings))
-        # block-max pruning overrides (docs/PRUNING.md): win over each
-        # index's creation-time Settings while EXPLICITLY set in the
-        # cluster settings, and clear back to None (index settings win
-        # again) when absent — synced here from the committed state
-        # because the value-only update consumers can't see explicitness
-        from elasticsearch_tpu.common.settings import (
-            SEARCH_AGGS_FUSED,
-            SEARCH_KNN_ENABLED,
-            SEARCH_KNN_TILE_SUB,
-            SEARCH_PALLAS_PRUNING_ENABLED,
-            SEARCH_PALLAS_PRUNING_PROBE_TILES,
-            SEARCH_TELEMETRY_ENABLED,
-        )
-
-        committed = state.persistent_settings.merged_with(
-            state.transient_settings)
-        for setting, attr in (
-                (SEARCH_PALLAS_PRUNING_ENABLED,
-                 "pruning_enabled_override"),
-                (SEARCH_PALLAS_PRUNING_PROBE_TILES,
-                 "pruning_probe_override"),
-                # kNN plane knobs share the explicitness contract: the
-                # cluster-level value wins while set, and clearing it
-                # hands control back to the index's own Settings
-                (SEARCH_KNN_ENABLED, "knn_enabled_override"),
-                (SEARCH_KNN_TILE_SUB, "knn_tile_sub_override"),
-                # fused on-device aggregations (ISSUE 13, docs/AGGS.md):
-                # same explicitness contract — the cluster value wins
-                # while set, clearing reverts to index/node settings
-                (SEARCH_AGGS_FUSED, "aggs_fused_override"),
-                # telemetry kill switch follows the same explicitness
-                # contract (docs/OBSERVABILITY.md)
-                (SEARCH_TELEMETRY_ENABLED, "telemetry_enabled_override")):
-            explicit = committed.get(setting.key) is not None
-            value = setting.get(committed) if explicit else None
-            for svc in self.indices.values():
-                setattr(svc, attr, value)
-        # overload-control knobs (search.queue.* / search.admission.* /
-        # search.batch.max_window_ms — ISSUE 12, docs/OVERLOAD.md) share
-        # the explicitness contract: each live admission controller
-        # installs the committed cluster settings' EXPLICIT keys as
-        # overrides; a cleared key hands control back to the index's own
-        # Settings map. (The controller reads its config live, so no
-        # value-only update consumers are needed.)
+        self.remote_clusters.apply_settings(committed)
+        # an EXPLICIT cluster value wins over each index's (or the node
+        # file's) own, and clearing it hands control back: every index
+        # takes the committed map as the top layer of its live view, as
+        # the node's own view has it (the value-only update consumers
+        # can't see explicitness)
         for svc in self.indices.values():
-            svc.admission.set_cluster_overrides(committed)
-        # the REST search pool's queue moves with the same key (the
-        # "both backpressure points" contract, docs/OVERLOAD.md):
-        # explicit cluster value wins, clearing reverts to the node file
-        qsize_key = "search.queue.size"
-        qsize_src = (committed if committed.get(qsize_key) is not None
-                     else self.settings)
-        self.thread_pool.executor("search").resize_queue(
-            qsize_src.get_int(qsize_key, 1000))
-        # HBM budget (search.memory.hbm_budget_bytes): the accountant is
-        # a process resource — an explicit cluster-level value wins, and
-        # clearing it reverts to the node-file setting; lowering the
-        # budget LRU-evicts immediately (set_budget → enforce_budget)
-        from elasticsearch_tpu.common.memory import memory_accountant
-
-        budget_key = "search.memory.hbm_budget_bytes"
-        if committed.get(budget_key) is not None:
-            memory_accountant().set_budget(
-                committed.get_bytes(budget_key, 0))
-        else:
-            memory_accountant().set_budget(
-                self.settings.get_bytes(budget_key, 0))
-        # device-staging retry knobs (search.staging.retry.*): explicit
-        # cluster values win; clearing them reverts to the node file
-        # (the value-only update consumers can't see explicitness)
-        from elasticsearch_tpu.common.settings import (
-            SEARCH_STAGING_RETRY_BACKOFF_MS,
-            SEARCH_STAGING_RETRY_MAX_ATTEMPTS,
-        )
-
-        from elasticsearch_tpu.common.staging import configure_staging_retry
-
-        for setting, kw in (
-                (SEARCH_STAGING_RETRY_MAX_ATTEMPTS, "max_attempts"),
-                (SEARCH_STAGING_RETRY_BACKOFF_MS, "backoff_ms")):
-            source = (committed if committed.get(setting.key) is not None
-                      else self.settings)
-            configure_staging_retry(**{kw: setting.get(source)})
-        # background integrity scrubber cadence (index.scrub.interval,
-        # ISSUE 16, docs/RESILIENCE.md "Data integrity"): same
-        # explicitness contract — an explicit cluster value overrides
-        # every index's own setting, clearing hands control back
-        from elasticsearch_tpu.common.settings import INDEX_SCRUB_INTERVAL
-
-        scrub_explicit = committed.get(INDEX_SCRUB_INTERVAL.key) is not None
-        scrub_value = (INDEX_SCRUB_INTERVAL.get(committed)
-                       if scrub_explicit else None)
-        for svc in self.indices.values():
-            svc.scrub_interval_override = scrub_value
-        # delta device staging knobs (index.staging.*, ISSUE 20): same
-        # explicitness contract — an explicit cluster value overrides
-        # every index's own setting, clearing hands control back
-        from elasticsearch_tpu.common.settings import (
-            INDEX_STAGING_COMPACT_THRESHOLD,
-            INDEX_STAGING_DELTA_ENABLED,
-        )
-
-        delta_explicit = (
-            committed.get(INDEX_STAGING_DELTA_ENABLED.key) is not None)
-        delta_value = (INDEX_STAGING_DELTA_ENABLED.get(committed)
-                       if delta_explicit else None)
-        compact_explicit = (
-            committed.get(INDEX_STAGING_COMPACT_THRESHOLD.key) is not None)
-        compact_value = (INDEX_STAGING_COMPACT_THRESHOLD.get(committed)
-                         if compact_explicit else None)
-        for svc in self.indices.values():
-            svc.staging_delta_enabled_override = delta_value
-            svc.staging_compact_threshold_override = compact_value
+            svc.set_cluster_overrides(committed)
+        self._sync_process_settings()
         return {
             "acknowledged": True,
             "persistent": state.persistent_settings.as_nested_dict(),
             "transient": state.transient_settings.as_nested_dict(),
         }
+
+    def _sync_process_settings(self) -> None:
+        """Point the process-level resources at ``self.live``: the REST
+        search pool's queue (search.queue.size bounds both backpressure
+        points, docs/OVERLOAD.md), the device-memory accountant's HBM
+        budget (ISSUE 9; lowering it LRU-evicts at once, over budget
+        stagings demote to the host rung — docs/OBSERVABILITY.md) and
+        the device-staging retry config (ISSUE 10, docs/RESILIENCE.md)."""
+        from elasticsearch_tpu.common.memory import memory_accountant
+        from elasticsearch_tpu.common.settings import (
+            SEARCH_STAGING_RETRY_BACKOFF_MS,
+            SEARCH_STAGING_RETRY_MAX_ATTEMPTS,
+        )
+        from elasticsearch_tpu.common.staging import configure_staging_retry
+
+        live = self.live
+        self.thread_pool.executor("search").resize_queue(
+            live.get_int("search.queue.size", 1000))
+        memory_accountant().set_budget(
+            live.get_bytes("search.memory.hbm_budget_bytes", 0))
+        configure_staging_retry(
+            max_attempts=live.get_int(
+                SEARCH_STAGING_RETRY_MAX_ATTEMPTS.key,
+                SEARCH_STAGING_RETRY_MAX_ATTEMPTS.default),
+            backoff_ms=live.get_float(
+                SEARCH_STAGING_RETRY_BACKOFF_MS.key,
+                SEARCH_STAGING_RETRY_BACKOFF_MS.default))
 
     def update_index_settings(self, expression: str, body: dict) -> dict:
         normalized = Settings.from_dict(

@@ -1,11 +1,14 @@
 """Pallas TPU kernel for dense-vector (kNN) retrieval on the MXU.
 
-The BM25 tile-scoring plane (ops/pallas_scoring.py) is bandwidth-bound —
-it streams posting bytes and does almost no arithmetic, so the TPU's
-matrix units sit idle. This module adds the workload TPUs are literally
-built for: brute-force kNN over a staged ``[nd_pad, d]`` bf16 embedding
-matrix, scored tile-by-tile with a real MXU matmul (ROADMAP item 4; the
-dense/hybrid retrieval scenario modern Elasticsearch grew into).
+The BM25 tile-scoring plane (ops/pallas_scoring.py) streams posting bytes
+and does almost no arithmetic, so the TPU's matrix units sit idle (it is
+not bandwidth-bound on the chip either: score_tiles runs at 0.137 % of
+its HBM roofline on msmarco-serial, ledger, PR 30; the cost its own note
+names is grid steps, not confirmed on this round's chip). This module
+adds the workload TPUs are literally built for: brute-force kNN over a
+staged ``[nd_pad, d]`` bf16 embedding matrix, scored tile-by-tile with a
+real MXU matmul (ROADMAP item 4; the dense/hybrid retrieval scenario
+modern Elasticsearch grew into).
 
 Design, mirroring the BM25 kernel's conventions so the two planes share
 the serving machinery (micro-batching, plane ladder, quarantine):

@@ -73,30 +73,23 @@ CB_MAX = 128
 NEG_INF = float("-inf")
 
 
-def tiles_per_step_default() -> int:
-    """Grid-coarsening factor for DMA double-buffering across tiles.
-
-    Sourced from ES_TPU_PALLAS_TPS (the registered node setting
-    ``search.pallas.tiles_per_step`` exports it at startup). 1 = one tile
-    per grid step (historical behavior); 2/4/8 fold that many tiles into
-    one step so their posting-window DMAs overlap compute and the fixed
-    per-step dispatch cost amortizes."""
-    import os
-
-    try:
-        v = int(os.environ.get("ES_TPU_PALLAS_TPS", "1"))
-    except ValueError:
-        return 1
-    return v if v in (1, 2, 4, 8) else 1
+# Tiles folded into one grid step of ``score_tiles`` (its
+# ``tiles_per_step`` parameter; 2/4/8 let a step's posting-window DMAs
+# overlap compute). Every caller passes this one value: the first change
+# that lowers the kernel's grid-step cost either derives it from n_tiles
+# or deletes tps > 1 from the kernel (ROADMAP A6, C3).
+TILES_PER_STEP = 1
 
 
 # ----------------------------------------------------------------------
 # Packed postings codec (ISSUE 6: break the bandwidth wall)
 #
 # The raw layout streams 8 bytes/posting (doc i32 + frac f32) out of HBM
-# for every covering window — BENCH_r05 measured the kernel bandwidth-
-# bound on exactly that traffic. The packed codec bit-packs each posting
-# into ONE i32 word:
+# for every covering window. On the chip score_tiles runs at 0.137 % of
+# its HBM roofline on msmarco-serial (ledger, PR 30), so it is not
+# bandwidth-bound there; the cost the note at DEFAULT_TILE_SUB names is
+# grid steps, not confirmed on this round's chip. The packed codec
+# bit-packs each posting into ONE i32 word:
 #
 #     word = (doc << PACK_FRAC_BITS) | frac_q        (frac_q in [1, 4095])
 #
@@ -113,8 +106,7 @@ def tiles_per_step_default() -> int:
 # Lossiness: |dequant(q) - frac| <= PACK_FRAC_SCALE/2 (~2.7e-4 absolute,
 # ~16x tighter than the bf16 rounding the two-pass compensation exists
 # for). Whether that reorders near-tied top-10 ranks is corpus-dependent,
-# which is why the codec is settings-gated (raw default) and bench gates
-# every packed config on measured recall@10 == 1.0 vs the RAW oracle.
+# which is why the codec is settings-gated (raw default).
 # ----------------------------------------------------------------------
 
 PACK_FRAC_BITS = 12
@@ -170,20 +162,13 @@ def pack_segment_blocks(block_docs: np.ndarray, block_frac: np.ndarray,
 
 
 def resolve_postings_codec(pref, nd_pad: int) -> str:
-    """Effective codec for a segment staging: the explicit preference
-    (index setting / caller), else the node-wide default exported via
-    ES_TPU_PALLAS_CODEC (search.pallas.postings_codec), demoted to raw
-    when the doc space exceeds the packed word's doc capacity."""
-    import os
-
-    codec = pref
-    if codec in (None, "default"):
-        codec = os.environ.get("ES_TPU_PALLAS_CODEC", "raw")
-    if codec not in ("raw", "packed"):
-        codec = "raw"
-    if codec == "packed" and not packed_codec_ok(nd_pad):
-        codec = "raw"
-    return codec
+    """Effective codec for a segment staging: the caller's preference
+    (the index's resolved setting; anything but "packed" is raw),
+    demoted to raw when the doc space exceeds the packed word's doc
+    capacity."""
+    if pref == "packed" and packed_codec_ok(nd_pad):
+        return "packed"
+    return "raw"
 
 
 # ----------------------------------------------------------------------

@@ -1266,17 +1266,9 @@ class IndexMeshSearch:
                          for sid, seg in pairs)
 
     def _delta_enabled(self) -> bool:
-        """index.staging.delta.enabled with the explicitness-aware
-        cluster override on top (put_cluster_settings)."""
-        override = getattr(self.svc, "staging_delta_enabled_override",
-                           None)
-        if override is not None:
-            return bool(override)
-        settings = getattr(self.svc, "settings", None)
-        if settings is None:
-            return True
-        return bool(settings.get_bool("index.staging.delta.enabled",
-                                      True))
+        """index.staging.delta.enabled, live."""
+        return bool(self.svc.live.get_bool("index.staging.delta.enabled",
+                                           True))
 
     def _classify_delta(self, old, pairs, codec):
         """Decide whether the staged-key change is servable as a DELTA
@@ -1435,10 +1427,7 @@ class IndexMeshSearch:
                     # a concurrent attempt faulted while we waited
                     self.staging_denied_reason = "staging_fault"
                     return False
-                settings = getattr(self.svc, "settings", None)
-                codec = (settings.get_str(
-                    "index.search.pallas.postings_codec", "default")
-                    if settings is not None else None)
+                codec = self.svc.postings_codec_pref
                 # ---- delta paths (ISSUE 20): tombstone a delete /
                 # append new segments into free slots, keeping the
                 # collective geometry — the rebuild below becomes the
@@ -1614,11 +1603,8 @@ class IndexMeshSearch:
         with self._stage_lock:
             if self._executor is None:
                 return False  # nothing staged: the next query goes cold
-            settings = getattr(self.svc, "settings", None)
-            codec = (settings.get_str(
-                "index.search.pallas.postings_codec", "default")
-                if settings is not None else None)
-            return self._stage_rebuild(mesh, pairs, key, codec,
+            return self._stage_rebuild(mesh, pairs, key,
+                                       self.svc.postings_codec_pref,
                                        reason="compaction")
 
     @staticmethod
@@ -1639,53 +1625,34 @@ class IndexMeshSearch:
 
     def _pruning_config(self):
         """(enabled, probe_tiles) from the live settings — block-max
-        pruned scoring is dynamic (search.pallas.pruning.*): a PUT
-        _cluster/settings update lands as per-index overrides (Node's
-        update consumers), which win over the index's creation-time
-        Settings map (docs/PRUNING.md)."""
-        settings = getattr(self.svc, "settings", None)
-        enabled = getattr(self.svc, "pruning_enabled_override", None)
-        if enabled is None:
-            if settings is None:
-                enabled = False
-            else:
-                enabled = settings.get_bool(
-                    "search.pallas.pruning.enabled", False)
+        pruned scoring is dynamic (search.pallas.pruning.*,
+        docs/PRUNING.md)."""
+        live = self.svc.live
+        enabled = live.get_bool("search.pallas.pruning.enabled", False)
         # brownout step 1 (ISSUE 12, docs/OVERLOAD.md): under admission-
         # queue pressure the overload plane forces pruned / gte-totals
         # eligibility — cheaper tiles before shedding features — and
         # releases it as the queue drains
-        adm = getattr(self.svc, "admission", None)
-        if not enabled and adm is not None \
-                and adm.brownout_forces_pruning:
+        if not enabled and self.svc.admission.brownout_forces_pruning:
             enabled = True
-        if settings is None:
-            return bool(enabled), 8
-        probe = getattr(self.svc, "pruning_probe_override", None)
-        if probe is None:
-            probe = (settings.get_int(
-                "search.pallas.pruning.probe_tiles", 8)
-                if settings is not None else 8)
+        probe = live.get_int("search.pallas.pruning.probe_tiles", 8)
         if probe not in (2, 4, 8, 16, 32):
             probe = 8
         return bool(enabled), probe
 
     def _fused_aggs_enabled(self) -> bool:
         """search.aggs.fused resolution (docs/AGGS.md): an explicit
-        cluster-level override wins (put_cluster_settings syncs it with
-        the search.pallas.* explicitness contract), then the index's
+        cluster-level search.aggs.fused wins, then the index's
         index.search.aggs.fused ("default" follows the node), then the
         seeded node default (on)."""
-        override = getattr(self.svc, "aggs_fused_override", None)
-        if override is not None:
-            return bool(override)
-        settings = getattr(self.svc, "settings", None)
-        if settings is None:
-            return True
-        idx = settings.get_str("index.search.aggs.fused", "default")
-        if idx in ("true", "false"):
-            return idx == "true"
-        return settings.get_bool("search.aggs.fused", True)
+        if self.svc.cluster_explicit.get("search.aggs.fused") is None:
+            # the index's key has a name of its own, so this one reader
+            # has to ask whether the cluster layer holds the node key
+            idx = self.svc.settings.get_str("index.search.aggs.fused",
+                                            "default")
+            if idx in ("true", "false"):
+                return idx == "true"
+        return self.svc.live.get_bool("search.aggs.fused", True)
 
     def _note_agg_fallback(self, reason: str, n: int = 1) -> None:
         with self._counter_lock:
@@ -1718,24 +1685,14 @@ class IndexMeshSearch:
 
     def _knn_config(self):
         """(enabled, tile_sub preference) from the live settings —
-        search.knn.* is dynamic (same override pattern as pruning: a
-        PUT _cluster/settings update lands as per-index overrides that
-        win over creation-time Settings; docs/VECTOR.md)."""
+        search.knn.* is dynamic (docs/VECTOR.md)."""
         from elasticsearch_tpu.ops.pallas_knn import (
             DEFAULT_KNN_SUB,
             VALID_KNN_SUBS,
         )
 
-        settings = getattr(self.svc, "settings", None)
-        enabled = getattr(self.svc, "knn_enabled_override", None)
-        if enabled is None:
-            enabled = (settings.get_bool("search.knn.enabled", True)
-                       if settings is not None else True)
-        sub = getattr(self.svc, "knn_tile_sub_override", None)
-        if sub is None:
-            sub = (settings.get_int("search.knn.tile_sub",
-                                    DEFAULT_KNN_SUB)
-                   if settings is not None else DEFAULT_KNN_SUB)
+        enabled = self.svc.live.get_bool("search.knn.enabled", True)
+        sub = self.svc.live.get_int("search.knn.tile_sub", DEFAULT_KNN_SUB)
         if sub not in VALID_KNN_SUBS:
             sub = DEFAULT_KNN_SUB
         return bool(enabled), int(sub)
@@ -2699,7 +2656,7 @@ class IndexMeshSearch:
                 w_all[slot, : q_batch] = tables[slot][2]
             # filler slots/queries keep zero tables/weights: their live
             # masks are all-dead and zero weights score nothing
-            tps = psc.tiles_per_step_default()
+            tps = psc.TILES_PER_STEP
             sharding = executor._sharding
             staged = executor._seg_staged
             corpus = ((staged["k_packed"],) if codec == "packed"
@@ -3960,7 +3917,7 @@ class MeshPlanExecutor:
         if not isinstance(session, dict):
             raise PlanStructureMismatch("kernel plane not staged")
         geom = session["geom"]
-        tps = psc.tiles_per_step_default()
+        tps = psc.TILES_PER_STEP
         for nodes in groups:
             if any(n._mesh_lanes is None for n in nodes):
                 raise PlanStructureMismatch(

@@ -139,18 +139,13 @@ class SearchAdmissionController:
     index's query path.
 
     Thread-safe; consulted once per top-level search dispatch. Config is
-    read live from the index's ``Settings`` map with explicitly-set
-    cluster overrides winning (``set_cluster_overrides`` — the same
-    explicitness contract as search.pallas.pruning.*)."""
+    read live through the index's layered settings view
+    (``IndexService.live``: explicit cluster values, then the index's
+    own)."""
 
-    _OVERRIDE_PREFIXES = ("search.queue.", "search.admission.",
-                          "search.drain.",
-                          "search.batch.max_window_ms")
-
-    def __init__(self, index_name: str, settings=None):
+    def __init__(self, index_name: str, settings):
         self.index_name = index_name
         self._settings = settings
-        self._overrides = None  # Settings of explicit cluster values
         self._lock = threading.Lock()
         self._shut = False
         # graceful drain (ISSUE 14): while True, new acquires get the
@@ -189,34 +184,14 @@ class SearchAdmissionController:
 
     # -- configuration -------------------------------------------------
 
-    def set_cluster_overrides(self, committed) -> None:
-        """Install the committed cluster settings' EXPLICIT overload
-        keys as overrides (cleared keys revert to the index's own
-        Settings — the value-only update consumers can't see
-        explicitness, so put_cluster_settings syncs this whole map)."""
-        data = {}
-        for key in committed.keys():
-            if any(key.startswith(p) or key == p
-                   for p in self._OVERRIDE_PREFIXES):
-                data[key] = committed.get(key)
-        from elasticsearch_tpu.common.settings import Settings
-
-        self._overrides = Settings(data) if data else None
-
-    def _cfg(self, getter: str, key: str, default):
-        for source in (self._overrides, self._settings):
-            if source is not None and source.get(key) is not None:
-                return getattr(source, getter)(key, default)
-        return default
-
     def _enabled(self) -> bool:
-        return bool(self._cfg("get_bool", "search.admission.enabled", True))
+        return bool(self._settings.get_bool("search.admission.enabled", True))
 
     def _queue_size(self) -> int:
-        return max(1, int(self._cfg("get_int", "search.queue.size", 1000)))
+        return max(1, int(self._settings.get_int("search.queue.size", 1000)))
 
     def _max_concurrent(self) -> int:
-        v = int(self._cfg("get_int", "search.admission.max_concurrent", 0))
+        v = int(self._settings.get_int("search.admission.max_concurrent", 0))
         if v > 0:
             return v
         # auto: mirror the search threadpool's sizing, floored so small
@@ -227,7 +202,7 @@ class SearchAdmissionController:
         return max(16, 3 * cores // 2 + 1)
 
     def _weight(self, tenant: str) -> int:
-        spec = self._cfg("get_str", "search.admission.weights", "") or ""
+        spec = self._settings.get_str("search.admission.weights", "") or ""
         if spec != self._weights_spec:
             # parse once per spec value — the dequeue loop consults
             # weights under the controller lock on the query hot path
@@ -244,16 +219,11 @@ class SearchAdmissionController:
         return self._weights.get(tenant, 1)
 
     def _thresholds(self) -> Tuple[float, float, float]:
+        get = self._settings.get_float
         return (
-            float(self._cfg("get_float",
-                            "search.admission.brownout.pruned_threshold",
-                            0.25)),
-            float(self._cfg("get_float",
-                            "search.admission.brownout.rescore_threshold",
-                            0.5)),
-            float(self._cfg("get_float",
-                            "search.admission.brownout.features_threshold",
-                            0.75)),
+            float(get("search.admission.brownout.pruned_threshold", 0.25)),
+            float(get("search.admission.brownout.rescore_threshold", 0.5)),
+            float(get("search.admission.brownout.features_threshold", 0.75)),
         )
 
     # -- pressure / brownout -------------------------------------------
@@ -339,8 +309,8 @@ class SearchAdmissionController:
         window — the zero-added-latency contract is untouched."""
         if not self._enabled():
             return base_s
-        max_s = float(self._cfg("get_float", "search.batch.max_window_ms",
-                                5.0)) / 1000.0
+        max_s = float(self._settings.get_float(
+            "search.batch.max_window_ms", 5.0)) / 1000.0
         if max_s <= base_s:
             return base_s
         occupancy, _blocked, _delay = self._synthetic_pressure(
@@ -669,7 +639,7 @@ class SearchAdmissionController:
     # -- graceful drain (ISSUE 14, docs/RESILIENCE.md) ------------------
 
     def _drain_deadline_s(self) -> float:
-        v = self._cfg("get_time", "search.drain.deadline", 30.0)
+        v = self._settings.get_time("search.drain.deadline", 30.0)
         return float(v) if v is not None else 30.0
 
     @property

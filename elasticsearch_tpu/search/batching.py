@@ -1,9 +1,11 @@
 """Cross-query micro-batching for the Pallas scoring plane (ISSUE 5).
 
-BENCH_r05 showed the tile kernel is bandwidth-bound: every query streams
-the same corpus posting windows out of HBM (~21 MB/query against a
-1.17 GB resident corpus), so at ~0.6 ms p50 a chip tops out near
-1.7k qps even though per-query compute is tiny. The classic serving fix
+Every query streams the same corpus posting windows out of HBM and pays
+the kernel's per-grid-step cost alone. (On the chip score_tiles runs at
+0.137 % of its HBM roofline on msmarco-serial, ledger, PR 30: it is not
+bandwidth-bound there; the cost the kernel's own note names is grid
+steps, ops/pallas_scoring.py at DEFAULT_TILE_SUB, not confirmed on this
+round's chip; no cell has timed a batch.) The classic serving fix
 (cf. Orca's iteration-level continuous batching for LLM serving, and
 shared block-max traversal in IR) is to amortize one corpus-stream pass
 across the queries that are in flight AT THE SAME TIME: score Q queries
@@ -434,7 +436,7 @@ def batched_segment_scores(segment, nodes: Sequence) -> Optional[
         row_lo, row_hi, weights,
         t_pad=row_lo.shape[1], cb=cb, sub=g.tile_sub,
         dense=True, with_counts=with_counts, interpret=interpret,
-        tiles_per_step=psc.tiles_per_step_default(),
+        tiles_per_step=psc.TILES_PER_STEP,
         q_batch=q_pad, codec=codec)
     nd = segment.nd_pad
     scores_all = np.asarray(_flat_batch(outs[0]))[:, :nd]

@@ -248,7 +248,7 @@ def _pallas_score_terms_node(segment, arrs, min_match):
     node = P.PallasScoreTermsNode(
         row_lo, row_hi, kweights, min_match,
         cb=cb, sub=g.tile_sub, interpret=(mode == "interpret"),
-        live_key=live_key, tiles_per_step=psc.tiles_per_step_default(),
+        live_key=live_key, tiles_per_step=psc.TILES_PER_STEP,
         codec=getattr(segment, "kernel_codec", "raw"))
     # the cross-query micro-batcher (search/batching.py) unions lane sets
     # across concurrent queries and re-derives shared tables, so the node

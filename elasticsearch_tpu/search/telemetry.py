@@ -1,6 +1,9 @@
 """Phase-attributed query telemetry (ISSUE 8, docs/OBSERVABILITY.md).
 
-The headline query is bandwidth-bound (~35 GB/s effective, ~21 MB/query),
+On the chip score_tiles runs at 0.137 % of its HBM roofline on
+msmarco-serial (ledger, PR 30): the query is not bandwidth-bound, the
+cost the kernel's own note names is grid steps (ops/pallas_scoring.py at
+DEFAULT_TILE_SUB), not confirmed on this round's chip,
 and every remaining tuning lever — packed-codec/pruning default flips,
 the ICI serving loop, kNN tile tuning — needs to know WHERE a query's
 sub-millisecond budget goes. The reference spends a whole subsystem on

@@ -318,12 +318,10 @@ class TestScrubIntervalKnob:
             # an explicit cluster value overrides the index setting
             node.put_cluster_settings(
                 {"persistent": {"index.scrub.interval": "5s"}})
-            assert svc.scrub_interval_override == 5.0
             assert svc._scrub_effective_interval() == 5.0
             # clearing hands control back to the index setting
             node.put_cluster_settings(
                 {"persistent": {"index.scrub.interval": None}})
-            assert svc.scrub_interval_override is None
             assert svc._scrub_effective_interval() == 30.0
         finally:
             node.close()

@@ -428,7 +428,8 @@ class TestCompaction:
             # any shard with ≥1 tombstone is "dense" at this threshold,
             # so the pass expunges every delete (hash routing spreads
             # the 12 deletes unevenly across the 3 shards)
-            idx.staging_compact_threshold_override = 0.01
+            idx.set_cluster_overrides(Settings(
+                {"index.staging.compact.threshold": 0.01}))
             out = idx.compact_now()
             assert out["ran"] is True, out
             assert out["merged_shards"], out  # deletes expunged
@@ -592,24 +593,28 @@ class TestSettingsPlumbing:
             node.create_index("ovr-a", {"settings": {
                 "index": {"number_of_shards": 1}}})
             svc_a = node.indices["ovr-a"]
-            assert svc_a.staging_delta_enabled_override is None
+
+            def delta_enabled(svc):
+                return svc.live.get_bool("index.staging.delta.enabled",
+                                         True)
+
+            assert delta_enabled(svc_a) is True
             node.put_cluster_settings({"persistent": {
                 "index.staging.delta.enabled": False,
                 "index.staging.compact.threshold": 0.5}})
-            assert svc_a.staging_delta_enabled_override is False
-            assert svc_a.staging_compact_threshold_override == 0.5
+            assert delta_enabled(svc_a) is False
             assert svc_a._compact_threshold() == 0.5
             # an index created AFTER the commit honors the live value
             node.create_index("ovr-b", {"settings": {
                 "index": {"number_of_shards": 1}}})
             svc_b = node.indices["ovr-b"]
-            assert svc_b.staging_delta_enabled_override is False
-            assert svc_b.staging_compact_threshold_override == 0.5
+            assert delta_enabled(svc_b) is False
+            assert svc_b._compact_threshold() == 0.5
             # clearing hands control back to each index's own setting
             node.put_cluster_settings({"persistent": {
                 "index.staging.delta.enabled": None,
                 "index.staging.compact.threshold": None}})
-            assert svc_a.staging_delta_enabled_override is None
+            assert delta_enabled(svc_a) is True
             assert svc_a._compact_threshold() == 0.25  # default
         finally:
             node.close()

@@ -304,7 +304,8 @@ class TestStructuralFallback:
             assert ms.agg_fused_query_total == 0
             assert ms.agg_host_fallback_by_reason.get("disabled", 0) >= 1
             # dynamic cluster override re-enables without a restart
-            mesh.aggs_fused_override = True
+            mesh.set_cluster_overrides(
+                Settings({"search.aggs.fused": True}))
             got2 = mesh.search(dict(body, size=5))
             assert got2["aggregations"] == want["aggregations"]
             assert ms.agg_fused_query_total == 1

@@ -148,6 +148,15 @@ def index(indices):
     return indices[0]
 
 
+@pytest.fixture()
+def host_reduced_aggs(indices):
+    """The mesh index with search.aggs.fused explicitly off at the
+    cluster level, for the length of one test."""
+    indices[0].set_cluster_overrides(Settings({"search.aggs.fused": False}))
+    yield
+    indices[0].set_cluster_overrides(Settings.EMPTY)
+
+
 def _drained(monkeypatch):
     """Every tracer the index's telemetry drains, as it drains it."""
     drained = []
@@ -186,11 +195,11 @@ SERIAL_BODIES = {
 
 
 @pytest.mark.parametrize("case", sorted(SERIAL_BODIES))
-def test_serial_path_copies_one_array_a_query(index, monkeypatch, case):
+def test_serial_path_copies_one_array_a_query(index, monkeypatch, case,
+                                              request):
     body = SERIAL_BODIES[case]
     if case == "views":  # the host reduces over the program's views
-        monkeypatch.setattr(index, "aggs_fused_override", False,
-                            raising=False)
+        request.getfixturevalue("host_reduced_aggs")
     drained = _drained(monkeypatch)
     index.search(body)  # a first call compiles
     arrays0, spans0 = _d2h(index)
@@ -210,13 +219,11 @@ def test_serial_path_copies_one_array_a_query(index, monkeypatch, case):
 
 
 @pytest.mark.parametrize("case", sorted(SERIAL_BODIES))
-def test_packed_answer_is_the_host_planes_answer(indices, monkeypatch,
-                                                 case):
+def test_packed_answer_is_the_host_planes_answer(indices, request, case):
     body = SERIAL_BODIES[case]
     mesh_idx, host_idx = indices
     if case == "views":
-        monkeypatch.setattr(mesh_idx, "aggs_fused_override", False,
-                            raising=False)
+        request.getfixturevalue("host_reduced_aggs")
     mesh, host = mesh_idx.search(body), host_idx.search(body)
     assert mesh["_plane"] in ("mesh", "mesh_pallas"), mesh["_plane"]
     assert host["_plane"] == "host"

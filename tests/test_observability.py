@@ -346,7 +346,8 @@ class TestTracerOverheadGuard:
             assert phases["queries_recorded"] == 0
             assert phases["histogram_us"] == {}
             # the dynamic override wins over the creation-time setting
-            idx.telemetry_enabled_override = True
+            idx.set_cluster_overrides(
+                Settings({"search.telemetry.enabled": True}))
             idx.search({"query": {"match": {"body": "t1"}}, "size": 3})
             assert idx.search_stats()["phases"]["queries_recorded"] == 1
         finally:

@@ -2,7 +2,8 @@
 
 Run on the CPU backend in interpreter mode (interpret=True): the kernel
 semantics are identical to the compiled TPU path; mosaic-specific layout
-constraints are exercised separately on hardware by bench.py.
+constraints are held by tests/test_tpu_compile.py and, on hardware, by
+chip_smoke.py and the benchmark.
 
 Oracle: reference_scores — a host scatter-add over the same block-packed
 postings, i.e. exactly what ops/scoring.score_term_blocks computes and

@@ -95,15 +95,37 @@ class TestPackedCodec:
         with pytest.raises(ValueError):
             pack_segment_blocks(docs, frac, psc.PACKED_DOC_CAP * 2)
 
-    def test_codec_resolution(self, monkeypatch):
-        monkeypatch.setenv("ES_TPU_PALLAS_CODEC", "packed")
-        assert psc.resolve_postings_codec(None, 1 << 20) == "packed"
+    def test_codec_resolution(self):
+        assert psc.resolve_postings_codec("packed", 1 << 20) == "packed"
         # doc space beyond the packed word's doc bits demotes to raw
-        assert psc.resolve_postings_codec(None, 1 << 21) == "raw"
+        assert psc.resolve_postings_codec("packed", 1 << 21) == "raw"
         assert psc.resolve_postings_codec("raw", 1 << 10) == "raw"
-        monkeypatch.delenv("ES_TPU_PALLAS_CODEC")
+        # no preference, or one the kernel does not know, is raw
         assert psc.resolve_postings_codec(None, 1 << 10) == "raw"
+        assert psc.resolve_postings_codec("default", 1 << 10) == "raw"
         assert psc.resolve_postings_codec("packed", 1 << 10) == "packed"
+
+    @pytest.mark.parametrize("settings, want", [
+        ({}, "raw"),
+        ({"search.pallas.postings_codec": "packed"}, "packed"),
+        ({"search.pallas.postings_codec": "packed",
+          "index.search.pallas.postings_codec": "raw"}, "raw"),
+        ({"search.pallas.postings_codec": "raw",
+          "index.search.pallas.postings_codec": "packed"}, "packed"),
+        ({"search.pallas.postings_codec": "packed",
+          "index.search.pallas.postings_codec": "default"}, "packed"),
+    ])
+    def test_index_codec_preference(self, settings, want):
+        """The index key unless "default", else the node key its
+        Settings were seeded with, else raw."""
+        idx = IndexService("codec-pref", Settings(
+            {"index.number_of_shards": 1, **settings}))
+        try:
+            assert idx.postings_codec_pref == want
+            assert all(s.engine.postings_codec == want
+                       for s in idx.shards.values())
+        finally:
+            idx.close()
 
     def test_packed_kernel_parity(self):
         """Dense + top-k outputs over the packed corpus equal the oracle
@@ -488,15 +510,15 @@ class TestServicePruning:
         finally:
             idx.close()
 
-    def test_host_path_packed_codec_parity(self, monkeypatch):
+    def test_host_path_packed_codec_parity(self):
         """Single-shard (host plan path): the packed codec serves the
         same hits as raw within quantization tolerance — the codec
         threads the host rung, not just the mesh."""
         raw = build_index("codec-raw", n_shards=1, n_docs=300)
-        monkeypatch.setenv("ES_TPU_PALLAS_CODEC", "packed")
-        packed = build_index("codec-packed", n_shards=1, n_docs=300)
+        packed = build_index("codec-packed", n_shards=1, n_docs=300, **{
+            "search.pallas.postings_codec": "packed"})
         try:
-            # staging happened under the env default
+            # staging happened under the seeded node default
             seg = next(iter(packed.shards.values())) \
                 .engine.searchable_segments()[0]
             seg.device_arrays()
