@@ -301,9 +301,11 @@ def stack_plans(plans: List[PlanNode], local_nd_pads: List[int],
 
     Returns a flat list aligned with ``template.flat_arrays()`` where every
     entry has a leading [n_slots] axis (slots = device x segments-packed-
-    per-device). Slots beyond len(plans) replicate shard 0's arrays —
-    their seg arrays have live1 all-False (and zero kernel frac), so they
-    contribute nothing.
+    per-device). Slots beyond len(plans) replicate shard 0's arrays, for
+    the shapes' sake only: their seg arrays have live1 all-False, so the
+    serial program skips their pass and never reads these rows (the
+    batched programs still score them, against zero kernel frac, and
+    mask the result away).
     """
     _check_same_structure(plans)
     kinds = plans[0].flat_pad_kinds()
@@ -438,9 +440,16 @@ def _mesh_query_program(mesh: Mesh, holder: _TemplateHolder, k: int,
     semantics of the reference's query phase (QueryPhase.java:179-268) as
     fused mask stages:
 
-      emit -> live -> min_score -> slice -> [agg view] -> post_filter ->
-      total psum -> search_after cut -> (rescore window pass) ->
-      local top-k -> all_gather global merge
+      [slot holds a live document?] -> emit -> live -> min_score ->
+      slice -> [agg view] -> post_filter -> total psum -> search_after
+      cut -> (rescore window pass) -> local top-k -> all_gather global
+      merge
+
+    The guard in front is a ``lax.cond`` on ``any(live1)`` of the slot:
+    a free slot (delta-staging headroom) or a segment whose documents
+    are all deleted skips the whole per-slot pass and hands the merge
+    what such a slot yields anyway (``dead_slot``). The predicate is
+    read from the staged mask at run time, so it is in no program key.
 
     spd: SLOTS per device. A device packs spd segments (the reference's
     data node searching any number of Lucene leaves per shard,
@@ -474,7 +483,7 @@ def _mesh_query_program(mesh: Mesh, holder: _TemplateHolder, k: int,
                  scalars):
         """One segment's query phase: emit -> mask stages -> local top-k.
         Returns (loc_keys, loc_docs, loc_scores, loc_raw|None,
-        local_count, agg_matched, scores)."""
+        local_count, (agg_matched, scores) | (), agg_parts)."""
         ctx = EmitCtx(seg, plan_arrays, row_base)
         scores, matched = plan.emit(ctx)
         matched = matched & seg["live1"]
@@ -566,8 +575,32 @@ def _mesh_query_program(mesh: Mesh, holder: _TemplateHolder, k: int,
 
             agg_parts = tuple(emit_agg_partials(agg_static, seg,
                                                 agg_matched))
+        views = (agg_matched, scores) if with_views else ()
         return (loc_keys, loc_docs, loc_scores, loc_raw, local_count,
-                agg_matched, scores, agg_parts)
+                views, agg_parts)
+
+    def dead_slot(seg, shapes):
+        """What ``per_slot`` yields (``shapes``) for a slot with no live
+        document, without the pass: every key ``-inf`` (the host drops
+        such lanes, so their docs and scores are free), count 0,
+        all-false views, and each fused-agg partial at its identity:
+        what ``emit_agg_partials`` makes of an all-false mask, asked of
+        a one-document stand-in for the slot (no partial's shape depends
+        on the document count)."""
+        keys, *rest, _agg_parts = jax.tree_util.tree_map(
+            lambda a: jnp.zeros(a.shape, a.dtype), shapes)
+        agg_parts = ()
+        if agg_static:
+            from elasticsearch_tpu.search.fused_aggs import (
+                emit_agg_partials,
+            )
+
+            one_doc = {name: jnp.zeros((1,) + a.shape[2:], a.dtype)
+                       for name, a in seg.items()
+                       if name not in _KERNEL_TABLES}
+            agg_parts = tuple(emit_agg_partials(
+                agg_static, one_doc, jnp.zeros((1,), bool)))
+        return (jnp.full_like(keys, -jnp.inf), *rest, agg_parts)
 
     def per_device(seg, plan_arrays, pf_arrays, rs_arrays, scalars):
         dev = jax.lax.axis_index("shards")
@@ -575,13 +608,26 @@ def _mesh_query_program(mesh: Mesh, holder: _TemplateHolder, k: int,
         # (no kernel table staged: the scatter plane, no row to offset)
         k_rows = next((seg[name].shape[0] // spd
                        for name in _KERNEL_TABLES if name in seg), 0)
-        for i in range(spd):
+
+        def slot(i):
             seg_i = {name: a if name in _KERNEL_TABLES else a[i]
                      for name, a in seg.items()}
-            slot_out.append(per_slot(
+            return per_slot(
                 seg_i, i * k_rows, [a[i] for a in plan_arrays],
                 [a[i] for a in pf_arrays], [a[i] for a in rs_arrays],
-                scalars))
+                scalars)
+
+        # A slot pays for its pass (tile kernel, masks, top-k) only if
+        # it holds a live document: a headroom slot, or a segment whose
+        # documents are all deleted, costs this reduction and a branch.
+        # The predicate reads what is staged, so a delta append into a
+        # free slot is served by the same compiled program; the slot is
+        # sliced inside the branch taken.
+        shapes = jax.eval_shape(lambda: slot(0))
+        for i in range(spd):
+            slot_out.append(jax.lax.cond(
+                jnp.any(seg["live1"][i]), functools.partial(slot, i),
+                lambda: dead_slot(seg, shapes)))
         kk = slot_out[0][0].shape[0]
         cand_keys = jnp.concatenate([o[0] for o in slot_out])
         cand_docs = jnp.concatenate([o[1] for o in slot_out])
@@ -615,11 +661,11 @@ def _mesh_query_program(mesh: Mesh, holder: _TemplateHolder, k: int,
                              top_score, top_raw)[None],
                 counts]
         if with_views:
-            outs.extend([jnp.stack([o[5] for o in slot_out]),
-                         jnp.stack([o[6] for o in slot_out])])
+            outs.extend([jnp.stack([o[5][0] for o in slot_out]),
+                         jnp.stack([o[5][1] for o in slot_out])])
         if agg_static:
-            n_agg = len(slot_out[0][7])
-            outs.extend(jnp.stack([o[7][j] for o in slot_out])
+            n_agg = len(slot_out[0][6])
+            outs.extend(jnp.stack([o[6][j] for o in slot_out])
                         for j in range(n_agg))
         return tuple(outs)
 
@@ -2249,6 +2295,13 @@ class IndexMeshSearch:
                         rescore_static=rescore_static, tracer=tracer,
                         agg_static=(agg_plan.statics
                                     if agg_plan is not None else ()))
+                    tel = self._telemetry
+                    if tel is not None:
+                        # occupied over total: the share of its slots a
+                        # query pays for (the program skips the others)
+                        tel.add_counters({
+                            "mesh_slots": executor.n_slots,
+                            "mesh_slots_occupied": len(executor.segments)})
                     # the plane served: fully re-open it (ends a probe's
                     # quarantine — single-flight contract)
                     self.plane_health.note_success(plane)
@@ -2915,10 +2968,12 @@ class MeshPlanExecutor:
 
     Segments PACK: with more segments than devices, each device owns
     ``slots_per_dev = ceil(N / n_dev)`` slots in the stacked leading axis
-    and the per-device program unrolls its slots (per-slot live masks keep
-    padding slots dead) — a realistically-refreshed index (many NRT
-    segments per shard) stays on the mesh plane instead of silently
-    falling back to the host path."""
+    and the per-device program unrolls its slots — a realistically-
+    refreshed index (many NRT segments per shard) stays on the mesh plane
+    instead of silently falling back to the host path. A slot with no
+    live document (padding, delta-staging headroom, a segment deleted
+    whole) has an all-false ``live1`` row: the serial program branches
+    around its pass, the batched and kNN programs mask its result."""
 
     _SCOPE_SEQ = itertools.count(1)
 

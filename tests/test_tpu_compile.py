@@ -121,8 +121,10 @@ CELL_ROWS_PAD = -(-CELL_ROWS // psc.CB_MAX) * psc.CB_MAX
 def _table_sized_ops(text):
     """Instructions of a compiled module that produce or copy an array
     with the cell's table row count (one slot's or the whole table's),
-    other than the kernel's custom call and bitcasts: each is a pass
-    over 41 MB or more that a query pays before it scores anything."""
+    other than the kernel's custom call, bitcasts and what hands a
+    buffer's address on (a conditional takes its branch's operands as a
+    tuple): each is a pass over 41 MB or more that a query pays before
+    it scores anything."""
     import re
 
     rows = {CELL_ROWS, CELL_ROWS_PAD, CELL_SLOTS * CELL_ROWS_PAD}
@@ -135,6 +137,7 @@ def _table_sized_ops(text):
         if not instruction.match(line) or not sized.search(line):
             continue  # module header, a computation's signature, small
         if (" parameter(" in line or " bitcast(" in line
+                or " tuple(" in line or " get-tuple-element(" in line
                 or ("custom-call(" in line and "tpu_custom_call" in line)):
             continue
         found.append(line[:200])
@@ -215,6 +218,29 @@ def test_segment_aggregate_compiles_at_1m_docs(sds):
         sds((ND_PAD,), jnp.float32),
         n_ords=2000, with_sum=True).compile().as_text()
     assert "tpu_custom_call" in text
+
+
+def _conditionals(text):
+    """How many conditionals a compiled module holds."""
+    return sum(" conditional(" in line for line in text.splitlines())
+
+
+def _branch_bodies(text):
+    """The text of a compiled module's conditional branches (the
+    computations named in ``branch_computations``, or the true and the
+    false computation of a predicated one)."""
+    import re
+
+    names = set(re.findall(r"(?:true|false)_computation=(%[\w.-]+)", text))
+    for group in re.findall(r"branch_computations=\{([^}]*)\}", text):
+        names.update(name.strip() for name in group.split(","))
+    bodies, keep = [], False
+    for line in text.splitlines():
+        if line and not line.startswith(" "):
+            keep = line.split(" ", 1)[0] in names
+        if keep:
+            bodies.append(line)
+    return "\n".join(bodies)
 
 
 def _for_tpu(plan):
@@ -329,6 +355,10 @@ def test_serial_mesh_program_compiles_for_four_chips(topo, monkeypatch):
     text = _compiled_for_tpu(build_program, seen, topo.devices).as_text()
     assert "tpu_custom_call" in text
     assert "all-gather" in text
+    # every device guards each of its slots, and the merge's collectives
+    # stay outside the branches (a device that skips must still meet them)
+    assert _conditionals(text) == seen["kwargs"]["spd"]
+    assert "all-gather" not in _branch_bodies(text)
 
 
 def test_serial_mesh_program_reads_the_cells_tables_in_place(
@@ -337,7 +367,9 @@ def test_serial_mesh_program_reads_the_cells_tables_in_place(
     (one of headroom a shard), with the kernel tables at the cell's
     size. Every query launches it, so a pass over a slot's table (41 MB)
     in it is paid by every query: there is none, and one launch of the
-    kernel per slot."""
+    kernel per slot, each behind the conditional that skips a slot with
+    no live document (ISSUE 32): a conditional that copied its operands
+    would copy the tables for every slot of every query."""
     build_program, seen = _recorded_serial_launch(
         monkeypatch, n_shards=2, n_devices=1, n_docs=1500)
     assert seen["kwargs"]["spd"] == CELL_SLOTS
@@ -346,6 +378,12 @@ def test_serial_mesh_program_reads_the_cells_tables_in_place(
                              {"k_docs": flat, "k_frac": flat}).as_text()
     assert text.count("tpu_custom_call") >= CELL_SLOTS
     assert _table_sized_ops(text) == []
+    assert _conditionals(text) == CELL_SLOTS
+    # the kernel and the sort are inside the branches, nowhere else
+    calls = [line for line in text.splitlines()
+             if "custom-call(" in line and "tpu_custom_call" in line]
+    assert len(calls) == CELL_SLOTS
+    assert all(line in _branch_bodies(text) for line in calls)
 
 
 @pytest.mark.parametrize("chips, n_shards, n_docs",
