@@ -103,3 +103,16 @@ def global_ordinals(segments: Sequence, field: str,
             _cache.pop(next(iter(_cache)))
         _cache[key] = (built, refs)
     return built
+
+
+def numeric_global_ordinals(columns: Sequence) -> np.ndarray:
+    """The numeric twin of the merged term list: the sorted distinct
+    float64 values over per-segment ``NumericColumn``s (None where a
+    segment lacks the field). A value's global ordinal is its
+    ``np.searchsorted`` position. Not cached here: the mesh executor
+    keeps one per field for its generation."""
+    parts = [c.flat_values[: c.count] for c in columns
+             if c is not None and c.count]
+    if not parts:
+        return np.zeros(0, np.float64)
+    return np.unique(np.concatenate(parts))

@@ -1108,10 +1108,16 @@ class IndexService:
 
             deadline = SearchDeadline(parse_search_timeout(body))
         cache_key = None
+        use_cache = self._request_cache_enabled
+        if "request_cache" in body:
+            # the URL's ?request_cache=, carried here by the REST layer:
+            # false opts this request out; it is no part of the search
+            use_cache = use_cache and body["request_cache"] is not False
+            body = {k: v for k, v in body.items() if k != "request_cache"}
         # (a cached COMPLETE response is always valid under a deadline;
         # only the put below filters — partial/timed-out responses must
         # not poison the cache)
-        if (self._request_cache_enabled and preference_shards is None
+        if (use_cache and preference_shards is None
                 and pinned_segments is None and cacheable(body)):
             epochs = [shard_epoch(self.shards[sid])
                       for sid in sorted(self.shards)]
@@ -1879,6 +1885,11 @@ class IndexService:
                     if self._mesh_search is not None else 0),
                 "agg_host_fallback_total": (
                     self._mesh_search.agg_host_fallback_total
+                    if self._mesh_search is not None else 0),
+                # field sorts ranked inside the mesh program (the ladder's
+                # host.sort_ineligible counts those that left it)
+                "sort_device_query_total": (
+                    self._mesh_search.sort_device_query_total
                     if self._mesh_search is not None else 0),
                 "agg_host_fallback_by_reason": (
                     dict(self._mesh_search.agg_host_fallback_by_reason)

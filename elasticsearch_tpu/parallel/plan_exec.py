@@ -40,6 +40,7 @@ from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as PS
 
 from elasticsearch_tpu.search.plan import EmitCtx, PlanNode
+from elasticsearch_tpu.search.telemetry import NULL_TRACER
 
 
 class PlanStructureMismatch(Exception):
@@ -420,6 +421,10 @@ class _TemplateHolder:
 # a slice of a stacked [slots, n_rows, LANE] table is a copy of the
 # slot's whole table, on the device, in every query.
 _KERNEL_TABLES = ("k_docs", "k_frac", "k_packed")
+
+
+# name prefixes of the slot columns staged on a request's demand
+_ON_DEMAND = ("msort.", "mslice.", "maggs.", "mnum.")
 
 
 def _slot_row_base(table, i: int, spd: int) -> int:
@@ -1174,6 +1179,8 @@ class IndexMeshSearch:
         # vs agg'd mesh queries that fell back to the host reduce over
         # device views — per documented reason (docs/OBSERVABILITY.md)
         self.agg_fused_query_total = 0
+        # queries whose field sort ranked inside the mesh program
+        self.sort_device_query_total = 0
         self.agg_host_fallback_total = 0
         self.agg_host_fallback_by_reason: Dict[str, int] = {}
         # block-max pruned scoring observability (docs/PRUNING.md):
@@ -1706,7 +1713,8 @@ class IndexMeshSearch:
             self.agg_host_fallback_by_reason[reason] = \
                 self.agg_host_fallback_by_reason.get(reason, 0) + n
 
-    def _resolve_fused_aggs(self, agg_specs, executor):
+    def _resolve_fused_aggs(self, agg_specs, executor,
+                            tracer=NULL_TRACER):
         """(FusedAggPlan | None, fallback reason | None) for a mesh-
         served query's agg set — all-or-nothing (docs/AGGS.md). A
         terminal doc-value staging fault demotes the AGGS (not the
@@ -1718,7 +1726,7 @@ class IndexMeshSearch:
         from elasticsearch_tpu.search.fused_aggs import resolve_fused_aggs
 
         try:
-            return resolve_fused_aggs(agg_specs, executor)
+            return resolve_fused_aggs(agg_specs, executor, tracer)
         except Exception:  # noqa: BLE001 — defensive: an unexpected
             # RESOLUTION error (not a device fault — those classify as
             # staging_fault inside resolve_fused_aggs) must degrade to
@@ -1955,7 +1963,8 @@ class IndexMeshSearch:
                     tr.annotate(key, int(v))
         return results
 
-    def _sort_plan(self, body: dict, executor: "MeshPlanExecutor"):
+    def _sort_plan(self, body: dict, executor: "MeshPlanExecutor",
+                   tracer=NULL_TRACER):
         """Resolve the request's sort to staged mesh key columns.
 
         Returns (sort_keys, sort_spec) — sort_keys None for relevance —
@@ -1981,7 +1990,7 @@ class IndexMeshSearch:
             # the fill participates in the f32 rank key like any value
             if float(np.float32(missing)) != float(missing):
                 return "fallback", None
-        keys = executor.ensure_sort_column(field, order, missing)
+        keys = executor.ensure_sort_column(field, order, missing, tracer)
         if keys is None:
             return "fallback", None
         return keys, sort_spec
@@ -2020,10 +2029,16 @@ class IndexMeshSearch:
                 else:
                     anchor = -big if order == "desc" else big
             else:
-                # anchor the cursor string in global-ordinal space;
-                # between-terms strings land at bisect-position - 0.5 so
-                # the strict cut stays exact either way
-                s = str(after)
+                # anchor the cursor in global-ordinal space; one that
+                # lies between two entries lands at bisect-position - 0.5
+                # so the strict cut stays exact either way
+                if isinstance(vocab, np.ndarray):
+                    try:
+                        s = float(after)
+                    except (TypeError, ValueError):
+                        return None
+                else:
+                    s = str(after)
                 pos = bisect.bisect_left(vocab, s)
                 present = pos < len(vocab) and vocab[pos] == s
                 anchor = float(pos) if present else pos - 0.5
@@ -2128,23 +2143,33 @@ class IndexMeshSearch:
                         "pruned": r.get("pruned")}
         t_parse = tracer.start("parse_rewrite")
         agg_specs = parse_aggs(body.get("aggs") or body.get("aggregations"))
-        sort_keys, sort_spec = self._sort_plan(body, executor)
+        sort_keys = sort_spec = agg_plan = agg_reason = None
+        if agg_specs or body.get("sort") is not None:
+            # what a sort or an aggregation reads besides the base slot
+            # tables is staged once a generation: the leaves
+            # ``staging.sort_column`` and ``staging.doc_values`` open
+            # under this parent only on the request that stages
+            tracer.stop("parse_rewrite", t_parse)
+            t_stage = tracer.start_parent("staging")
+            try:
+                sort_keys, sort_spec = self._sort_plan(body, executor,
+                                                       tracer)
+                # fused on-device aggregations (ISSUE 13, docs/AGGS.md):
+                # when every spec is fused-eligible the agg reduction
+                # rides INSIDE the mesh program (doc-value columns staged
+                # per slot, ledger kind doc_values) and the [n_slots,
+                # nd1] matched masks never cross to the host; otherwise
+                # the previous with_views host reduce serves, counted
+                # per fallback reason
+                if agg_specs and sort_keys != "fallback":
+                    agg_plan, agg_reason = self._resolve_fused_aggs(
+                        agg_specs, executor, tracer)
+            finally:
+                tracer.stop("staging", t_stage)
+            t_parse = tracer.start("parse_rewrite")
         if sort_keys == "fallback":
             self._note("host", "sort_ineligible")
             return None
-        # fused on-device aggregations (ISSUE 13, docs/AGGS.md): when
-        # every spec is fused-eligible the agg reduction rides INSIDE
-        # the mesh program (doc-value columns staged per slot, ledger
-        # kind doc_values) and the [n_slots, nd1] matched masks never
-        # cross to the host; otherwise the previous with_views host
-        # reduce serves, counted per fallback reason
-        agg_plan = None
-        agg_reason = None
-        if agg_specs:
-            t_aggstage = tracer.start("staging")
-            agg_plan, agg_reason = self._resolve_fused_aggs(agg_specs,
-                                                            executor)
-            tracer.stop("staging", t_aggstage)
 
         features = set()
         scalars: Dict[str, float] = {}
@@ -2270,6 +2295,9 @@ class IndexMeshSearch:
                         # harmonization below
                         ctx.for_mesh = True
                         ctx.mesh_kernel = session
+                        # numeric filters read columns this generation
+                        # stages once (ensure_numeric_column)
+                        ctx.mesh_columns = executor
                         ctxs[sid] = ctx
                         plans.append(qb.to_plan(ctx, seg))
                         # post_filter/rescore plans stay on scatter
@@ -2280,6 +2308,10 @@ class IndexMeshSearch:
                             pf_plans.append(pf_qb.to_plan(ctx, seg))
                         if rs_qb is not None:
                             rs_plans.append(rs_qb.to_plan(ctx, seg))
+                        # (the context outlives the launch: the host
+                        # fallback of the aggregations plans a ``filter``
+                        # body with it, over the segment's own arrays)
+                        ctx.mesh_columns = None
                     used_pallas = False
                     if session is not None:
                         used_pallas = executor.harmonize_kernel_nodes(
@@ -2358,6 +2390,8 @@ class IndexMeshSearch:
             self.query_total += 1
             if used_pallas:
                 self.pallas_query_total += 1
+            if sort_keys is not None:
+                self.sort_device_query_total += 1
         self._note("mesh_pallas" if used_pallas else "mesh", "served")
         # per-shard search stats stay attributed even though the mesh
         # executes all shards as one program (SearchStats semantics)
@@ -2377,15 +2411,18 @@ class IndexMeshSearch:
             if sort_keys is None:
                 sv = (score,) if rescore_static is not None else ()
             elif vocab is not None:
-                # global ordinal back to the term; missing-fill
-                # sentinels render as the host path's string sentinels
-                # (both serialize to null)
+                # global ordinal back to the term or the stored number;
+                # missing-fill sentinels render as the host path's
+                # (string sentinels, +/-inf: all serialize to null)
                 raw = float(raws[i])
+                numeric = isinstance(vocab, np.ndarray)
                 if abs(raw) >= 3.0e38:
-                    sv = (_STR_SENTINEL_HIGH if raw > 0
-                          else _STR_SENTINEL_LOW,)
+                    sv = ((np.inf if raw > 0 else -np.inf,) if numeric
+                          else (_STR_SENTINEL_HIGH if raw > 0
+                                else _STR_SENTINEL_LOW,))
                 else:
-                    sv = (vocab[int(round(raw))],)
+                    v = vocab[int(round(raw))]
+                    sv = (float(v) if numeric else v,)
             else:
                 # missing-fill sentinels surface as +/-inf, which
                 # fetch_hits renders as null (same as the host path)
@@ -2400,13 +2437,20 @@ class IndexMeshSearch:
         tracer.stop("merge", t_merge)
         aggregations = None
         if agg_specs:
-            t_agg = tracer.start("aggregate")
+            # ``aggregate.fetch``: what the reduce reads of the program's
+            # outputs, device to host (the fused partials, or the masks
+            # and scores of the host fallback), every copy asked for
+            # before the first is waited for; ``aggregate.finalize``: the
+            # reduce on the host
+            t_agg = tracer.start_parent("aggregate")
+            t = tracer.start("aggregate.fetch")
+            agg_outs = jax.device_get(list(outs[2:]))
+            t = tracer.switch("aggregate.fetch", t, "aggregate.finalize")
             if agg_plan is not None:
                 from elasticsearch_tpu.search.fused_aggs import (
                     finalize_fused,
                 )
 
-                agg_outs = [np.asarray(o) for o in outs[2:]]
                 aggregations = finalize_fused(agg_plan, agg_outs,
                                               len(executor.pairs))
                 with self._counter_lock:
@@ -2419,8 +2463,7 @@ class IndexMeshSearch:
                         "doc_values_bytes_streamed":
                             agg_plan.staged_bytes(executor._seg_staged)})
             else:
-                matched_np = np.asarray(outs[2])
-                scores_np = np.asarray(outs[3])
+                matched_np, scores_np = agg_outs
                 views = []
                 for i, (sid, seg) in enumerate(executor.pairs):
                     nd1 = seg.nd_pad + 1
@@ -2429,6 +2472,7 @@ class IndexMeshSearch:
                         scores_np[i, :nd1]))
                 aggregations = run_aggregations(agg_specs, views)
                 self._note_agg_fallback(agg_reason or "field_ineligible")
+            tracer.stop("aggregate.finalize", t)
             tracer.stop("aggregate", t_agg)
         return {"total": total, "refs": refs, "max_score": max_score,
                 "aggregations": aggregations,
@@ -3052,10 +3096,12 @@ class MeshPlanExecutor:
         self._account("mesh_slot_tables", "seg_stacked",
                       sum(int(a.nbytes) for a in stacked.values()),
                       duration_ms=(_time.monotonic() - t0) * 1000.0)
-        # per staged sort column: {"vocab": [terms]|None} — keyword sorts
+        # per staged sort column: {"vocab": [terms] | array | None} —
+        # keyword sorts, and numeric ones whose values f32 cannot hold,
         # rank by GLOBAL ordinals built over the staged segment set and
-        # the caller maps ordinals back to terms for the response
+        # the caller maps ordinals back to values for the response
         self.sort_meta: Dict[str, dict] = {}
+        self._numeric_ords: Dict[str, np.ndarray] = {}
         # lazily-staged tile-kernel plane (ensure_kernel): False =
         # unavailable, dict = {geom, meta: {id(seg): (bmin, bmax)}, mode}
         self._kernel = None
@@ -3235,6 +3281,7 @@ class MeshPlanExecutor:
         self._released = False
         self._kernel_stage_lock = threading.Lock()
         self.sort_meta = {}
+        self._numeric_ords = {}
         self._kernel = None
         self._denied = threading.local()
         self._knn = {}
@@ -4003,115 +4050,173 @@ class MeshPlanExecutor:
                                 live_key=live_key, tiles_per_step=tps)
         return len(groups)
 
-    def ensure_sort_column(self, field: str, order: str, missing) -> Optional[
-            Tuple[str, str]]:
+    def numeric_ordinals(self, field: str) -> np.ndarray:
+        """Sorted distinct values of a numeric field over the staged
+        segments (``index/global_ordinals.numeric_global_ordinals``),
+        kept for this generation: a value's position in it is its global
+        ordinal, which numeric ``terms`` count by and rank-keyed sorts
+        order by."""
+        values = self._numeric_ords.get(field)
+        if values is None:
+            from elasticsearch_tpu.index.global_ordinals import (
+                numeric_global_ordinals,
+            )
+
+            values = self._numeric_ords[field] = numeric_global_ordinals(
+                [s.numeric_columns.get(field) for s in self.segments])
+        return values
+
+    def ensure_numeric_column(self, field: str) -> Optional[str]:
+        """Stage a single-valued numeric field as ONE dense int64 column
+        in sortable order (``search/plan.sortable_int64``; a document
+        with no value: ``SORTABLE_MISSING``) and return its seg-dict
+        name, or None where the field is absent, multi-valued in some
+        segment, or the budget turns the staging away: the CSR filter
+        nodes then carry their column with each query, as before. Staged
+        once a generation under the ``doc_values`` ledger kind; a warm
+        request pays a dict lookup."""
+        name = f"mnum.{field}"
+        if name in self._seg_staged:
+            return name
+        from elasticsearch_tpu.search.fused_aggs import _metric_field_checks
+        from elasticsearch_tpu.search.plan import (
+            SORTABLE_MISSING,
+            sortable_int64,
+        )
+
+        facts = _metric_field_checks(self, field)  # kept a generation
+        if not (facts["present"] and facts["single"]):
+            return None
+        cols = [s.numeric_columns.get(field) for s in self.segments]
+
+        def build():
+            out = np.full((self.n_slots, self.nd1), SORTABLE_MISSING,
+                          np.int64)
+            for i, c in enumerate(cols):
+                if c is not None:
+                    n = c.exists.shape[0]
+                    out[i, :n] = np.where(
+                        c.exists, sortable_int64(c.first_value),
+                        SORTABLE_MISSING)
+            return {name: out}
+
+        # (a budget denial is not kept: the next query asks again)
+        return name if self.stage_doc_value_columns({name: build}) else None
+
+    def ensure_sort_column(self, field: str, order: str, missing,
+                           tracer=NULL_TRACER) -> Optional[Tuple[str, str]]:
         """Stage (oriented key, raw values) columns for a single-field sort
         and return their seg-dict names, or None if the field can't sort
-        exactly on the mesh.
+        exactly on the mesh. One pair per (field, order, missing), staged
+        once a generation (the ``staging.sort_column`` span): a warm
+        request finds the names and stages nothing.
 
-        The in-program rank key is f32; a float64 column only qualifies if
-        every value is exactly f32-representable (timestamps usually are
-        not — resolution 2^-24 relative — and silently reordering near-tied
-        dates would be wrong, so those fall back to the host path). The
-        oriented key follows _sort_keys: negate for asc, missing-fill with
-        finite sentinels so -inf stays reserved for "not matched".
+        The in-program rank key is f32. A numeric column whose values are
+        all exactly f32-representable (a status, a size) is its own key.
+        One whose values are not (epoch milliseconds: f32 resolves 65,536
+        ms at 1998) ranks by each document's position in the sorted union
+        of the staged segments' distinct values (``numeric_ordinals``),
+        exact below 2^24 distinct values; the response's ``sort`` value is
+        that union's entry, the stored number. The oriented key follows
+        _sort_keys: negate for asc, missing-fill with finite sentinels so
+        -inf stays reserved for "not matched".
 
-        Keyword fields rank by GLOBAL ordinals: per-segment ordinal spaces
-        are meaningless across shards (the reference's global-ordinals
-        problem, fielddata/ordinals/GlobalOrdinalsBuilder), so the staged
-        key is each doc's position in the sorted union of every staged
-        segment's terms — exact in f32 for < 2^24 distinct terms."""
+        Keyword fields rank the same way by GLOBAL ordinals: per-segment
+        ordinal spaces are meaningless across shards (the reference's
+        global-ordinals problem, fielddata/ordinals/GlobalOrdinalsBuilder),
+        so the staged key is each doc's position in the sorted union of
+        every staged segment's terms."""
         token = (repr(missing) if isinstance(missing, (int, float))
                  else str(missing or "_last"))
         name = f"msort.{field}.{order}.{token}"
         if name in self._seg_staged:
             return name, name + ".raw"
+        t = tracer.start("staging.sort_column")
+        try:
+            built = self._sort_column_values(field, order, missing)
+            if built is None:
+                return None
+            per_seg, vocab = built
+            big = np.float32(3.0e38)
+            keys = np.zeros((self.n_slots, self.nd1), np.float32)
+            raws = np.zeros((self.n_slots, self.nd1), np.float32)
+            for i, (seg, raw) in enumerate(zip(self.segments, per_seg)):
+                key = np.clip(raw if order == "desc" else -raw, -big, big)
+                keys[i, : seg.nd_pad] = key.astype(np.float32)
+                keys[i, seg.nd_pad:] = -big  # padding never outranks real docs
+                raws[i, : seg.nd_pad] = raw.astype(np.float32)
+            self._seg_staged[name] = jax.device_put(keys, self._sharding)
+            self._seg_staged[name + ".raw"] = jax.device_put(
+                raws, self._sharding)
+            # sort key columns are doc-values-plane tables (ISSUE 13): they
+            # derive from the same sealed columns the fused aggs stage, so
+            # they account under the doc_values ledger kind (docs/AGGS.md)
+            self._account("doc_values", name,
+                          int(keys.nbytes + raws.nbytes))
+            self.sort_meta[name] = {"vocab": vocab}
+            return name, name + ".raw"
+        finally:
+            tracer.stop("staging.sort_column", t)
+
+    def _sort_column_values(self, field: str, order: str, missing):
+        """(per-segment float64 [nd_pad] of what ranks each document, the
+        missing-fill applied; vocab) or None. ``vocab`` None: the values
+        rank themselves. A list of terms or an array of numbers: the
+        values are positions in it (see ensure_sort_column)."""
+        big = np.float64(3.0e38)
+        if missing is None or missing == "_last":
+            fill = -big if order == "desc" else big
+        elif missing == "_first":
+            fill = big if order == "desc" else -big
+        else:
+            fill = None  # a custom value: ranks among the real ones
         ords = [s.ordinal_columns.get(field)
                 or s.ordinal_columns.get(f"{field}.keyword")
                 for s in self.segments]
         if any(o is not None for o in ords):
-            return self._ensure_keyword_sort_column(
-                name, ords, order, missing)
-        big = np.float32(3.0e38)
-        keys = np.zeros((self.n_slots, self.nd1), np.float32)
-        raws = np.zeros((self.n_slots, self.nd1), np.float32)
-        for i, seg in enumerate(self.segments):
-            if field == "_doc":
-                if seg.nd_pad > (1 << 24):
-                    return None  # doc id not f32-exact
-                raw = np.arange(seg.nd_pad, dtype=np.float64)
-                exists = np.ones(seg.nd_pad, bool)
-            else:
-                col = seg.numeric_columns.get(field)
-                if col is None:
-                    return None
-                raw = (col.min_value if order == "asc"
-                       else col.max_value).astype(np.float64)
-                exists = col.exists
-                vals = raw[exists]
-                if not np.array_equal(
-                        vals, vals.astype(np.float32).astype(np.float64)):
-                    return None  # not exactly f32-representable
-            if missing is None or missing == "_last":
-                fill = np.float64(-big if order == "desc" else big)
-            elif missing == "_first":
-                fill = np.float64(big if order == "desc" else -big)
-            else:
-                fill = np.float64(missing)
-            raw = np.where(exists, raw, fill)
-            key = np.clip(raw if order == "desc" else -raw, -big, big)
-            keys[i, : seg.nd_pad] = key.astype(np.float32)
-            keys[i, seg.nd_pad:] = -big  # padding never outranks real docs
-            raws[i, : seg.nd_pad] = raw.astype(np.float32)
-        self._seg_staged[name] = jax.device_put(keys, self._sharding)
-        self._seg_staged[name + ".raw"] = jax.device_put(
-            raws, self._sharding)
-        # sort key columns are doc-values-plane tables (ISSUE 13): they
-        # derive from the same sealed columns the fused aggs stage, so
-        # they account under the doc_values ledger kind (docs/AGGS.md)
-        self._account("doc_values", name,
-                      int(keys.nbytes + raws.nbytes))
-        self.sort_meta[name] = {"vocab": None}
-        return name, name + ".raw"
-
-    def _ensure_keyword_sort_column(self, name: str, ords: List,
-                                    order: str, missing) -> Optional[
-            Tuple[str, str]]:
-        """Global-ordinal key columns for a keyword sort (see
-        ensure_sort_column). `ords`: per-segment ordinal column or None
-        (None = every doc in that segment is missing)."""
-        if missing not in (None, "_last", "_first"):
-            return None  # custom-string missing ranks mid-vocab: host path
-        vocab: List[str] = sorted(
-            set().union(*(o.terms for o in ords if o is not None)))
-        if len(vocab) >= (1 << 24):
-            return None  # ordinal not f32-exact
-        big = np.float32(3.0e38)
-        if missing == "_first":
-            fill = np.float64(big if order == "desc" else -big)
-        else:
-            fill = np.float64(-big if order == "desc" else big)
-        keys = np.zeros((self.n_slots, self.nd1), np.float32)
-        raws = np.zeros((self.n_slots, self.nd1), np.float32)
-        for i, (seg, ocol) in enumerate(zip(self.segments, ords)):
-            if ocol is None:
-                raw = np.full(seg.nd_pad, fill)
-            else:
+            if fill is None:
+                return None  # custom-string missing ranks mid-vocab: host
+            vocab: List[str] = sorted(
+                set().union(*(o.terms for o in ords if o is not None)))
+            if len(vocab) >= (1 << 24):
+                return None  # ordinal not f32-exact
+            per_seg = []
+            for seg, ocol in zip(self.segments, ords):
+                if ocol is None:  # every doc in that segment is missing
+                    per_seg.append(np.full(seg.nd_pad, fill))
+                    continue
                 # local ordinal -> global ordinal (terms are sorted, so
                 # searchsorted is the OrdinalMap build)
                 g = np.searchsorted(vocab, ocol.terms).astype(np.float64)
-                raw = np.where(ocol.exists, g[ocol.first_ord], fill)
-            key = np.clip(raw if order == "desc" else -raw, -big, big)
-            keys[i, : seg.nd_pad] = key.astype(np.float32)
-            keys[i, seg.nd_pad:] = -big
-            raws[i, : seg.nd_pad] = raw.astype(np.float32)
-        self._seg_staged[name] = jax.device_put(keys, self._sharding)
-        self._seg_staged[name + ".raw"] = jax.device_put(
-            raws, self._sharding)
-        self._account("doc_values", name,
-                      int(keys.nbytes + raws.nbytes))
-        self.sort_meta[name] = {"vocab": vocab}
-        return name, name + ".raw"
+                per_seg.append(np.where(ocol.exists, g[ocol.first_ord],
+                                        fill))
+            return per_seg, vocab
+        if field == "_doc":
+            if any(seg.nd_pad > (1 << 24) for seg in self.segments):
+                return None  # doc id not f32-exact
+            return [np.arange(seg.nd_pad, dtype=np.float64)
+                    for seg in self.segments], None
+        cols = [seg.numeric_columns.get(field) for seg in self.segments]
+        if any(c is None for c in cols):
+            return None
+        raws = [(c.min_value if order == "asc" else c.max_value
+                 ).astype(np.float64) for c in cols]
+        exact = all(np.array_equal(r[c.exists], r[c.exists].astype(
+            np.float32).astype(np.float64)) for r, c in zip(raws, cols))
+        vocab = None
+        if not exact:
+            # not f32-representable (a NaN among them neither): rank by
+            # global ordinal, which no custom fill has a place in
+            vocab = self.numeric_ordinals(field)
+            if (fill is None or len(vocab) >= (1 << 24)
+                    or not np.all(np.isfinite(vocab))):
+                return None
+            raws = [np.searchsorted(vocab, r).astype(np.float64)
+                    for r in raws]
+        if fill is None:
+            fill = np.float64(missing)
+        return [np.where(c.exists, r, fill)
+                for r, c in zip(raws, cols)], vocab
 
     def ensure_slice_column(self, slice_spec: dict,
                             shard_of_device: List[int],
@@ -4151,7 +4256,8 @@ class MeshPlanExecutor:
         self._account("mesh_slot_tables", name, int(out.nbytes))
         return name
 
-    def stage_doc_value_columns(self, builds: Dict[str, object]) -> bool:
+    def stage_doc_value_columns(self, builds: Dict[str, object],
+                                tracer=NULL_TRACER) -> bool:
         """Stage fused-aggregation doc-value columns (ISSUE 13,
         docs/AGGS.md): ``builds`` maps a representative table name to a
         callable producing ``{name: np.ndarray}`` groups of per-slot
@@ -4164,7 +4270,16 @@ class MeshPlanExecutor:
         evictable with this executor generation's scope. Transient
         device faults retry with the classified backoff
         (``search.staging.retry.*``); a terminal fault propagates to
-        the caller (fallback reason ``staging_fault``)."""
+        the caller (fallback reason ``staging_fault``). The whole of it,
+        building the columns on the host included, is the request's
+        ``staging.doc_values`` span."""
+        t = tracer.start("staging.doc_values")
+        try:
+            return self._stage_doc_value_columns(builds)
+        finally:
+            tracer.stop("staging.doc_values", t)
+
+    def _stage_doc_value_columns(self, builds: Dict[str, object]) -> bool:
         from elasticsearch_tpu.common.memory import memory_accountant
         from elasticsearch_tpu.common.staging import run_staged
 
@@ -4267,5 +4382,23 @@ class MeshPlanExecutor:
         jscalars = {name: jnp.float32(v)
                     for name, v in (scalars or {}).items()}
         tracer.stop("staging", t_stage)
-        return _launch_locked(tracer, run, self._seg_staged, staged_plan,
-                              staged_pf, staged_rs, jscalars)
+        wanted = set(sort_keys or ())
+        if slice_col is not None:
+            wanted.add(slice_col)
+        if agg_static:
+            from elasticsearch_tpu.search.fused_aggs import agg_column_keys
+
+            wanted.update(agg_column_keys(agg_static))
+        for tpl in (plans[0], pf_tpl, rs_tpl):
+            if tpl is not None:
+                wanted.update(tpl.flat_seg_columns())
+        # The program's argument: the base slot tables, and of the
+        # columns staged on demand (sort keys, slice masks, doc values,
+        # filter columns: ``_ON_DEMAND``) those this request names. The
+        # argument's keys are part of the jitted function's cache key:
+        # handed the whole of ``_seg_staged``, every program was traced
+        # and compiled again after a later request staged a column.
+        seg = {name: a for name, a in self._seg_staged.items()
+               if name in wanted or not name.startswith(_ON_DEMAND)}
+        return _launch_locked(tracer, run, seg, staged_plan, staged_pf,
+                              staged_rs, jscalars)

@@ -698,6 +698,11 @@ def _search_body(req):
     if req.param("allow_partial_search_results") is not None:
         body["allow_partial_search_results"] = req.bool_param(
             "allow_partial_search_results")
+    if req.param("request_cache") is not None:
+        # RestSearchAction's request_cache: false keeps this request out
+        # of the shard request cache (IndexService takes the key off the
+        # body before anything else reads it)
+        body["request_cache"] = req.bool_param("request_cache")
     if req.param("track_total_hits") is not None:
         # boolean OR the reference's integer-threshold form; an explicit
         # false is the default behavior, so the key is simply not set

@@ -386,11 +386,20 @@ def _terms_global_merge(spec, views) -> Optional[Dict]:
 _CAL_INTERVALS = {"year": "Y", "quarter": None, "month": "M", "week": "W",
                   "day": "D", "hour": "h", "minute": "m", "second": "s"}
 _FIXED_MS = {"ms": 1, "s": 1000, "m": 60_000, "h": 3_600_000, "d": 86_400_000}
+# named units of one length in UTC, the only zone this module buckets
+# in (no leap second in epoch time, no DST): equal to the calendar
+# rounding to the millisecond, and unlike it they take an ``offset``
+_FIXED_UNIT_MS = {"second": _FIXED_MS["s"], "minute": _FIXED_MS["m"],
+                  "hour": _FIXED_MS["h"], "day": _FIXED_MS["d"]}
 
 
 def _date_interval_ms(interval: str) -> Optional[float]:
-    """Fixed intervals -> millis; calendar intervals return None."""
+    """Fixed intervals (``90s``, ``1h``, and the named units ``second``,
+    ``minute``, ``hour``, ``day``) -> millis; calendar intervals
+    (``week``, ``month``, ``quarter``, ``year``) return None."""
     s = str(interval)
+    if s in _FIXED_UNIT_MS:
+        return float(_FIXED_UNIT_MS[s])
     if s in _CAL_INTERVALS:
         return None
     for unit in sorted(_FIXED_MS, key=len, reverse=True):

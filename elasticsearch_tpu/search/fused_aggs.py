@@ -64,6 +64,7 @@ from elasticsearch_tpu.search.aggregations import (
     finalize_histogram,
     finalize_terms,
 )
+from elasticsearch_tpu.search.telemetry import NULL_TRACER
 
 # metric sums: v is offset to u = v + VALUE_OFFSET and split into
 # N_DIGITS base-2^DIGIT_BITS digits; 6 * 9 bits cover u < 2^54 and a
@@ -90,7 +91,7 @@ _ALLOWED_BODY = {
     "terms": {"field", "size", "order"},
     "histogram": {"field", "interval", "offset", "min_doc_count"},
     "date_histogram": {"field", "interval", "fixed_interval", "offset",
-                       "min_doc_count"},
+                       "min_doc_count", "time_zone"},
     "min": {"field"}, "max": {"field"}, "sum": {"field"},
     "avg": {"field"}, "stats": {"field"}, "value_count": {"field"},
 }
@@ -125,22 +126,27 @@ class FusedAggPlan:
         return tuple(self.ops)
 
     def column_keys(self) -> List[str]:
-        keys: List[str] = []
-        for op in self.ops:
-            if op[0] == "bucket":
-                keys.append(op[1])
-            elif op[0] == "metric":
-                _, base, want_mm, want_dig = op
-                keys.append(base + ".ex")
-                if want_mm:
-                    keys.append(base + ".mm")
-                if want_dig:
-                    keys.append(base + ".dig")
-        return keys
+        return agg_column_keys(self.statics)
 
     def staged_bytes(self, seg_staged: dict) -> int:
         return sum(int(seg_staged[k].nbytes) for k in self.column_keys()
                    if k in seg_staged)
+
+
+def agg_column_keys(statics: tuple) -> List[str]:
+    """The staged doc-value columns a descriptor set reads."""
+    keys: List[str] = []
+    for op in statics:
+        if op[0] == "bucket":
+            keys.append(op[1])
+        elif op[0] == "metric":
+            _, base, want_mm, want_dig = op
+            keys.append(base + ".ex")
+            if want_mm:
+                keys.append(base + ".mm")
+            if want_dig:
+                keys.append(base + ".dig")
+    return keys
 
 
 def n_agg_outputs(statics: tuple) -> int:
@@ -252,7 +258,8 @@ def _resolve_terms(spec, executor, ops, metas, builds) -> Optional[str]:
              or s.ordinal_columns.get(f"{field}.keyword") for s in segs]
     if all(o is None for o in ocols):
         if any(s.numeric_columns.get(field) is not None for s in segs):
-            return "field_ineligible"  # numeric terms: host path
+            return _resolve_numeric_terms(field, executor, ops, metas,
+                                          builds)
         if any(s.terms_for_field(field) for s in segs):
             # text fielddata builds lazily on the host (breaker-gated) —
             # the fused plane stages sealed keyword ordinals only
@@ -300,6 +307,48 @@ def _resolve_terms(spec, executor, ops, metas, builds) -> Optional[str]:
     return None
 
 
+def _resolve_numeric_terms(field, executor, ops, metas,
+                           builds) -> Optional[str]:
+    """``terms`` on a single-valued numeric column: bucket codes are the
+    ordinals of the sorted union of the staged segments' distinct values
+    (``MeshPlanExecutor.numeric_ordinals``), as keyword terms go through
+    ``global_ordinals``; keys come back as the host reduce renders them
+    (``_partial_terms``: an int where the value is whole)."""
+    facts = _metric_field_checks(executor, field)
+    if not facts["single"]:
+        return "multi_valued"
+    if not facts["finite"]:
+        return "values_not_fusable"  # NaN has no ordinal
+    values = executor.numeric_ordinals(field)
+    nb = len(values)
+    if nb > MAX_TERMS_ORDS:
+        return "bucket_range"
+    if nb == 0:
+        ops.append(("empty",))
+        metas.append({"kind": "terms"})
+        return None
+    name = f"maggs.nord.{field}"
+    if name not in executor._seg_staged and name not in builds:
+        def build(name=name):
+            per_seg = []
+            for s in executor.segments:
+                c = s.numeric_columns.get(field)
+                per_seg.append(None if c is None else np.where(
+                    c.exists, np.searchsorted(values, c.first_value),
+                    -1).astype(np.int32))
+            return {name: _build_bucket_codes(executor, per_seg)}
+
+        builds[name] = build
+    cache = executor._agg_field_checks
+    vocab = cache.get(("nord_keys", field))
+    if vocab is None:
+        vocab = cache[("nord_keys", field)] = [
+            int(v) if v.is_integer() else v for v in values.tolist()]
+    ops.append(("bucket", name, nb))
+    metas.append({"kind": "terms", "vocab": vocab})
+    return None
+
+
 def _resolve_histogram(spec, executor, ops, metas, builds) -> Optional[str]:
     from elasticsearch_tpu.common.errors import ParsingException
 
@@ -307,6 +356,8 @@ def _resolve_histogram(spec, executor, ops, metas, builds) -> Optional[str]:
     body = spec.body
     field = body.get("field")
     if is_date:
+        if body.get("time_zone") not in (None, "UTC"):
+            return "unsupported_params"  # buckets are cut in UTC only
         interval_spec = body.get("interval") or body.get("fixed_interval")
         if interval_spec is None:
             return "unsupported_params"
@@ -315,7 +366,7 @@ def _resolve_histogram(spec, executor, ops, metas, builds) -> Optional[str]:
         except ParsingException:
             return "field_ineligible"  # host path owns the 400
         if ms is None:
-            return "unsupported_params"  # calendar interval
+            return "unsupported_params"  # week, month, quarter, year
         interval = float(ms)
     else:
         try:
@@ -486,7 +537,7 @@ def _resolve_metric(spec, executor, ops, metas, builds) -> Optional[str]:
     return None
 
 
-def resolve_fused_aggs(specs: List[AggSpec], executor
+def resolve_fused_aggs(specs: List[AggSpec], executor, tracer=NULL_TRACER
                        ) -> Tuple[Optional[FusedAggPlan], Optional[str]]:
     """Resolve a query's agg set against the staged segment set.
 
@@ -496,7 +547,8 @@ def resolve_fused_aggs(specs: List[AggSpec], executor
     fused and host-reduced frames. Reasons are the documented fallback
     vocabulary (docs/OBSERVABILITY.md). Budget denials return
     ``hbm_budget``; a terminal staging fault propagates to the caller
-    (which reports ``staging_fault``)."""
+    (which reports ``staging_fault``). ``tracer``: the request's, for the
+    ``staging.doc_values`` span of a request that stages."""
     ops: List[tuple] = []
     metas: List[dict] = []
     builds: Dict[str, object] = {}
@@ -524,7 +576,7 @@ def resolve_fused_aggs(specs: List[AggSpec], executor
             return None, reason
     if builds:
         try:
-            staged = executor.stage_doc_value_columns(builds)
+            staged = executor.stage_doc_value_columns(builds, tracer)
         except Exception:  # noqa: BLE001 — classified terminal staging
             # fault (run_staged already retried/recorded): ONLY the
             # device staging step may report staging_fault — a

@@ -46,6 +46,17 @@ class PlanNode:
             out.extend(c.flat_arrays())
         return out
 
+    def seg_columns(self) -> List[str]:
+        """Names of the columns this node reads from ``ctx.seg`` that a
+        mesh executor stages on demand (``StagedNumeric*Node``)."""
+        return []
+
+    def flat_seg_columns(self) -> List[str]:
+        out = list(self.seg_columns())
+        for c in self.children():
+            out.extend(c.flat_seg_columns())
+        return out
+
     def pad_kinds(self) -> List[str]:
         """How each entry of arrays() pads when per-shard plans for the
         SAME query are stacked onto a device mesh (parallel/plan_exec.py).
@@ -495,6 +506,82 @@ class NumericRangeNode(PlanNode):
         flat_docs, flat_values, lo, hi = ctx.take(4)
         cond = (flat_values >= lo) & (flat_values <= hi)
         return ctx.zeros_f(), ctx.zeros_b().at[flat_docs].max(cond)
+
+
+# A float64 as an int64 of the same order (Lucene's
+# NumericUtils.doubleToSortableLong): what a numeric filter compares on
+# the device. A TPU has no float64: XLA emulates it in less than its 53
+# bits, so ``np.nextafter(v, -inf)`` rounds back to ``v`` there and an
+# exclusive bound on a document's own value let the document in
+# (measured, PERF.md 6, PR 33); 64-bit INTEGERS it emulates exactly.
+SORTABLE_MISSING = np.int64(np.iinfo(np.int64).max)  # above +inf's
+
+
+def sortable_int64(values) -> np.ndarray:
+    """``values`` (float64) as int64 in the same order; -0.0 as 0.0."""
+    bits = (np.asarray(values, np.float64) + 0.0).view(np.int64)
+    return bits ^ ((bits >> 63) & np.int64(0x7FFFFFFFFFFFFFFF))
+
+
+class _StagedNumericNode(PlanNode):
+    """A filter on a single-valued numeric field that the mesh executor
+    has staged once, dense over documents, as sortable int64
+    (``MeshPlanExecutor.ensure_numeric_column``; a document with no
+    value holds ``SORTABLE_MISSING``, above every bound). What the query
+    asks for is the only plan array: no column travels with it, and the
+    match is one elementwise comparison, no scatter."""
+
+    def __init__(self, column: str):
+        self.column = column
+
+    def pad_kinds(self):
+        return ["s"] * len(self.arrays())
+
+    def trace_statics(self):
+        return (self.column,)
+
+    def seg_columns(self):
+        return [self.column]
+
+
+class StagedNumericRangeNode(_StagedNumericNode):
+    """``lo <= value <= hi``."""
+
+    def __init__(self, column: str, lo: float, hi: float):
+        super().__init__(column)
+        self.lo = sortable_int64(lo)
+        self.hi = sortable_int64(hi)
+
+    def key(self):
+        return f"snrange[{self.column}]"
+
+    def arrays(self):
+        return [self.lo, self.hi]
+
+    def emit(self, ctx):
+        lo, hi = ctx.take(2)
+        v = ctx.seg[self.column]
+        return ctx.zeros_f(), (v >= lo) & (v <= hi)
+
+
+class StagedNumericTermsNode(_StagedNumericNode):
+    """``value in values``: [K] float64 padded by repeating an entry;
+    never NaN, so never ``SORTABLE_MISSING``."""
+
+    def __init__(self, column: str, values):
+        super().__init__(column)
+        self.values = sortable_int64(values)
+
+    def key(self):
+        return f"snterms[{self.column},{len(self.values)}]"
+
+    def arrays(self):
+        return [self.values]
+
+    def emit(self, ctx):
+        (values,) = ctx.take(1)
+        v = ctx.seg[self.column]
+        return ctx.zeros_f(), (v[:, None] == values[None, :]).any(axis=1)
 
 
 class NumericTermsNode(PlanNode):

@@ -290,6 +290,31 @@ def _numeric_csr(segment, field):
     return docs, vals, col
 
 
+def _staged_numeric(ctx, field):
+    """The name of ``field``'s dense sortable column where this plan is
+    built for a mesh executor that holds (or can stage) one: a
+    single-valued numeric field (``MeshPlanExecutor.
+    ensure_numeric_column``). None everywhere else: the CSR nodes, which
+    carry their column with the plan, serve."""
+    columns = getattr(ctx, "mesh_columns", None)
+    return None if columns is None else columns.ensure_numeric_column(field)
+
+
+def _numeric_terms_node(ctx, segment, field, nums):
+    """``field in nums`` over a numeric doc-value column, or None where
+    the segment has none."""
+    staged = _staged_numeric(ctx, field)
+    if staged is not None:
+        return P.StagedNumericTermsNode(
+            staged, _pad_pow2(nums, nums[0], min_len=1, dtype=np.float64))
+    csr = _numeric_csr(segment, field)
+    if csr is None:
+        return None
+    docs, vals, _ = csr
+    return P.NumericTermsNode(
+        docs, vals, _pad_pow2(nums, np.nan, min_len=1, dtype=np.float64))
+
+
 def _ordinal_csr(segment, field):
     col = segment.ordinal_columns.get(field)
     if col is None:
@@ -681,26 +706,17 @@ class TermQueryBuilder(QueryBuilder):
             v = ft.numeric_for_query(self.value)
             return _range_pair_node(segment, self.field, v, v, "intersects",
                                     self.boost)
-        if isinstance(ft, NumberFieldType) or isinstance(ft, DateFieldType):
-            csr = _numeric_csr(segment, self.field)
-            if csr is None:
-                return P.MatchNoneNode()
-            docs, vals, _ = csr
-            v = ft.numeric_for_query(self.value)
-            return P.ConstantScoreNode(P.NumericTermsNode(
-                docs, vals, _pad_pow2([v], np.nan, min_len=1, dtype=np.float64)
-            ), self.boost)
-        if isinstance(ft, IpFieldType):
-            csr = _numeric_csr(segment, self.field)
-            if csr is None:
-                return P.MatchNoneNode()
-            docs, vals, _ = csr
-            from elasticsearch_tpu.mapper.field_types import parse_ip
+        if isinstance(ft, (NumberFieldType, DateFieldType, IpFieldType)):
+            if isinstance(ft, IpFieldType):
+                from elasticsearch_tpu.mapper.field_types import parse_ip
 
-            v = float(parse_ip(self.value))
-            return P.ConstantScoreNode(P.NumericTermsNode(
-                docs, vals, _pad_pow2([v], np.nan, min_len=1, dtype=np.float64)
-            ), self.boost)
+                v = float(parse_ip(self.value))
+            else:
+                v = ft.numeric_for_query(self.value)
+            node = _numeric_terms_node(ctx, segment, self.field, [v])
+            if node is None:
+                return P.MatchNoneNode()
+            return P.ConstantScoreNode(node, self.boost)
         # term against the inverted index (keyword/boolean/text-raw-token)
         token = (ft.term_for_query(self.value, ctx.analyzers)
                  if ft is not None and not isinstance(ft, TextFieldType)
@@ -740,15 +756,12 @@ class TermsQueryBuilder(QueryBuilder):
                     ctx, segment)
         ft = ctx.field_type(self.field)
         if isinstance(ft, (NumberFieldType, DateFieldType)):
-            csr = _numeric_csr(segment, self.field)
-            if csr is None:
-                return P.MatchNoneNode()
-            docs, vals, _ = csr
             nums = [ft.numeric_for_query(v) for v in self.values]
-            return P.ConstantScoreNode(P.NumericTermsNode(
-                docs, vals,
-                _pad_pow2(nums, np.nan, min_len=1, dtype=np.float64),
-            ), self.boost)
+            node = (_numeric_terms_node(ctx, segment, self.field, nums)
+                    if nums else None)
+            if node is None:
+                return P.MatchNoneNode()
+            return P.ConstantScoreNode(node, self.boost)
         # constant-score terms over ordinals if the field has them, else
         # inverted-index disjunction
         col = segment.ordinal_columns.get(self.field)
@@ -817,10 +830,10 @@ class RangeQueryBuilder(QueryBuilder):
         if isinstance(ft, (NumberFieldType, DateFieldType, BooleanFieldType, IpFieldType)) or (
             ft is None and segment.numeric_columns.get(self.field) is not None
         ):
-            csr = _numeric_csr(segment, self.field)
-            if csr is None:
+            staged = _staged_numeric(ctx, self.field)
+            csr = None if staged else _numeric_csr(segment, self.field)
+            if staged is None and csr is None:
                 return P.MatchNoneNode()
-            docs, vals, _ = csr
             conv = (ft.numeric_for_query if ft is not None else float)
             if isinstance(ft, IpFieldType):
                 from elasticsearch_tpu.mapper.field_types import parse_ip
@@ -835,6 +848,10 @@ class RangeQueryBuilder(QueryBuilder):
                 hi = conv(self.lte)
             if self.lt is not None:
                 hi = np.nextafter(conv(self.lt), -np.inf)
+            if staged is not None:
+                return P.ConstantScoreNode(
+                    P.StagedNumericRangeNode(staged, lo, hi), self.boost)
+            docs, vals, _ = csr
             return P.ConstantScoreNode(P.NumericRangeNode(docs, vals, lo, hi), self.boost)
         col = segment.ordinal_columns.get(self.field)
         if col is not None:

@@ -307,7 +307,7 @@ class TestIndexMeshAggsSort:
             assert ([h["sort"] for h in got["hits"]["hits"]]
                     == [h["sort"] for h in want["hits"]["hits"]]), missing
 
-    def test_non_f32_exact_sort_falls_back(self, pair):
+    def test_non_f32_exact_sort_ranks_by_ordinal_on_the_mesh(self, pair):
         mesh_idx, _ = pair
         # a fresh float column with non-f32-exact values via a new index
         from elasticsearch_tpu.common.settings import Settings
@@ -324,12 +324,14 @@ class TestIndexMeshAggsSort:
                   if idx._mesh_search is not None else 0)
         r = idx.search({"query": {"match_all": {}},
                         "sort": [{"t": "asc"}], "size": 5})
-        # host fallback must serve it correctly (exact f64 ordering)
+        # exact f64 ordering and the stored values, from the mesh program:
+        # the key is each value's rank among the distinct values (f32
+        # holds 1.7e12 to 131,072 and would tie all thirty)
         assert [h["sort"] for h in r["hits"]["hits"]] == [
             [1700000000000.0 + d] for d in range(5)]
-        after = (idx._mesh_search.query_total
-                 if idx._mesh_search is not None else 0)
-        assert after == before  # mesh path declined
+        assert r["_plane"] == "mesh"
+        assert idx._mesh_search.query_total == before + 1
+        assert idx._mesh_search.sort_device_query_total == 1
         idx.close()
 
 

@@ -133,3 +133,33 @@ class TestRequestCache:
         assert s["memory_size_in_bytes"] <= 3000
         # most recent entries survive
         assert cache.get("k49") is not None
+
+
+class TestRequestCacheUrlParameter:
+    """``?request_cache=false`` (RestSearchAction) keeps one request out
+    of the cache: Rally's http_logs runs ``hourly_agg`` that way."""
+
+    def test_false_opts_a_request_out_and_true_leaves_it_cached(self):
+        from elasticsearch_tpu.client import Client
+
+        node = make_node()
+        client = Client(node)
+        for _ in range(2):
+            status, r = client.perform(
+                "POST", "/logs/_search", {"request_cache": "false"},
+                body=AGG_BODY)
+            assert status == 200 and r["hits"]["total"] == 40
+            assert len(r["aggregations"]["hosts"]["buckets"]) == 4
+        assert cache_stats(node)["miss_count"] == 0
+        assert cache_stats(node)["entries"] == 0
+        for _ in range(2):
+            status, r = client.perform(
+                "POST", "/logs/_search", {"request_cache": "true"},
+                body=AGG_BODY)
+            assert status == 200
+        s = cache_stats(node)
+        assert (s["miss_count"], s["hit_count"], s["entries"]) == (1, 1, 1)
+        # the parameter is no part of the search: the same entry serves
+        # a request without it
+        node.search("logs", dict(AGG_BODY))
+        assert cache_stats(node)["hit_count"] == 2

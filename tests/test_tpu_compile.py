@@ -262,19 +262,11 @@ def _for_tpu(plan):
     return plan
 
 
-def _recorded_serial_launch(monkeypatch, n_shards, n_devices, n_docs):
-    """A scratch harness: a small index is searched on virtual CPU
-    devices in interpret mode and the call into ``_mesh_query_program``
-    is recorded. Returns (the real program builder, what was seen: the
+def _recording_programs(monkeypatch):
+    """(the real ``_mesh_query_program``, what its last launch saw: the
     template holder, the builder's arguments, the launch's arrays)."""
-    import numpy as np
-
-    from elasticsearch_tpu.common.settings import Settings
-    from elasticsearch_tpu.index.index_service import IndexService
     from elasticsearch_tpu.parallel import plan_exec
-    from elasticsearch_tpu.parallel.mesh import shard_mesh
 
-    monkeypatch.setenv("ES_TPU_PALLAS", "interpret")
     build_program = plan_exec._mesh_query_program
     seen = {}
 
@@ -289,6 +281,23 @@ def _recorded_serial_launch(monkeypatch, n_shards, n_devices, n_docs):
         return call
 
     monkeypatch.setattr(plan_exec, "_mesh_query_program", recording)
+    return build_program, seen
+
+
+def _recorded_serial_launch(monkeypatch, n_shards, n_devices, n_docs):
+    """A scratch harness: a small index is searched on virtual CPU
+    devices in interpret mode and the call into ``_mesh_query_program``
+    is recorded. Returns (the real program builder, what was seen: the
+    template holder, the builder's arguments, the launch's arrays)."""
+    import numpy as np
+
+    from elasticsearch_tpu.common.settings import Settings
+    from elasticsearch_tpu.index.index_service import IndexService
+    from elasticsearch_tpu.parallel import plan_exec
+    from elasticsearch_tpu.parallel.mesh import shard_mesh
+
+    monkeypatch.setenv("ES_TPU_PALLAS", "interpret")
+    build_program, seen = _recording_programs(monkeypatch)
     idx = IndexService("tpucompile", Settings({
         "index.number_of_shards": n_shards,
         "index.search.mesh": True,
@@ -414,3 +423,81 @@ def test_serial_mesh_program_has_one_replicated_merge_output(
     # the host's unpack reads that very layout
     rows = plan_exec._unpack_answer(np.zeros(packed.shape, packed.dtype))
     assert [r.shape for r in rows] == [(k,), (k,), (k,), (), (k,), (k,)]
+
+
+# ----------------------------------------------------------------------
+# The log-search cell's programs (ISSUE 33): no text scored, the scatter
+# rung; range filters on a staged int64 column, fused histogram and
+# terms counts, rank-keyed sorts
+# ----------------------------------------------------------------------
+
+# (the cell pads to 65,537 documents a slot; the described chip's
+# compiler takes 30 s a program at that size, most of it the top-k's
+# sort, and 3 s at this one: what it refuses does not depend on it)
+LOGS_ND1 = (1 << 12) + 1
+LOGS_T0 = 897436800000
+LOGS_RANGE = {"range": {"@timestamp": {
+    "gte": LOGS_T0 + 3456 * 20, "lt": LOGS_T0 + 3456 * 200}}}
+LOGS_HOURS = {"date_histogram": {"field": "@timestamp", "interval": "hour"}}
+LOGS_REQUESTS = {  # (hourly_agg and range are parts of these)
+    "panel": {"size": 0, "query": LOGS_RANGE, "aggs": {
+        "by_hour": LOGS_HOURS, "status": {"terms": {"field": "status"}}}},
+    "200s-in-range": {"query": {"bool": {"must": [
+        LOGS_RANGE, {"match": {"status": 200}}]}}},
+    "desc_sort_timestamp": {"query": {"match_all": {}},
+                            "sort": [{"@timestamp": "desc"}]},
+}
+
+
+@pytest.mark.parametrize("name", sorted(LOGS_REQUESTS))
+def test_log_search_programs_compile_for_one_chip(topo, monkeypatch, name):
+    """The request shapes of ``http-logs-search-serial`` for one
+    described chip, four slots. No float64 reaches the
+    device: a TPU emulates it in fewer bits than it has (an exclusive
+    bound on a document's own timestamp let the document in on the chip,
+    PERF.md 6, PR 33), so the filters compare int64 in the order of the
+    float64 values and the sorts rank by ordinal."""
+    import numpy as np
+
+    from elasticsearch_tpu.common.settings import Settings
+    from elasticsearch_tpu.index.index_service import IndexService
+    from elasticsearch_tpu.parallel import plan_exec
+    from elasticsearch_tpu.parallel.mesh import shard_mesh
+
+    build_program, seen = _recording_programs(monkeypatch)
+    idx = IndexService(f"tpulogs-{name}", Settings({
+        "index.number_of_shards": 2, "index.search.mesh": True,
+        "index.refresh_interval": -1,
+    }), mapping={"properties": {"@timestamp": {"type": "date"},
+                                "status": {"type": "integer"}}})
+    idx._mesh_search = plan_exec.IndexMeshSearch(idx, mesh=shard_mesh(1))
+    try:
+        for d in range(300):
+            idx.index_doc(str(d), {"@timestamp": LOGS_T0 + 3456 * d,
+                                   "status": (200, 304, 404)[d % 3]})
+        idx.refresh()
+        resp = idx.search(dict(LOGS_REQUESTS[name]))
+    finally:
+        idx.close()
+    assert resp["_plane"] == "mesh"
+    seg = seen["arrays"][0]
+    small = seg["live1"].shape[1]
+    at_size = {n: tuple(LOGS_ND1 if d == small else d for d in a.shape)
+               for n, a in seg.items() if small in a.shape}
+    assert "live1" in at_size and at_size["live1"] == (4, LOGS_ND1)
+    # the request's own columns are in the argument, and no other's
+    on_demand = {n for n in seg if n.startswith(plan_exec._ON_DEMAND)}
+    assert on_demand == {
+        "panel": {"maggs.hist.@timestamp.date_histogram.3600000.0.0.0",
+                  "maggs.nord.status", "mnum.@timestamp"},
+        "200s-in-range": {"mnum.@timestamp", "mnum.status"},
+        "desc_sort_timestamp": {"msort.@timestamp.desc._last",
+                                "msort.@timestamp.desc._last.raw"},
+    }[name]
+    assert all(seg[n].dtype == np.int64 for n in on_demand
+               if n.startswith("mnum."))
+    text = _compiled_for_tpu(build_program, seen, topo.devices[:1],
+                             at_size).as_text()
+    assert "f64[" not in text
+    assert "tpu_custom_call" not in text  # no kernel: the scatter rung
+    assert _conditionals(text) == 4
