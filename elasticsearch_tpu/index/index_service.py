@@ -1886,6 +1886,16 @@ class IndexService:
                 "agg_host_fallback_total": (
                     self._mesh_search.agg_host_fallback_total
                     if self._mesh_search is not None else 0),
+                # the fused queries' bucket counts, by formulation: a
+                # dense compare-and-sum up to
+                # fused_aggs.DENSE_COUNT_MAX_BUCKETS, a one-hot product
+                # above
+                "agg_bucket_dense_total": (
+                    self._mesh_search.agg_bucket_dense_total
+                    if self._mesh_search is not None else 0),
+                "agg_bucket_product_total": (
+                    self._mesh_search.agg_bucket_product_total
+                    if self._mesh_search is not None else 0),
                 # field sorts ranked inside the mesh program (the ladder's
                 # host.sort_ineligible counts those that left it)
                 "sort_device_query_total": (

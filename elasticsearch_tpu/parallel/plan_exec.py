@@ -1179,6 +1179,10 @@ class IndexMeshSearch:
         # vs agg'd mesh queries that fell back to the host reduce over
         # device views — per documented reason (docs/OBSERVABILITY.md)
         self.agg_fused_query_total = 0
+        # their bucket counts by the formulation the program traced for
+        # each (fused_aggs.DENSE_COUNT_MAX_BUCKETS)
+        self.agg_bucket_dense_total = 0
+        self.agg_bucket_product_total = 0
         # queries whose field sort ranked inside the mesh program
         self.sort_device_query_total = 0
         self.agg_host_fallback_total = 0
@@ -1712,6 +1716,21 @@ class IndexMeshSearch:
             self.agg_host_fallback_total += n
             self.agg_host_fallback_by_reason[reason] = \
                 self.agg_host_fallback_by_reason.get(reason, 0) + n
+
+    def _note_fused_aggs(self, plans) -> None:
+        """Queries served fused, and their bucket counts on either side
+        of ``fused_aggs.DENSE_COUNT_MAX_BUCKETS``."""
+        from elasticsearch_tpu.search.fused_aggs import (
+            DENSE_COUNT_MAX_BUCKETS,
+        )
+
+        sizes = [op[2] for plan in plans for op in plan.statics
+                 if op[0] == "bucket"]
+        dense = sum(nb <= DENSE_COUNT_MAX_BUCKETS for nb in sizes)
+        with self._counter_lock:
+            self.agg_fused_query_total += len(plans)
+            self.agg_bucket_dense_total += dense
+            self.agg_bucket_product_total += len(sizes) - dense
 
     def _resolve_fused_aggs(self, agg_specs, executor,
                             tracer=NULL_TRACER):
@@ -2453,8 +2472,7 @@ class IndexMeshSearch:
 
                 aggregations = finalize_fused(agg_plan, agg_outs,
                                               len(executor.pairs))
-                with self._counter_lock:
-                    self.agg_fused_query_total += 1
+                self._note_fused_aggs([agg_plan])
                 tel = self._telemetry
                 if tel is not None:
                     # doc-value column bytes the fused launch read in
@@ -2954,9 +2972,8 @@ class IndexMeshSearch:
                     plan, agg_raw[pos: pos + n], n_pairs)
                 pos += n
             bt.stop("aggregate", t_aggf)
-            with self._counter_lock:
-                self.agg_fused_query_total += sum(
-                    1 for p in member_agg_plans if p is not None)
+            self._note_fused_aggs(
+                [p for p in member_agg_plans if p is not None])
         t_merge = bt.start("merge")
         results = []
         for q, body in enumerate(bodies):

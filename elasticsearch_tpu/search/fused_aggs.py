@@ -28,7 +28,8 @@ hoped for:
   histogram/date_histogram), cached per (field, interval, offset) on
   the executor — the device only counts int32 codes, so bucketing can
   never diverge by f32 rounding;
-- **counts** accumulate in int32 (exact);
+- **counts** accumulate in int32 (exact), by a compare-and-sum or a
+  one-hot product, never a scatter (``_bucket_counts``);
 - **sums** ride an exact integer-digit decomposition: each value
   ``v`` (eligible only when every value is an integer with
   ``|v| < 2^48`` and the column's ``sum(|v|) < 2^53`` — epoch-millis
@@ -80,6 +81,19 @@ MM_SPLIT = float(1 << 24)  # min/max hi/lo split point (both halves f32-exact)
 
 MAX_HIST_BUCKETS = 4096
 MAX_TERMS_ORDS = 1 << 16
+# The largest bucket count that ``_bucket_counts`` takes by the dense
+# compare-and-sum; above it the one-hot product. Device time of one
+# count of 65,537 documents, us (TPU v5e; PERF.md 6, PR 34):
+#
+#   buckets            5    96   128   1024   2048   3072   4096   65536
+#   scatter-add      577   577   577    577    479    456    449     444
+#   dense compare      2     5    39     51    109    163    249   6,243
+#   one-hot product   76    76    76    121    121    123    124      99
+#
+# (of 1,048,577 documents at 2,048 buckets: 7,611 / 1,727 / 1,926). The
+# dense count grows with the buckets and the product hardly does; they
+# cross just above 2,048, whatever the number of documents.
+DENSE_COUNT_MAX_BUCKETS = 2048
 
 FUSED_BUCKET_TYPES = ("terms", "histogram", "date_histogram")
 FUSED_METRIC_TYPES = ("min", "max", "sum", "avg", "stats", "value_count")
@@ -164,6 +178,36 @@ def n_agg_outputs(statics: tuple) -> int:
 # ---------------------------------------------------------------------------
 
 
+def _bucket_counts(codes, mask, nb: int):
+    """int32 [nb]: how many of the masked documents carry each bucket
+    code. ``codes``: int32 [nd1], -1 = no value. Not a scatter-add: the
+    chip runs its updates one after the other (0.44-0.58 ms for 65,537
+    documents, whatever ``nb``). Both forms below give the same exact
+    integers and compile to one fusion that writes nothing of
+    ``nb x nd1`` to memory; ``DENSE_COUNT_MAX_BUCKETS`` has the table
+    that chooses."""
+    import jax
+    import jax.numpy as jnp
+
+    # a masked document counts like one with no value: -1 is no bucket
+    codes = jnp.where(mask, codes, jnp.int32(-1))
+    if nb <= DENSE_COUNT_MAX_BUCKETS:
+        # every code against every bucket, summed over the documents
+        buckets = jnp.arange(nb, dtype=jnp.int32)[:, None]
+        return jnp.sum(codes[None, :] == buckets, axis=1, dtype=jnp.int32)
+    # code = 128 * hi + lo, and counts[hi, lo] = sum over the documents
+    # of onehot(hi) * onehot(lo): nd1 x (nb / 128 + 128) compares and a
+    # matrix product of int8 one-hots accumulated in int32 on the MXU
+    # (-1 >> 7 = -1 is no row)
+    n_hi = -(-nb // 128)
+    hi = (codes >> 7)[:, None] == jnp.arange(n_hi, dtype=jnp.int32)
+    lo = (codes & 127)[:, None] == jnp.arange(128, dtype=jnp.int32)
+    counts = jax.lax.dot_general(
+        hi.astype(jnp.int8), lo.astype(jnp.int8),
+        (((0,), (0,)), ((), ())), preferred_element_type=jnp.int32)
+    return counts.reshape(-1)[:nb]
+
+
 def emit_agg_partials(statics: tuple, seg: dict, mask):
     """Per-slot partial accumulators for one (slot, mask) pair, traced
     into the mesh program. ``mask``: bool [nd1] — the agg-visible
@@ -179,11 +223,7 @@ def emit_agg_partials(statics: tuple, seg: dict, mask):
             continue
         if op[0] == "bucket":
             _, key, nb = op
-            codes = seg[key]  # [nd1] int32, -1 = no value
-            sel = mask & (codes >= 0)
-            safe = jnp.where(sel, codes, jnp.int32(0))
-            outs.append(jnp.zeros((nb,), jnp.int32).at[safe].add(
-                sel.astype(jnp.int32)))
+            outs.append(_bucket_counts(seg[key], mask, nb))
             continue
         _, base, want_mm, want_dig = op
         sel = mask & seg[base + ".ex"]

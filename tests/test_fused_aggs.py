@@ -17,6 +17,11 @@ import pytest
 from elasticsearch_tpu.common.memory import memory_accountant
 from elasticsearch_tpu.common.settings import Settings
 from elasticsearch_tpu.index.index_service import IndexService
+from elasticsearch_tpu.search import fused_aggs
+from elasticsearch_tpu.search.fused_aggs import (
+    DENSE_COUNT_MAX_BUCKETS,
+    emit_agg_partials,
+)
 from elasticsearch_tpu.testing.disruption import (
     PlaneFailScheme,
     QueuePressureScheme,
@@ -122,6 +127,9 @@ class TestFusedParity:
             assert ms.agg_fused_query_total == 1
             assert ms.agg_host_fallback_total == 0, \
                 ms.agg_host_fallback_by_reason
+            # one count a bucket aggregation, all of them small
+            assert (ms.agg_bucket_dense_total,
+                    ms.agg_bucket_product_total) == (6, 0)
             # the doc_values ledger kind is populated by the staged
             # agg/sort columns and visible in _stats search.memory
             mem = mesh.search_stats()["memory"]
@@ -423,3 +431,79 @@ class TestLedgerLifecycle:
             assert acct.staged_bytes(name) == 0, (
                 f"doc_values ledger leaked for [{name}] across "
                 f"merge/evict cycles")
+
+
+# bucket counts on both sides of the limit between the two formulations
+BUCKET_SIZES = [1, 5, 96, DENSE_COUNT_MAX_BUCKETS,
+                DENSE_COUNT_MAX_BUCKETS + 1, 4096]
+BUCKET_ND1 = 1537  # no multiple of a tile
+
+
+def _bucket_case(case, nb, rng):
+    codes = rng.randint(-1, nb, size=BUCKET_ND1).astype(np.int32)
+    mask = rng.rand(BUCKET_ND1) < 0.5
+    if case == "all_false":
+        mask[:] = False
+    elif case == "all_true":
+        mask[:] = True
+    elif case == "every_code_missing":
+        codes[:] = -1
+    elif case == "codes_only_at_last":
+        codes[:] = nb - 1
+    return codes, mask
+
+
+class TestBucketCounts:
+    @pytest.mark.parametrize("case", [
+        "all_false", "all_true", "random", "every_code_missing",
+        "codes_only_at_last"])
+    @pytest.mark.parametrize("nb", BUCKET_SIZES)
+    def test_bucket_partial_is_the_bincount_of_the_selected_codes(
+            self, nb, case):
+        import jax
+
+        codes, mask = _bucket_case(case, nb, np.random.RandomState(nb))
+        statics = (("bucket", "codes", nb),)
+        (got,) = jax.jit(
+            lambda c, m: emit_agg_partials(statics, {"codes": c}, m))(
+                codes, mask)
+        got = np.asarray(got)
+        assert got.dtype == np.int32 and got.shape == (nb,)
+        selected = codes[mask & (codes >= 0)]
+        assert np.array_equal(got, np.bincount(selected, minlength=nb))
+
+    @pytest.mark.parametrize("nb", BUCKET_SIZES)
+    def test_dead_slot_stand_in_yields_the_identity(self, nb):
+        # what ``_mesh_query_program.dead_slot`` asks: one document, an
+        # all-false mask
+        (got,) = emit_agg_partials(
+            (("bucket", "codes", nb),),
+            {"codes": np.zeros((1,), np.int32)}, np.zeros((1,), bool))
+        got = np.asarray(got)
+        assert got.dtype == np.int32 and got.shape == (nb,)
+        assert not got.any()
+
+    def test_counters_say_which_formulation_each_count_traced(
+            self, monkeypatch):
+        # (the limit is read as the program is traced and as the query
+        # is counted: lowered, this small index has counts on both sides)
+        monkeypatch.setattr(fused_aggs, "DENSE_COUNT_MAX_BUCKETS", 3)
+        mesh, host = build_pair("fbc")
+        try:
+            body = {"query": {"match": {"body": "t0 t1"}}, "size": 3,
+                    "aggs": {"tags": {"terms": {"field": "tag"}},  # 4
+                             "h5": {"histogram": {"field": "n",
+                                                  "interval": 5}},  # 4
+                             "h8": {"histogram": {"field": "n",
+                                                  "interval": 8}},  # 3
+                             "sm": {"sum": {"field": "n"}}}}
+            got = mesh.search(dict(body))
+            assert got["_plane"] == "mesh_pallas", got["_plane"]
+            assert_parity(got, host.search(dict(body)))
+            planes = mesh.search_stats()["planes"]
+            assert planes["agg_fused_query_total"] == 1
+            assert planes["agg_bucket_dense_total"] == 1
+            assert planes["agg_bucket_product_total"] == 2
+        finally:
+            mesh.close()
+            host.close()

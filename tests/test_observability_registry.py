@@ -140,7 +140,8 @@ class TestObservabilityRegistryLint:
         doc = _doc_text()
         planes = exercised_index.search_stats()["planes"]
         for key in ("agg_fused_query_total", "agg_host_fallback_total",
-                    "agg_host_fallback_by_reason"):
+                    "agg_host_fallback_by_reason",
+                    "agg_bucket_dense_total", "agg_bucket_product_total"):
             assert key in planes, planes.keys()
             assert key in doc, f"[{key}] undocumented"
         # the `aggregate` phase joined the taxonomy ring

@@ -22,6 +22,7 @@ from jax.sharding import SingleDeviceSharding
 from elasticsearch_tpu.ops import pallas_aggs as pag
 from elasticsearch_tpu.ops import pallas_knn as pkn
 from elasticsearch_tpu.ops import pallas_scoring as psc
+from elasticsearch_tpu.search.fused_aggs import DENSE_COUNT_MAX_BUCKETS
 
 ND_PAD = 1 << 20
 # 1M docs x ~80 tokens / 128 postings per block, rounded up
@@ -436,8 +437,19 @@ def test_serial_mesh_program_has_one_replicated_merge_output(
 # sort, and 3 s at this one: what it refuses does not depend on it)
 LOGS_ND1 = (1 << 12) + 1
 LOGS_T0 = 897436800000
+LOGS_DOCS = 300
+
+
+def _logs_ts(d):
+    """Document ``d``'s timestamp: the documents span as many hours as
+    the dense count takes buckets, so the panel's histogram is the
+    largest that formulation serves."""
+    return LOGS_T0 + d * (DENSE_COUNT_MAX_BUCKETS - 1) * 3_600_000 // (
+        LOGS_DOCS - 1)
+
+
 LOGS_RANGE = {"range": {"@timestamp": {
-    "gte": LOGS_T0 + 3456 * 20, "lt": LOGS_T0 + 3456 * 200}}}
+    "gte": _logs_ts(20), "lt": _logs_ts(200)}}}
 LOGS_HOURS = {"date_histogram": {"field": "@timestamp", "interval": "hour"}}
 LOGS_REQUESTS = {  # (hourly_agg and range are parts of these)
     "panel": {"size": 0, "query": LOGS_RANGE, "aggs": {
@@ -472,8 +484,8 @@ def test_log_search_programs_compile_for_one_chip(topo, monkeypatch, name):
                                 "status": {"type": "integer"}}})
     idx._mesh_search = plan_exec.IndexMeshSearch(idx, mesh=shard_mesh(1))
     try:
-        for d in range(300):
-            idx.index_doc(str(d), {"@timestamp": LOGS_T0 + 3456 * d,
+        for d in range(LOGS_DOCS):
+            idx.index_doc(str(d), {"@timestamp": _logs_ts(d),
                                    "status": (200, 304, 404)[d % 3]})
         idx.refresh()
         resp = idx.search(dict(LOGS_REQUESTS[name]))
@@ -496,8 +508,20 @@ def test_log_search_programs_compile_for_one_chip(topo, monkeypatch, name):
     }[name]
     assert all(seg[n].dtype == np.int64 for n in on_demand
                if n.startswith("mnum."))
-    text = _compiled_for_tpu(build_program, seen, topo.devices[:1],
-                             at_size).as_text()
+    compiled = _compiled_for_tpu(build_program, seen, topo.devices[:1],
+                                 at_size)
+    text = compiled.as_text()
     assert "f64[" not in text
     assert "tpu_custom_call" not in text  # no kernel: the scatter rung
     assert _conditionals(text) == 4
+    if name == "panel":
+        # the counts compare and sum: no scatter-add, and nothing of
+        # buckets x documents is written to memory (the program's
+        # temporaries, all of them, are smaller than ONE such array)
+        hist = "maggs.hist.@timestamp.date_histogram.3600000.0.0.0"
+        assert seen["kwargs"]["agg_static"] == (
+            ("bucket", hist, DENSE_COUNT_MAX_BUCKETS),
+            ("bucket", "maggs.nord.status", 3))
+        assert " scatter(" not in text
+        assert (compiled.memory_analysis().temp_size_in_bytes
+                < DENSE_COUNT_MAX_BUCKETS * LOGS_ND1 * 4)
