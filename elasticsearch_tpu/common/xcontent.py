@@ -29,6 +29,25 @@ MIME = {
 }
 
 
+class JsonTextDict(dict):
+    """A JSON object parsed from one line of a request that still has
+    the text it was parsed from: ``_source`` is kept as sent, so what is
+    written (translog, a flushed segment's sources) is that text and
+    not a second serialisation of the parsed object. Only what nobody
+    changes may carry it: whoever alters a source (an ingest pipeline,
+    an update) hands a plain ``dict`` on."""
+
+    __slots__ = ("text",)
+
+
+def json_text(obj) -> str:
+    """One line of JSON for ``obj``: the text it was sent as where it
+    still has it (``JsonTextDict``), else its compact serialisation."""
+    text = getattr(obj, "text", None)
+    return text if text is not None else json.dumps(
+        obj, separators=(",", ":"))
+
+
 class XContentParseError(ValueError):
     pass
 

@@ -1876,6 +1876,15 @@ class IndexService:
                 "knn_query_total": (
                     self._mesh_search.knn_query_total
                     if self._mesh_search is not None else 0),
+                # the slots whose kNN pass ran, a query each (a slot
+                # with no live vector is skipped), and the bf16
+                # embedding bytes those passes streamed, a launch
+                "knn_slots_scanned_total": (
+                    self._mesh_search.knn_slots_scanned_total
+                    if self._mesh_search is not None else 0),
+                "embedding_bytes_streamed_total": (
+                    self._mesh_search.embedding_bytes_streamed_total
+                    if self._mesh_search is not None else 0),
                 # fused on-device aggregations (ISSUE 13, docs/AGGS.md):
                 # agg'd queries whose whole agg set reduced inside the
                 # mesh program vs those that fell back to the host

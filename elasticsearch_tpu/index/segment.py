@@ -35,6 +35,7 @@ from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
 
+from elasticsearch_tpu.common.xcontent import json_text
 from elasticsearch_tpu.common.memory import (
     KIND_BOUND_TABLES,
     KIND_DOC_VALUES,
@@ -104,6 +105,50 @@ class OrdinalColumn:
             hi_ord = (bisect.bisect_right(self.terms, hi) if include_hi
                       else bisect.bisect_left(self.terms, hi))
         return lo_ord, hi_ord
+
+
+class StoredSources:
+    """``_source`` of a run of documents, by local doc id: each kept as
+    the JSON text it was sent or stored as until someone reads it, then
+    as the parsed object (a bulk-loaded segment is flushed, reopened and
+    searched without its sources ever being parsed or serialised again;
+    a hit's source is parsed on its first fetch and found parsed after).
+    Reads give the parsed object, as the list of dicts this replaces
+    did."""
+
+    __slots__ = ("_items",)
+
+    def __init__(self, items=()):
+        self._items = []
+        for item in items:
+            self.append(item)
+
+    def append(self, source) -> None:
+        """A source as text (a ``str``, or a ``JsonTextDict``'s) or as
+        the parsed object."""
+        self._items.append(getattr(source, "text", None) or source)
+
+    def __len__(self) -> int:
+        return len(self._items)
+
+    def __getitem__(self, doc: int):
+        item = self._items[doc]
+        if isinstance(item, str):
+            item = self._items[doc] = json.loads(item)
+        return item
+
+    def __iter__(self):
+        return (self[doc] for doc in range(len(self._items)))
+
+    def texts(self):
+        """Each document's source as one line of JSON."""
+        return (item if isinstance(item, str) else json_text(item)
+                for item in self._items)
+
+    def reordered(self, perm) -> "StoredSources":
+        out = StoredSources()
+        out._items = [self._items[p] for p in perm]
+        return out
 
 
 @dataclass
@@ -189,7 +234,8 @@ class Segment:
         self.num_docs = num_docs
         self.nd_pad = next_pow2(max(num_docs, 1))
         self.doc_ids = doc_ids
-        self.sources = sources
+        self.sources = (sources if isinstance(sources, StoredSources)
+                        else StoredSources(sources))
         self.routings = routings
         # legacy _parent metadata value per doc (None = no parent) —
         # persisted with the segment like routings (ParentFieldMapper)
@@ -859,7 +905,7 @@ class SegmentBuilder:
         # doc permutation at seal() (IndexSortConfig.java semantics)
         self.index_sort = index_sort
         self.doc_ids: List[str] = []
-        self.sources: List[dict] = []
+        self.sources = StoredSources()
         self.routings: List[Optional[str]] = []
         self.parents: List[Optional[str]] = []
         self.seqnos: List[int] = []
@@ -873,8 +919,9 @@ class SegmentBuilder:
         self.numeric_values: Dict[str, List[Tuple[int, float]]] = {}
         self.string_values: Dict[str, List[Tuple[int, str]]] = {}
         self.geo_values: Dict[str, List[Tuple[int, float, float]]] = {}
-        # dense_vector field -> {doc: [dims] float list} (+ dims per field)
-        self.vector_values: Dict[str, Dict[int, list]] = {}
+        # dense_vector field -> {doc: float32 [dims] row} (+ dims per
+        # field): rows as the mapper parsed them, stacked once at seal
+        self.vector_values: Dict[str, Dict[int, np.ndarray]] = {}
         self.vector_dims: Dict[str, int] = {}
         # geo_shape field -> {doc: [raw GeoJSON/WKT values]}
         self.shape_values: Dict[str, Dict[int, list]] = {}
@@ -983,7 +1030,7 @@ class SegmentBuilder:
             return [lst[p] for p in perm]
 
         self.doc_ids = reorder(self.doc_ids)
-        self.sources = reorder(self.sources)
+        self.sources = self.sources.reordered(perm)
         self.routings = reorder(self.routings)
         self.parents = reorder(self.parents)
         self.seqnos = reorder(self.seqnos)
@@ -1164,9 +1211,9 @@ class SegmentBuilder:
                 dims = self.vector_dims[f]
                 vecs = np.zeros((nd_pad, dims), np.float32)
                 exists = np.zeros(nd_pad, dtype=bool)
-                for doc, vec in per_doc.items():
-                    vecs[doc] = vec
-                    exists[doc] = True
+                docs = np.fromiter(per_doc, np.int64, len(per_doc))
+                vecs[docs] = np.stack(list(per_doc.values()))
+                exists[docs] = True
                 # round to the bf16 grid ONCE at seal: the host mirror,
                 # the numpy oracle and the device bf16 staging all see
                 # the same values (docs/VECTOR.md storage contract)
@@ -1201,7 +1248,7 @@ class SegmentBuilder:
             name=self.name,
             num_docs=nd,
             doc_ids=list(self.doc_ids),
-            sources=list(self.sources),
+            sources=self.sources,
             routings=list(self.routings),
             seqnos=np.asarray(self.seqnos, dtype=np.int64),
             versions=np.asarray(self.versions, dtype=np.int64),

@@ -33,6 +33,7 @@ import numpy as np
 
 from elasticsearch_tpu.common.errors import ElasticsearchTpuException
 from elasticsearch_tpu.index.segment import (
+    StoredSources,
     GeoColumn,
     NestedContext,
     NumericColumn,
@@ -282,8 +283,8 @@ class Store:
         with open(os.path.join(d, "meta.json"), "w", encoding="utf-8") as f:
             json.dump(meta, f)
         with open(os.path.join(d, "sources.jsonl"), "w", encoding="utf-8") as f:
-            for src in seg.sources:
-                f.write(json.dumps(src, separators=(",", ":")) + "\n")
+            for text in seg.sources.texts():
+                f.write(text + "\n")
         # positions sidecar (phrase queries): term_id -> {doc: [pos...]}
         with open(os.path.join(d, "positions.json"), "w", encoding="utf-8") as f:
             json.dump(
@@ -388,11 +389,10 @@ class Store:
         with open(os.path.join(d, "meta.json"), encoding="utf-8") as f:
             meta = json.load(f)
         data = np.load(os.path.join(d, "arrays.npz"))
-        sources = []
         with open(os.path.join(d, "sources.jsonl"), encoding="utf-8") as f:
-            for line in f:
-                if line.strip():
-                    sources.append(json.loads(line))
+            # (a line a document, parsed when it is first read)
+            sources = StoredSources(
+                text for text in f.read().split("\n") if text.strip())
         with open(os.path.join(d, "positions.json"), encoding="utf-8") as f:
             pos_raw = json.load(f)
         positions = {

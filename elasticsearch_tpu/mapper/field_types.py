@@ -20,6 +20,8 @@ import ipaddress
 import math
 from typing import Any, List, Optional
 
+import numpy as np
+
 from elasticsearch_tpu.common.errors import (
     IllegalArgumentException,
     MapperParsingException,
@@ -699,7 +701,11 @@ class DenseVectorFieldType(FieldType):
     indexable = False
     has_doc_values = False
 
-    SIMILARITIES = ("cosine", "dot_product")
+    SIMILARITIES = ("cosine", "dot_product", "max_inner_product")
+    # index_options.type: this system scans every vector (exact top-k),
+    # which is what Elasticsearch answers under ``flat``; a graph or a
+    # quantised index would be another answer, not a faster one
+    INDEX_TYPES = ("flat",)
 
     def __init__(self, name, params=None):
         super().__init__(name, params)
@@ -724,10 +730,20 @@ class DenseVectorFieldType(FieldType):
                 f"Field [{name}]: unknown [similarity] "
                 f"[{self.similarity}]; expected one of "
                 f"{list(self.SIMILARITIES)}")
+        options = self.params.get("index_options")
+        if options is not None and (
+                not isinstance(options, dict)
+                or options.get("type") not in self.INDEX_TYPES):
+            raise MapperParsingException(
+                f"Field [{name}]: unsupported [index_options] "
+                f"[{options!r}]: this system scans every vector and "
+                f"answers the exact top-k, so the only [type] is "
+                f"{list(self.INDEX_TYPES)} (no hnsw, no quantised type)")
 
-    def parse_vector(self, value) -> List[float]:
-        """Validate one document's vector: a list of exactly ``dims``
-        finite numbers. Anything else is a 400 at index time."""
+    def parse_vector(self, value) -> np.ndarray:
+        """Validate one vector (a document's, or a query's): a list of
+        exactly ``dims`` finite numbers, returned as ONE float32 row.
+        Anything else is a 400 at index time."""
         if not isinstance(value, (list, tuple)):
             raise MapperParsingException(
                 f"failed to parse field [{self.name}] of type "
@@ -738,19 +754,23 @@ class DenseVectorFieldType(FieldType):
                 f"failed to parse field [{self.name}]: the [dims] of the "
                 f"vector [{len(value)}] does not match the mapping "
                 f"[{self.dims}]")
-        out = []
-        for v in value:
-            if isinstance(v, bool) or not isinstance(v, (int, float)):
-                raise MapperParsingException(
-                    f"failed to parse field [{self.name}] of type "
-                    f"[dense_vector]: non-numeric element [{v!r}]")
-            f = float(v)
-            if math.isnan(f) or math.isinf(f):
-                raise MapperParsingException(
-                    f"failed to parse field [{self.name}]: non-finite "
-                    f"vector element")
-            out.append(f)
-        return out
+        # (types first: numpy would take a bool or a numeric string for
+        # a number)
+        if not set(map(type, value)) <= {int, float}:
+            bad = next(v for v in value if type(v) not in (int, float))
+            raise MapperParsingException(
+                f"failed to parse field [{self.name}] of type "
+                f"[dense_vector]: non-numeric element [{bad!r}]")
+        try:
+            with np.errstate(over="ignore"):  # beyond float32: inf
+                row = np.asarray(value, np.float32)
+        except OverflowError:  # an int beyond float64
+            row = np.full(self.dims, np.inf, np.float32)
+        if not np.isfinite(row).all():
+            raise MapperParsingException(
+                f"failed to parse field [{self.name}]: non-finite "
+                f"vector element")
+        return row
 
     def index_terms(self, value, analyzers):
         return []

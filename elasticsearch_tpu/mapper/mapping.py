@@ -49,10 +49,10 @@ class ParsedDocument:
     shape_values: Dict[str, List[Any]] = field(default_factory=dict)
     # range fields: field -> list[(lo, hi)] inclusive float bounds
     range_values: Dict[str, List[Tuple[float, float]]] = field(default_factory=dict)
-    # dense vectors: field -> ONE [dims] float list per doc (the mapper
+    # dense vectors: field -> ONE float32 [dims] row per doc (the mapper
     # rejects multiple vectors per field per document, like the
     # reference's DenseVectorFieldMapper)
-    vector_values: Dict[str, List[float]] = field(default_factory=dict)
+    vector_values: Dict[str, Any] = field(default_factory=dict)
     # fields present (for exists query — the reference's _field_names field)
     field_names: List[str] = field(default_factory=list)
     # dynamic mapping update produced while parsing, or None

@@ -567,7 +567,10 @@ class Node:
         if wait_for_active_shards is not None:
             self._check_active_shards(svc, wait_for_active_shards)
         if pipeline:
-            source = self.ingest.run_pipeline(pipeline, source, doc_id, index)
+            # (a plain dict: what a pipeline makes of a source is no
+            # longer the text it was sent as)
+            source = self.ingest.run_pipeline(pipeline, dict(source),
+                                              doc_id, index)
             if source is None:  # dropped by pipeline
                 return {"_index": index, "_id": doc_id, "result": "noop"}
         if doc_id is None:

@@ -24,12 +24,14 @@ the serving machinery (micro-batching, plane ladder, quarantine):
   ONE corpus stream serves all Q queries of the batch — exactly the
   cross-query amortization the MicroBatcher exists for (``q_batch`` is
   the same static dim the BM25 kernel carries).
-- Metrics: ``dot_product`` scores the raw inner product;``cosine``
-  multiplies by a staged per-doc inverse-norm column (the query side is
-  normalized host-side), so one kernel body serves both — the metric is
-  a scale column, not a code path. Both are mapped through the
-  reference's affine rescale ``(1 + sim) / 2`` so scores stay
-  positive-ish and orderings match the ES convention.
+- Metrics: ``dot_product`` and ``max_inner_product`` score the raw
+  inner product; ``cosine`` multiplies by a staged per-doc inverse-norm
+  column (the query side is normalized host-side), so one kernel body
+  serves all three — the metric is a scale column, not a code path. The
+  kernel ranks by that similarity as it is; Elasticsearch's score of it
+  (``hit_score``: ``(1 + sim) / 2``, or for ``max_inner_product``
+  ``sim < 0 ? 1 / (1 - sim) : sim + 1``) is monotone, so it is applied
+  to the k winners and never inside the kernel.
 - The per-tile top-k is fused: each tile emits its local top-K (scores,
   doc ids) per query via the same masked-select loop the BM25 kernel
   uses; the [n_tiles * K] candidate pools merge with one tiny
@@ -41,9 +43,12 @@ the serving machinery (micro-batching, plane ladder, quarantine):
 - The matmul runs ``Precision.HIGHEST``: the recall@10 == 1.0 gate vs
   the exact f32 numpy oracle is the bench's acceptance bar, and the
   default single-pass bf16 MXU rounding (~2^-8 relative) can reorder
-  near-tied neighbors. bf16 already halved the HBM traffic the kernel
-  is actually bound on; 6 extra MXU passes on a d=128 contraction are
-  noise next to the stream.
+  near-tied neighbors. Whether the multi-pass product is noise next to
+  the stream at 768 wide is what ``knn_roofline.knn`` reads on the chip
+  (PERF.md 5).
+- A slot of a flat ``[n_slots * nd_pad, d_pad]`` staging is read in
+  place: ``row_base`` offsets the block index maps, so no slot is ever
+  sliced out (a slice is a copy of ~100 MB a slot a query at 768 wide).
 
 All shapes are static and bucketed (d padded to a lane multiple, Q and K
 padded to powers of two by the callers) so compiled programs cache
@@ -78,7 +83,7 @@ KNN_TILE_F32_BUDGET = 8 * 1024 * 1024
 
 VALID_KNN_SUBS = (8, 16, 32, 64, 128)
 
-METRICS = ("cosine", "dot_product")
+METRICS = ("cosine", "dot_product", "max_inner_product")
 
 
 def pad_dims(dims: int) -> int:
@@ -122,7 +127,8 @@ def bf16_round(vectors: np.ndarray) -> np.ndarray:
 def vector_scale_column(vectors_f32: np.ndarray, metric: str) -> np.ndarray:
     """Per-doc score scale [nd_pad, 1] f32: 1/|x| for cosine (docs with
     zero norm scale to 0 → score 0.5, ranked by nothing), all-ones for
-    dot_product. ``vectors_f32``: the bf16-rounded host mirror."""
+    the inner-product metrics. ``vectors_f32``: the bf16-rounded host
+    mirror."""
     if metric == "cosine":
         norms = np.linalg.norm(vectors_f32.astype(np.float32), axis=1)
         with np.errstate(divide="ignore"):
@@ -144,6 +150,20 @@ def normalize_query(qvec: np.ndarray, metric: str,
         if n > 0.0:
             q[: v.shape[0]] = v / n
     return q
+
+
+def hit_score(sim, metric: str, xp=np):
+    """Elasticsearch's ``_score`` of a similarity (float32 in, float32
+    out; ``xp`` is numpy or jax.numpy): ``(1 + sim) / 2`` for ``cosine``
+    and ``dot_product``; for ``max_inner_product``, whose similarity is
+    unbounded, ``1 / (1 - sim)`` below zero and ``sim + 1`` from it.
+    Monotone, so every rung ranks by ``sim`` and scores the winners;
+    ``-inf`` (an unfilled rank) stays ``-inf``."""
+    if metric == "max_inner_product":
+        score = xp.where(sim < 0, 1 / (1 - xp.minimum(sim, 0)), sim + 1)
+    else:
+        score = sim * 0.5 + 0.5
+    return xp.where(sim == -np.inf, sim, score)
 
 
 # ----------------------------------------------------------------------
@@ -176,9 +196,10 @@ def _make_knn_kernel(sub: int, d_pad: int, k: int, q_batch: int):
             q_ref[...], emb, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
             precision=lax.Precision.HIGHEST)
-        # metric scale row (cosine: 1/|x|; dot: ones) + the reference
-        # affine rescale (1 + sim) / 2 — [1, W] broadcasts over Q
-        s = s * scale_ref[...] * jnp.float32(0.5) + jnp.float32(0.5)
+        # metric scale row (cosine: 1/|x|; inner products: ones);
+        # [1, W] broadcasts over Q. The similarity ranks as it is:
+        # hit_score is applied to the winners, outside
+        s = s * scale_ref[...]
         live = mask_ref[...] > jnp.float32(0.0)  # [1, W]
         ninf = jnp.float32(NEG_INF)
         masked = jnp.where(live, s, ninf)  # [Q, W]
@@ -201,12 +222,13 @@ def _make_knn_kernel(sub: int, d_pad: int, k: int, q_batch: int):
 
 
 @functools.partial(
-    jax.jit, static_argnames=("sub", "k", "q_batch", "interpret"))
+    jax.jit, static_argnames=("sub", "k", "q_batch", "interpret",
+                              "row_base", "n_rows"))
 def knn_score_tiles(
-    emb,  # [nd_pad, d_pad] bf16 embedding matrix (rows beyond the real
+    emb,  # [rows, d_pad] bf16 embedding matrix (rows beyond the real
     # docs are zero; the mask column kills them anyway)
-    scale,  # [nd_pad, 1] f32 per-doc metric scale (vector_scale_column)
-    mask,  # [nd_pad, 1] f32: 1.0 = live AND has a vector
+    scale,  # [rows, 1] f32 per-doc metric scale (vector_scale_column)
+    mask,  # [rows, 1] f32: 1.0 = live AND has a vector
     qvecs,  # [q_batch, d_pad] f32 query batch (normalize_query rows;
     # padding members are all-zero and their outputs are discarded)
     *,
@@ -214,19 +236,26 @@ def knn_score_tiles(
     k: int = 10,
     q_batch: int = 1,
     interpret: bool = False,
+    row_base: int = 0,  # first row of the doc space to score, and
+    n_rows: Optional[int] = None,  # its rows: ONE slot of a table that
+    # holds several, read in place (both multiples of the tile)
 ):
     """Run the MXU kNN kernel over a staged embedding matrix.
 
-    Returns (tile_scores [n_tiles, q_batch, k] f32, tile_docs
+    Returns (tile_sims [n_tiles, q_batch, k] f32, tile_docs
     [n_tiles, q_batch, k] i32, -1 = empty) — per-tile fused top-k
-    candidates, merged per query by ``merge_knn_topk``. The match TOTAL
-    (live docs carrying a vector) is metric- and query-independent, so
-    callers count it from the mask column instead of a kernel output.
+    candidates by similarity (``hit_score`` is the caller's, on the
+    winners), merged per query by ``merge_knn_topk``; doc ids count
+    from ``row_base``. The match TOTAL (live docs carrying a vector) is
+    metric- and query-independent, so callers count it from the mask
+    column instead of a kernel output.
     """
-    nd_pad, d_pad = emb.shape
+    rows, d_pad = emb.shape
+    nd_pad = rows - row_base if n_rows is None else n_rows
     w = sub * LANE
-    if nd_pad % w:
-        raise ValueError(f"nd_pad={nd_pad} not a multiple of tile {w}")
+    if nd_pad % w or row_base % w:
+        raise ValueError(f"rows [{row_base}, +{nd_pad}) are not whole "
+                         f"tiles of {w}")
     n_tiles = nd_pad // w
     k = min(int(k), w)
     q_batch = max(1, int(q_batch))
@@ -237,10 +266,13 @@ def knn_score_tiles(
     def zero():
         return jnp.int32(0)
 
+    def tile(t):  # the slot's tile t in the table's own count
+        return t + jnp.int32(row_base // w)
+
     in_specs = [
-        pl.BlockSpec((w, d_pad), lambda t: (t, zero())),
-        pl.BlockSpec((1, w), lambda t: (zero(), t)),
-        pl.BlockSpec((1, w), lambda t: (zero(), t)),
+        pl.BlockSpec((w, d_pad), lambda t: (tile(t), zero())),
+        pl.BlockSpec((1, w), lambda t: (zero(), tile(t))),
+        pl.BlockSpec((1, w), lambda t: (zero(), tile(t))),
         pl.BlockSpec((q_batch, d_pad), lambda t: (zero(), zero())),
     ]
     out_specs = [
@@ -263,7 +295,7 @@ def knn_score_tiles(
         # stated, not inherited from a Python function: the device
         # trace's readers find the custom call by this name
         name="knn_tiles",
-    )(emb, scale.reshape(1, nd_pad), mask.reshape(1, nd_pad), qvecs)
+    )(emb, scale.reshape(1, rows), mask.reshape(1, rows), qvecs)
 
 
 @functools.partial(jax.jit, static_argnames=("k",))
@@ -290,15 +322,16 @@ def reference_knn_scores(vectors_f32: np.ndarray, qvec: np.ndarray,
                          scale: Optional[np.ndarray] = None) -> np.ndarray:
     """Exact f32 scores over the bf16-rounded host mirror — the oracle
     the kernel (and the host plan node) must match. ``qvec`` is the RAW
-    user vector; normalization/affine happen here exactly as staged."""
+    user vector; normalization and ``hit_score`` happen here exactly as
+    staged."""
     qvec = np.asarray(qvec, np.float32)
     q = normalize_query(qvec, metric, max(vectors_f32.shape[1],
                                           qvec.shape[0]))
     s = vectors_f32.astype(np.float32) @ q[: vectors_f32.shape[1]]
     if scale is None:
         scale = vector_scale_column(vectors_f32, metric)
-    return (s * scale[:, 0] * np.float32(0.5)
-            + np.float32(0.5)).astype(np.float32)
+    return hit_score((s * scale[:, 0]).astype(np.float32),
+                     metric).astype(np.float32)
 
 
 def reference_knn_topk(vectors_f32: np.ndarray, mask: np.ndarray,

@@ -432,6 +432,29 @@ def _slot_row_base(table, i: int, spd: int) -> int:
     return i * (table.shape[0] // spd)
 
 
+def _dead_slot(shapes):
+    """What a slot's pass yields (``shapes``: a tuple, the candidates'
+    keys first) for a slot with no live document, without the pass:
+    every key ``-inf`` (the host drops such lanes, so their docs and
+    scores are free) and everything else zero (counts, views)."""
+    keys, *rest = jax.tree_util.tree_map(
+        lambda a: jnp.zeros(a.shape, a.dtype), shapes)
+    return (jnp.full_like(keys, -jnp.inf), *rest)
+
+
+def _live_slots_only(spd: int, live, slot, dead=_dead_slot) -> list:
+    """Each of a device's ``spd`` slots' ``slot(i)``, paid only where
+    ``live(i)`` holds at run time: a slot pays for its pass (kernel,
+    masks, top-k) only if it holds a live document; a headroom slot, or
+    a segment whose documents are all deleted, costs the predicate's
+    reduction and a branch (``dead(shapes)``). The predicate reads what
+    is staged, so a delta append into a free slot is served by the same
+    compiled program; the slot is read inside the branch taken."""
+    shapes = jax.eval_shape(lambda: slot(0))
+    return [jax.lax.cond(live(i), functools.partial(slot, i),
+                         lambda: dead(shapes)) for i in range(spd)]
+
+
 @functools.lru_cache(maxsize=128)
 def _mesh_query_program(mesh: Mesh, holder: _TemplateHolder, k: int,
                         spd: int = 1,
@@ -585,15 +608,11 @@ def _mesh_query_program(mesh: Mesh, holder: _TemplateHolder, k: int,
                 views, agg_parts)
 
     def dead_slot(seg, shapes):
-        """What ``per_slot`` yields (``shapes``) for a slot with no live
-        document, without the pass: every key ``-inf`` (the host drops
-        such lanes, so their docs and scores are free), count 0,
-        all-false views, and each fused-agg partial at its identity:
+        """``_dead_slot`` with each fused-agg partial at its identity:
         what ``emit_agg_partials`` makes of an all-false mask, asked of
         a one-document stand-in for the slot (no partial's shape depends
         on the document count)."""
-        keys, *rest, _agg_parts = jax.tree_util.tree_map(
-            lambda a: jnp.zeros(a.shape, a.dtype), shapes)
+        *rest, _agg_parts = _dead_slot(shapes)
         agg_parts = ()
         if agg_static:
             from elasticsearch_tpu.search.fused_aggs import (
@@ -605,11 +624,10 @@ def _mesh_query_program(mesh: Mesh, holder: _TemplateHolder, k: int,
                        if name not in _KERNEL_TABLES}
             agg_parts = tuple(emit_agg_partials(
                 agg_static, one_doc, jnp.zeros((1,), bool)))
-        return (jnp.full_like(keys, -jnp.inf), *rest, agg_parts)
+        return (*rest, agg_parts)
 
     def per_device(seg, plan_arrays, pf_arrays, rs_arrays, scalars):
         dev = jax.lax.axis_index("shards")
-        slot_out = []
         # (no kernel table staged: the scatter plane, no row to offset)
         k_rows = next((seg[name].shape[0] // spd
                        for name in _KERNEL_TABLES if name in seg), 0)
@@ -622,17 +640,9 @@ def _mesh_query_program(mesh: Mesh, holder: _TemplateHolder, k: int,
                 [a[i] for a in pf_arrays], [a[i] for a in rs_arrays],
                 scalars)
 
-        # A slot pays for its pass (tile kernel, masks, top-k) only if
-        # it holds a live document: a headroom slot, or a segment whose
-        # documents are all deleted, costs this reduction and a branch.
-        # The predicate reads what is staged, so a delta append into a
-        # free slot is served by the same compiled program; the slot is
-        # sliced inside the branch taken.
-        shapes = jax.eval_shape(lambda: slot(0))
-        for i in range(spd):
-            slot_out.append(jax.lax.cond(
-                jnp.any(seg["live1"][i]), functools.partial(slot, i),
-                lambda: dead_slot(seg, shapes)))
+        slot_out = _live_slots_only(
+            spd, lambda i: jnp.any(seg["live1"][i]), slot,
+            functools.partial(dead_slot, seg))
         kk = slot_out[0][0].shape[0]
         cand_keys = jnp.concatenate([o[0] for o in slot_out])
         cand_docs = jnp.concatenate([o[1] for o in slot_out])
@@ -1049,7 +1059,7 @@ def _mesh_batched_pruned_program(mesh: Mesh, spd: int, q_batch: int,
 
 @functools.lru_cache(maxsize=32)
 def _mesh_knn_program(mesh: Mesh, spd: int, q_pad: int, kk: int,
-                      sub: int, d_pad: int, nd_knn: int,
+                      sub: int, d_pad: int, nd_knn: int, metric: str,
                       interpret: bool):
     """One compiled scatter-gather serving Q concurrent kNN queries on
     the MXU (ROADMAP item 4): per slot, ONE ``knn_score_tiles`` launch
@@ -1057,31 +1067,41 @@ def _mesh_knn_program(mesh: Mesh, spd: int, q_pad: int, kk: int,
     and emits per-query per-tile top-k candidates; pools merge locally,
     then over ICI via one all_gather — the same collective shape as
     ``_mesh_batched_kernel_program``, with the posting windows replaced
-    by a dense matmul. The match total (live docs carrying the vector
-    field) is query-independent: it is the psum of the staged mask
-    sums, not a kernel output."""
+    by a dense matmul.
+
+    The embeddings, scale and mask are staged FLAT (``[spd * nd_knn,
+    ...]`` a device, one slot after the other) and the kernel reads a
+    slot in place at its row base, as the tile kernel reads its postings
+    (``_KERNEL_TABLES``); a slot whose mask holds no live vector skips
+    its pass (``_live_slots_only``); the candidates rank by the raw
+    similarity and the winners alone are scored (``hit_score``); each
+    query's answer leaves as one ``_pack_answer`` row, so the batch is
+    ONE array and one transfer. The match total (live docs carrying the
+    vector field) is query-independent: it is the psum of the staged
+    mask sums, not a kernel output."""
     from elasticsearch_tpu.ops import pallas_knn as pkn
 
     def per_device(emb, scale, mask, qv):
         dev = jax.lax.axis_index("shards")
-        cand_s, cand_d, cand_slot = [], [], []
-        count = None
-        for i in range(spd):
+        slot_mask = mask.reshape(spd, nd_knn)  # (a view: no copy)
+
+        def slot(i):
             ts, td = pkn.knn_score_tiles(
-                emb[i], scale[i], mask[i], qv,
-                sub=sub, k=kk, q_batch=q_pad, interpret=interpret)
+                emb, scale, mask, qv, sub=sub, k=kk, q_batch=q_pad,
+                interpret=interpret,
+                row_base=_slot_row_base(emb, i, spd), n_rows=nd_knn)
             s_i, d_i = pkn.merge_knn_topk(ts, td, kk)  # [q_pad, kk']
-            cand_s.append(s_i)
-            cand_d.append(d_i)
-            cand_slot.append(
-                jnp.zeros(s_i.shape, jnp.int32)
-                + (dev.astype(jnp.int32) * jnp.int32(spd) + jnp.int32(i)))
-            c = jnp.sum(mask[i]).astype(jnp.int32)
-            count = c if count is None else count + c
-        cs = jnp.concatenate(cand_s, axis=1)
-        cd = jnp.concatenate(cand_d, axis=1)
-        cslot = jnp.concatenate(cand_slot, axis=1)
-        total = jax.lax.psum(count, "shards")  # scalar, replicated
+            return s_i, d_i, jnp.sum(slot_mask[i]).astype(jnp.int32)
+
+        slot_out = _live_slots_only(
+            spd, lambda i: jnp.any(slot_mask[i] > 0), slot)
+        cs = jnp.concatenate([o[0] for o in slot_out], axis=1)
+        cd = jnp.concatenate([o[1] for o in slot_out], axis=1)
+        cslot = jnp.concatenate([
+            jnp.zeros(o[0].shape, jnp.int32)
+            + (dev.astype(jnp.int32) * jnp.int32(spd) + jnp.int32(i))
+            for i, o in enumerate(slot_out)], axis=1)
+        total = jax.lax.psum(sum(o[2] for o in slot_out), "shards")
         all_s = jax.lax.all_gather(cs, "shards")
         all_d = jax.lax.all_gather(cd, "shards")
         all_slot = jax.lax.all_gather(cslot, "shards")
@@ -1091,21 +1111,26 @@ def _mesh_knn_program(mesh: Mesh, spd: int, q_pad: int, kk: int,
         top_s, top_i = jax.lax.top_k(pool_s, min(kk, pool_s.shape[1]))
         top_d = jnp.take_along_axis(pool_d, top_i, axis=1)
         top_slot = jnp.take_along_axis(pool_slot, top_i, axis=1)
-        totals = jnp.full((q_pad,), total, jnp.int32)
-        return top_s[None], top_d[None], top_slot[None], totals[None]
+        scores = pkn.hit_score(top_s, metric, jnp)
+        total = total.astype(jnp.int64)
+        # (the sixth row carries a field sort's raw values in the serial
+        # program; here it repeats the scores)
+        packed = jax.vmap(lambda keys, slots, docs, sc: _pack_answer(
+            keys, slots, docs, total, sc, sc))(
+                top_s, top_slot, top_d, scores)
+        return packed[None]
 
     mapped = shard_map(
         per_device, mesh=mesh,
         in_specs=(PS("shards"), PS("shards"), PS("shards"), PS()),
-        out_specs=(PS("shards"),) * 4,
+        out_specs=PS("shards"),
         check_vma=False,
     )
 
     @jax.jit
     def run(*args):
         with jax.named_scope("mesh_knn"):
-            outs = mapped(*args)
-        return tuple(o[0] for o in outs)  # replicated: row 0 == row i
+            return mapped(*args)[0]  # replicated: row 0 == row i
 
     from elasticsearch_tpu.common.compile_cache import (
         instrument_program,
@@ -1115,7 +1140,7 @@ def _mesh_knn_program(mesh: Mesh, spd: int, q_pad: int, kk: int,
     return instrument_program(
         run, "knn",
         variant_key("knn", len(mesh.devices), spd, q_pad, kk, sub,
-                    d_pad, nd_knn, interpret))
+                    d_pad, nd_knn, metric, interpret))
 
 
 def clear_compiled_programs() -> None:
@@ -1174,6 +1199,12 @@ class IndexMeshSearch:
         # dense-vector retrieval on the MXU (docs/VECTOR.md): queries
         # whose kNN side ran the mesh kNN program
         self.knn_query_total = 0
+        # slots whose kNN pass ran, a query each (one that holds a live
+        # vector; the program skips the others): over knn_query_total,
+        # the slots a query paid for
+        self.knn_slots_scanned_total = 0
+        # and the bf16 embedding bytes those passes streamed, a launch
+        self.embedding_bytes_streamed_total = 0
         # fused on-device aggregations (ISSUE 13, docs/AGGS.md):
         # queries whose whole agg set reduced inside the mesh program,
         # vs agg'd mesh queries that fell back to the host reduce over
@@ -1814,6 +1845,7 @@ class IndexMeshSearch:
 
     def _query_knn_batch_admitted(self, specs, ks, deadline, stats,
                                   tracers) -> Optional[list]:
+        from elasticsearch_tpu.common.errors import MapperParsingException
         from elasticsearch_tpu.index.segment import next_pow2
         from elasticsearch_tpu.mapper.field_types import DenseVectorFieldType
         from elasticsearch_tpu.ops import pallas_knn as pkn
@@ -1851,55 +1883,53 @@ class IndexMeshSearch:
             ft = self.svc.mapper_service.field_type(field)
             if not isinstance(ft, DenseVectorFieldType):
                 return None
-            for spec in specs:
-                qv = spec["query_vector"]
-                if (not isinstance(qv, (list, tuple))
-                        or len(qv) != ft.dims
-                        or any(isinstance(v, bool)
-                               or not isinstance(v, (int, float))
-                               or not np.isfinite(v) for v in qv)):
-                    # incl. NaN/inf: the serial path owns the 400 (a
-                    # NaN would poison scores and drive the kernel's
-                    # tie-select past the doc range)
-                    return None
-        except (KeyError, TypeError):
+            # incl. NaN/inf: the serial path owns the 400 (a NaN would
+            # poison scores and drive the kernel's tie-select past the
+            # doc range)
+            qvecs = [ft.parse_vector(spec["query_vector"])
+                     for spec in specs]
+        except (KeyError, TypeError, MapperParsingException):
             return None
         if deadline is not None:
             deadline.checkpoint()
-        t_stage = bt.start("staging")
-        if not self._ensure_staged():
-            self._note("host", self.staging_denied_reason
-                       or "knn_staging_unavailable", len(specs))
-            return None
-        executor = self._executor
-        if executor is None:
-            self._note("host", "knn_staging_unavailable", len(specs))
-            return None
-        session = executor.ensure_knn(field, ft.dims, ft.similarity)
-        if session is None:
-            reason = executor.kernel_denied_reason
-            self._note("host", reason or "knn_staging_unavailable",
-                       len(specs))
-            if reason == "staging_fault":
-                # a terminal classified staging fault: bench the plane
-                # so peers don't re-pay the staging attempt per query
-                # (the post-cooldown probe restages — docs/RESILIENCE.md)
-                self.plane_health.record_failure("mesh_pallas",
-                                                 reason="staging_fault")
-            return None
-        q_batch = len(specs)
-        q_pad = next_pow2(q_batch)
-        kk = next_pow2(max(max(ks), 1))
-        d_pad = session["d_pad"]
-        nd_knn = session["nd_pad"]
-        g = psc.tile_geometry(nd_knn,
-                              pkn.knn_tile_sub(nd_knn, d_pad, sub_pref))
-        qmat = np.zeros((q_pad, d_pad), np.float32)
-        for q, spec in enumerate(specs):
-            qmat[q] = pkn.normalize_query(
-                np.asarray(spec["query_vector"], np.float32),
-                ft.similarity, d_pad)
-        bt.stop("staging", t_stage)
+        # (``staging.knn_embeddings`` opens under this parent only on
+        # the request that stages the field's embeddings)
+        t_stage = bt.start_parent("staging")
+        try:
+            if not self._ensure_staged():
+                self._note("host", self.staging_denied_reason
+                           or "knn_staging_unavailable", len(specs))
+                return None
+            executor = self._executor
+            if executor is None:
+                self._note("host", "knn_staging_unavailable", len(specs))
+                return None
+            session = executor.ensure_knn(field, ft.dims, ft.similarity,
+                                          tracer=bt)
+            if session is None:
+                reason = executor.kernel_denied_reason
+                self._note("host", reason or "knn_staging_unavailable",
+                           len(specs))
+                if reason == "staging_fault":
+                    # a terminal classified staging fault: bench the
+                    # plane so peers don't re-pay the staging attempt per
+                    # query (the post-cooldown probe restages —
+                    # docs/RESILIENCE.md)
+                    self.plane_health.record_failure(
+                        "mesh_pallas", reason="staging_fault")
+                return None
+            q_batch = len(specs)
+            q_pad = next_pow2(q_batch)
+            kk = next_pow2(max(max(ks), 1))
+            d_pad = session["d_pad"]
+            nd_knn = session["nd_pad"]
+            g = psc.tile_geometry(
+                nd_knn, pkn.knn_tile_sub(nd_knn, d_pad, sub_pref))
+            qmat = np.zeros((q_pad, d_pad), np.float32)
+            for q, qvec in enumerate(qvecs):
+                qmat[q] = pkn.normalize_query(qvec, ft.similarity, d_pad)
+        finally:
+            bt.stop("staging", t_stage)
         from elasticsearch_tpu.common.errors import TaskCancelledException
         from elasticsearch_tpu.search.cancellation import (
             TimeExceededException,
@@ -1909,7 +1939,7 @@ class IndexMeshSearch:
             on_plane_execute(self.svc.name, "mesh_pallas")
             run = _mesh_knn_program(
                 executor.mesh, executor.slots_per_dev,
-                q_pad, kk, g.tile_sub, d_pad, nd_knn,
+                q_pad, kk, g.tile_sub, d_pad, nd_knn, ft.similarity,
                 session["mode"] == "interpret")
             args = (session["emb"], session["scale"], session["mask"],
                     jnp.asarray(qmat))
@@ -1919,7 +1949,6 @@ class IndexMeshSearch:
                 deadline.checkpoint()
             on_kernel_launch(self.svc.name, "knn")
             outs = _launch_locked(bt, run, *args)
-            keys, docs, slots, totals = _fetch(bt, outs, self._telemetry)
         except (PlanStructureMismatch, NotImplementedError):
             self._note("mesh_pallas", "shape_mismatch", q_batch)
             return None  # shape ineligibility: next rung, no penalty
@@ -1946,29 +1975,40 @@ class IndexMeshSearch:
         self._note("mesh_pallas",
                    "knn_served_batched" if q_batch > 1 else "knn_served",
                    q_batch)
-        # the whole batch streams each slot's bf16 embedding matrix once
+        # the whole batch streams the bf16 embedding matrix of each
+        # slot whose pass ran (one that holds a live vector) once
+        scanned = session["slots_scanned"]
         launch_adds = {"embedding_bytes_streamed":
-                       executor.n_slots * nd_knn * d_pad * 2}
-        t_merge = bt.start("merge")
+                       scanned * nd_knn * d_pad * 2}
+        with self._counter_lock:
+            self.knn_slots_scanned_total += scanned * q_batch
+            self.embedding_bytes_streamed_total += \
+                launch_adds["embedding_bytes_streamed"]
+        # (no finally: an exception in here ends the request)
+        t_merge = bt.start_parent("merge")
+        # (the batch's answers are ONE array, a row a query: one transfer)
+        packed = _fetch(bt, outs, self._telemetry)
+        t_assemble = bt.start("merge.assemble")
         results = []
         for q in range(q_batch):
             for sid in self.svc.shards:
                 self.svc.shards[sid].searcher.note_query(
                     stats[q] if stats is not None else None)
+            keys, slots, docs, total, scores, _ = _unpack_answer(packed[q])
             refs = []
             max_score = None
-            for key, slot, d in zip(keys[q][: ks[q]], slots[q][: ks[q]],
-                                    docs[q][: ks[q]]):
-                if key == -np.inf or d < 0:
+            for i in range(min(ks[q], len(keys))):
+                if keys[i] == -np.inf:
                     continue
-                sid, seg = executor.pairs[int(slot)]
-                score = float(key)
-                refs.append(DocRef(sid, seg.name, int(d), score, ()))
+                sid, seg = executor.pairs[int(slots[i])]
+                score = float(scores[i])
+                refs.append(DocRef(sid, seg.name, int(docs[i]), score, ()))
                 if max_score is None:
                     max_score = score
-            results.append({"total": int(totals[q]), "refs": refs,
+            results.append({"total": int(total), "refs": refs,
                             "max_score": max_score,
                             "plane": "mesh_pallas"})
+        bt.stop("merge.assemble", t_assemble)
         bt.stop("merge", t_merge)
         tel = self._telemetry
         if tel is not None:
@@ -3023,6 +3063,19 @@ class IndexMeshSearch:
         return results
 
 
+def _knn_live(seg, col) -> np.ndarray:
+    """A segment's kNN mask rows: live AND carries the vector, f32."""
+    return (col.exists & seg.live[: col.vectors.shape[0]]).astype(
+        np.float32)
+
+
+def _knn_slot_rows(table, n_slots: int):
+    """A flat kNN table as ``[n_slots, rows a slot, width]``, to set
+    whole slots of it by index (delta append, tombstones)."""
+    return table.reshape(n_slots, table.shape[0] // n_slots,
+                         table.shape[1])
+
+
 class MeshPlanExecutor:
     """Stage N sealed segments onto a device mesh once; run any query
     plan as one compiled multi-device program.
@@ -3465,32 +3518,32 @@ class MeshPlanExecutor:
                 col = seg.vector_columns.get(field)
                 if col is None:
                     continue  # slot stays dead
-                emb_rows[j, : col.vectors.shape[0], : dims] = \
-                    col.vectors.astype(ml_dtypes.bfloat16)
-                sc = pkn.vector_scale_column(col.vectors,
-                                             entry["metric"])
-                sc_rows[j, : sc.shape[0]] = sc
+                emb_rows[j, : col.vectors.shape[0], : dims] = col.vectors
+                sc_rows[j, : col.vectors.shape[0]] = \
+                    pkn.vector_scale_column(col.vectors, entry["metric"])
             mk_rows = np.zeros((len(live_slots), nd_knn, 1), np.float32)
             for j, slot in enumerate(live_slots):
                 seg = self.segments[slot]
                 col = seg.vector_columns.get(field)
-                if col is None:
-                    continue
-                m = (col.exists
-                     & seg.live[: col.vectors.shape[0]]).astype(
-                         np.float32)
-                mk_rows[j, : m.shape[0], 0] = m
+                if col is not None:
+                    mk_rows[j, : col.vectors.shape[0], 0] = \
+                        _knn_live(seg, col)
+
+            def with_slots(table, idx, rows):
+                return jax.device_put(
+                    _knn_slot_rows(table, self.n_slots).at[idx].set(
+                        jnp.asarray(rows)).reshape(table.shape),
+                    self._sharding)
+
+            slot_live = entry["slot_live"].copy()
+            slot_live[live_slots] = mk_rows.any(axis=(1, 2))
             knn_new[field] = dict(
                 entry,
-                emb=jax.device_put(
-                    entry["emb"].at[idx_new].set(jnp.asarray(emb_rows)),
-                    self._sharding),
-                scale=jax.device_put(
-                    entry["scale"].at[idx_new].set(
-                        jnp.asarray(sc_rows)), self._sharding),
-                mask=jax.device_put(
-                    entry["mask"].at[idx_live].set(
-                        jnp.asarray(mk_rows)), self._sharding))
+                emb=with_slots(entry["emb"], idx_new, emb_rows),
+                scale=with_slots(entry["scale"], idx_new, sc_rows),
+                mask=with_slots(entry["mask"], idx_live, mk_rows),
+                slot_live=slot_live,
+                slots_scanned=int(slot_live.sum()))
             knn_amp[field] = (int(emb_rows.nbytes), int(sc_rows.nbytes),
                               int(mk_rows.nbytes))
 
@@ -3611,15 +3664,19 @@ class MeshPlanExecutor:
                 for j, slot in enumerate(slots):
                     seg = self.segments[slot]
                     col = seg.vector_columns.get(field)
-                    if col is None:
-                        continue
-                    m = (col.exists
-                         & seg.live[: col.vectors.shape[0]]).astype(
-                             np.float32)
-                    mk[j, : m.shape[0], 0] = m
-                knn_updates[field] = dict(entry, mask=jax.device_put(
-                    entry["mask"].at[idx].set(jnp.asarray(mk)),
-                    self._sharding))
+                    if col is not None:
+                        mk[j, : col.vectors.shape[0], 0] = \
+                            _knn_live(seg, col)
+                slot_live = entry["slot_live"].copy()
+                slot_live[slots] = mk.any(axis=(1, 2))
+                knn_updates[field] = dict(
+                    entry,
+                    mask=jax.device_put(
+                        _knn_slot_rows(entry["mask"], self.n_slots)
+                        .at[idx].set(jnp.asarray(mk))
+                        .reshape(entry["mask"].shape), self._sharding),
+                    slot_live=slot_live,
+                    slots_scanned=int(slot_live.sum()))
                 knn_amp[field] = int(mk.nbytes)
             restaged = sum(amp.values()) + sum(knn_amp.values())
             # commit: publish every replacement, then re-register the
@@ -3817,18 +3874,21 @@ class MeshPlanExecutor:
         self._account("bound_tables", "k_bounds", sum(
             int(b.nbytes) for t in meta.values() for b in t))
 
-    def ensure_knn(self, field: str, dims: int,
-                   metric: str) -> Optional[dict]:
+    def ensure_knn(self, field: str, dims: int, metric: str,
+                   tracer=NULL_TRACER) -> Optional[dict]:
         """Stage a dense_vector field's kNN plane over the stacked
-        segment set: per-slot bf16 embedding matrices [n_slots, nd_pad,
-        d_pad], the metric scale columns (cosine inverse norms / ones)
-        and the live∧has-vector mask columns — packed on the SAME
-        collective geometry as the postings staging, so the kNN program
-        reuses the executor's mesh/sharding/slot mapping verbatim.
-        Deletes are honored through the mask: IndexMeshSearch rebuilds
-        the executor (and with it this staging) whenever any segment's
-        live_doc_count changes. Returns the session dict or None when
-        the kernel can't run here."""
+        segment set: the slots' bf16 embedding matrices one after the
+        other along the rows of ONE ``[n_slots * nd_pad, d_pad]`` table
+        (the kernel reads a slot in place at its row base, see
+        ``_KERNEL_TABLES``), the metric scale column (cosine inverse
+        norms / ones) and the live∧has-vector mask column laid out the
+        same way — on the SAME collective geometry as the postings
+        staging, so the kNN program reuses the executor's
+        mesh/sharding/slot mapping verbatim. Staged once a generation
+        (the ``staging.knn_embeddings`` span): a warm request finds the
+        session and stages nothing. Deletes are honored through the
+        mask (``apply_tombstones``). Returns the session dict or None
+        when the kernel can't run here."""
         from elasticsearch_tpu.ops.aggs import _pallas_mode
 
         # reset FIRST — before every early return (same contract as
@@ -3839,40 +3899,50 @@ class MeshPlanExecutor:
         if not mode:
             return None
         entry = self._knn.get(field)
-        if entry is False:
-            return None
         if entry is None:
-            from elasticsearch_tpu.common.staging import run_staged
-
-            with self._kernel_stage_lock:
-                entry = self._knn.get(field)
-                if isinstance(entry, dict):  # racing cold stager built it
-                    return dict(entry, mode=mode)
-                if entry is False:
-                    return None
-                try:
-                    entry = run_staged(
-                        lambda: self._stage_knn_plane(field, dims, metric),
-                        index=self.index_name, kind="embeddings",
-                        plane="mesh")  # retry: process-level config
-                except _KnnStructuralError:
-                    # a REQUEST/mapping-shaped inability (dims mismatch
-                    # across segments): permanent for this segment set,
-                    # never a device fault — plane stays host quietly
-                    self._knn[field] = False
-                    return None
-                except Exception:  # noqa: BLE001 — classified terminal
-                    # staging fault (rollback ran): demote + quarantine;
-                    # the entry stays None so the probe restages
-                    _plane_logger.warning(
-                        "[%s] mesh kNN staging failed for [%s]; plane "
-                        "demotes with reason staging_fault",
-                        self.index_name, field, exc_info=True)
-                    self.kernel_denied_reason = "staging_fault"
-                    return None
-                if entry is None:  # hbm_budget denial inside the attempt
-                    return None
+            # (the span around the lock, not under it: a request that
+            # finds the field unstaged says so, whoever stages it)
+            t = tracer.start("staging.knn_embeddings")
+            try:
+                entry = self._stage_knn_once(field, dims, metric)
+            except Exception:  # noqa: BLE001 — classified terminal
+                # staging fault (rollback ran): demote + quarantine;
+                # the entry stays None so the probe restages
+                _plane_logger.warning(
+                    "[%s] mesh kNN staging failed for [%s]; plane "
+                    "demotes with reason staging_fault",
+                    self.index_name, field, exc_info=True)
+                self.kernel_denied_reason = "staging_fault"
+                return None
+            finally:
+                tracer.stop("staging.knn_embeddings", t)
+        if not isinstance(entry, dict):
+            return None
         return dict(entry, mode=mode)
+
+    def _stage_knn_once(self, field: str, dims: int, metric: str):
+        """``ensure_knn``'s cold path under the staging lock: the
+        session entry, False where the field cannot stage on this
+        segment set, None where the budget denied the attempt (the next
+        request asks again); a terminal staging fault is the
+        caller's."""
+        from elasticsearch_tpu.common.staging import run_staged
+
+        with self._kernel_stage_lock:
+            entry = self._knn.get(field)
+            if entry is not None:  # a racing cold stager decided
+                return entry
+            try:
+                return run_staged(
+                    lambda: self._stage_knn_plane(field, dims, metric),
+                    index=self.index_name, kind="embeddings",
+                    plane="mesh")  # retry: process-level config
+            except _KnnStructuralError:
+                # a REQUEST/mapping-shaped inability (dims mismatch
+                # across segments): permanent for this segment set,
+                # never a device fault — plane stays host quietly
+                self._knn[field] = False
+                return False
 
     def _stage_knn_plane(self, field: str, dims: int,
                          metric: str) -> Optional[dict]:
@@ -3911,28 +3981,30 @@ class MeshPlanExecutor:
                     f"dims={col.dims}, mapping says {dims}")
             # the host mirror is already on the bf16 grid: the
             # astype below is exact
-            emb[i, : col.vectors.shape[0], : dims] = \
-                col.vectors.astype(ml_dtypes.bfloat16)
-            sc = pkn.vector_scale_column(col.vectors, metric)
-            live = seg.live[: col.vectors.shape[0]]
-            m = (col.exists & live).astype(np.float32)
-            scale[i, : sc.shape[0]] = sc
-            mask[i, : m.shape[0], 0] = m
+            emb[i, : col.vectors.shape[0], : dims] = col.vectors
+            scale[i, : col.vectors.shape[0]] = pkn.vector_scale_column(
+                col.vectors, metric)
+            mask[i, : col.vectors.shape[0], 0] = _knn_live(seg, col)
         on_device_staging(self.index_name, "embeddings", f"knn:{field}")
         # all three device transfers must land before anything
         # publishes: a fault between them leaves only unreferenced
         # arrays for the GC (nothing in _seg_staged / the ledger)
         entry = {
-            "emb": jax.device_put(emb, self._sharding),
-            "scale": jax.device_put(scale, self._sharding),
-            "mask": jax.device_put(mask, self._sharding),
+            # flat: slot after slot along the rows, read in place
+            "emb": jax.device_put(emb.reshape(-1, d_pad), self._sharding),
+            "scale": jax.device_put(scale.reshape(-1, 1), self._sharding),
+            "mask": jax.device_put(mask.reshape(-1, 1), self._sharding),
             "d_pad": d_pad,
             "nd_pad": nd_knn,
             "metric": metric,
             # mapping dims: delta_append verifies a new segment's column
             # against it before carrying this plane forward (ISSUE 20)
             "dims": dims,
+            # which slots hold a live vector: those whose pass a query
+            # pays for (the program reads the same mask on the device)
+            "slot_live": mask.any(axis=(1, 2)),
         }
+        entry["slots_scanned"] = int(entry["slot_live"].sum())
         self._knn[field] = entry
         dur = (_time.monotonic() - t0) * 1000.0
         self._account("embeddings", f"knn:{field}",

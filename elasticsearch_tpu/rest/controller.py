@@ -89,12 +89,20 @@ class RestRequest:
             raise ParsingException(f"request body is not valid: {e}") from e
 
     def ndjson_lines(self) -> List[dict]:
+        from elasticsearch_tpu.common.xcontent import JsonTextDict
+
         out = []
         for line in self.raw_body.split(b"\n"):
             line = line.strip()
             if line:
                 try:
-                    out.append(json.loads(line))
+                    parsed = json.loads(line)
+                    if type(parsed) is dict and b"\r" not in line:
+                        # (a document keeps the text it was sent as; a
+                        # carriage return would not survive a text file)
+                        parsed = JsonTextDict(parsed)
+                        parsed.text = line.decode("utf-8")
+                    out.append(parsed)
                 except json.JSONDecodeError as e:
                     raise ParsingException(
                         f"Malformed content, found invalid json line: {e}"

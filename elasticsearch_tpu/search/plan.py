@@ -356,10 +356,11 @@ class KnnScoreNode(PlanNode):
     (the host rung of the kNN plane ladder; the mesh_pallas rung runs
     the MXU kernel in ops/pallas_knn.py with identical arithmetic).
 
-    score = (dot(x, q) * scale) / 2 + 1/2 with q pre-normalized for
-    cosine and scale the staged per-doc inverse norm (ones for
-    dot_product) — the reference's (1 + sim) / 2 convention. Every live
-    doc carrying the vector field "matches"; ranking is the whole query.
+    score = hit_score(dot(x, q) * scale) with q pre-normalized for
+    cosine and scale the staged per-doc inverse norm (none for the inner
+    products): the reference's (1 + sim) / 2, or max_inner_product's
+    piecewise score (ops/pallas_knn.hit_score). Every live doc carrying
+    the vector field "matches"; ranking is the whole query.
 
     The embedding matrix is segment-local device state (ctx.seg keys
     staged by Segment.ensure_vector_staged), NOT a plan array — so the
@@ -404,7 +405,9 @@ class KnnScoreNode(PlanNode):
             precision=jax.lax.Precision.HIGHEST)[:, 0]
         if self.metric == "cosine":
             s = s * ctx.seg[self.norm_key]
-        s = s * jnp.float32(0.5) + jnp.float32(0.5)
+        from elasticsearch_tpu.ops.pallas_knn import hit_score
+
+        s = hit_score(s, self.metric, jnp)
         scores = jnp.concatenate([s, jnp.zeros(1, jnp.float32)])
         matched = ctx.seg[self.exists_key]
         return jnp.where(matched, scores * boost,

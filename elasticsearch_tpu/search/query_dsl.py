@@ -22,6 +22,7 @@ import numpy as np
 
 from elasticsearch_tpu.common.errors import (
     IllegalArgumentException,
+    MapperParsingException,
     ParsingException,
     QueryShardException,
 )
@@ -408,17 +409,15 @@ class KnnQueryBuilder(QueryBuilder):
             raise QueryShardException(
                 f"[knn] queries are only supported on [dense_vector] "
                 f"fields; [{self.field}] is [{ft.type_name}]")
-        qv = self.query_vector
-        if (not isinstance(qv, (list, tuple))
-                or len(qv) != ft.dims
-                or any(isinstance(v, bool) or not isinstance(v, (int, float))
-                       or not np.isfinite(v) for v in qv)):
+        try:
             # finiteness matters: a NaN query poisons every score and
             # drives the kernel's tie-select out of the doc range —
             # reject with the same 400 the index path gives NaN vectors
+            ft.parse_vector(self.query_vector)
+        except MapperParsingException:
             raise IllegalArgumentException(
                 f"[knn] query_vector must be an array of {ft.dims} "
-                f"finite numbers for field [{self.field}]")
+                f"finite numbers for field [{self.field}]") from None
         return ft
 
     def to_plan(self, ctx, segment):

@@ -84,7 +84,7 @@ def oracle_ids(vecs, q, k, metric="cosine", live=None):
 
 
 class TestKnnKernel:
-    @pytest.mark.parametrize("metric", ["cosine", "dot_product"])
+    @pytest.mark.parametrize("metric", pkn.METRICS)
     def test_kernel_matches_oracle(self, metric):
         import jax.numpy as jnp
 
@@ -118,7 +118,10 @@ class TestKnnKernel:
             ref_s, ref_i = pkn.reference_knn_topk(vecs, live, qs[q], 10,
                                                   metric)
             assert top_d[q].tolist() == ref_i.tolist()
-            np.testing.assert_allclose(top_s[q], ref_s, rtol=1e-6)
+            # the kernel ranks by the similarity; the winners' _score is
+            # hit_score of it
+            np.testing.assert_allclose(pkn.hit_score(top_s[q], metric),
+                                       ref_s, rtol=1e-6)
             assert 7 not in top_d[q]
 
     def test_tile_sub_shrinks_for_vmem(self):

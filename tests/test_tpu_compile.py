@@ -525,3 +525,67 @@ def test_log_search_programs_compile_for_one_chip(topo, monkeypatch, name):
         assert " scatter(" not in text
         assert (compiled.memory_analysis().temp_size_in_bytes
                 < DENSE_COUNT_MAX_BUCKETS * LOGS_ND1 * 4)
+
+
+# cohere-768-knn-serial's staged embeddings on its one chip: 4 slots (2
+# live, 2 of headroom) of 131,072 rows (75,000 documents a shard), 768
+# wide, read by q_batch 1, k 16
+KNN_SLOT_ROWS = 1 << 17
+KNN_D_PAD = 768
+
+
+@pytest.mark.parametrize("chips, spd", [(1, 4), (4, 2)])
+def test_knn_mesh_program_reads_the_flat_embeddings_in_place(
+        topo, chips, spd):
+    """The flat kNN program at the cell's size, for one described chip
+    and for ``v5e:2x2``: one launch of the kernel a slot, each behind the
+    conditional that skips a slot with no live vector, no pass over a
+    slot's embeddings (200 MB) or the whole table outside the kernel,
+    the merge's collectives outside the branches, and ONE output (the
+    packed answers)."""
+    import re
+
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as PS
+
+    from elasticsearch_tpu.parallel import plan_exec
+
+    mesh = Mesh(np.asarray(topo.devices[:chips]), ("shards",))
+    sub = pkn.knn_tile_sub(KNN_SLOT_ROWS, KNN_D_PAD)
+    assert sub == 16  # 2,048 documents a tile at 768 wide
+    k = 16
+    program = plan_exec._mesh_knn_program(
+        mesh, spd, 1, k, sub, KNN_D_PAD, KNN_SLOT_ROWS,
+        "max_inner_product", False)
+    sharded = NamedSharding(mesh, PS("shards"))
+    rows = chips * spd * KNN_SLOT_ROWS
+
+    def on(shape, dtype, sharding=sharded):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    compiled = program.__wrapped__.lower(
+        on((rows, KNN_D_PAD), jnp.bfloat16), on((rows, 1), jnp.float32),
+        on((rows, 1), jnp.float32),
+        on((1, KNN_D_PAD), jnp.float32,
+           NamedSharding(mesh, PS()))).compile()
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines()
+             if "custom-call(" in line and "tpu_custom_call" in line]
+    assert len(calls) == spd
+    assert all("knn_tiles" in line for line in calls)
+    assert _conditionals(text) == spd
+    assert all(line in _branch_bodies(text) for line in calls)
+    if chips > 1:
+        assert "all-gather" in text
+        assert "all-gather" not in _branch_bodies(text)
+    wide = re.compile(r"bf16\[\d+,%d\]" % KNN_D_PAD)
+    passes = [line.strip()[:160] for line in text.splitlines()
+              if wide.search(line) and re.match(r"\s*(ROOT )?%[\w.-]+ = ",
+                                                line)
+              and not any(op in line for op in (
+                  " parameter(", " bitcast(", " tuple(",
+                  " get-tuple-element(", "tpu_custom_call",
+                  " conditional("))]
+    assert passes == []
+    out, = jax.tree_util.tree_leaves(compiled.out_info)
+    assert out.shape == (1, 2 + 5 * k) and out.dtype == jnp.int32
