@@ -154,6 +154,73 @@ def _unpack_answer(packed: np.ndarray):
             floats[3], floats[4])
 
 
+# The dtypes a plan array crosses to the device in by its BITS. Anything
+# else (a float64: the TPU keeps it in another form than its 64 bits,
+# PERF.md 7 (7)) stays an argument of its own.
+_PACKED_DTYPES = frozenset(
+    np.dtype(t) for t in (np.int32, np.float32, np.int64, np.bool_))
+
+
+def _pack_words(tail: tuple, dtype) -> int:
+    """int32 words a slot's row of one packed array takes (a bool row is
+    padded to whole words)."""
+    return -(-int(np.prod(tail, dtype=np.int64)) * dtype.itemsize // 4)
+
+
+def _pack_plan_arrays(arrays: List[np.ndarray], n_slots: int):
+    """A serial launch's per-slot host arrays (``[n_slots, ...]`` each:
+    the stacked plan arrays, a traced scalar a slot) as ONE
+    ``int32[n_slots, W]``: one transfer a launch where each array was
+    one (~0.14 ms each inside the jitted call on a v5e, PERF.md 6 PR
+    36). Everything goes in by its bits (``ndarray.view``: an int64 is
+    two words, four bools one), ``_unpack_plan_arrays`` takes it apart
+    in the program. Returns (packed, the arrays whose dtype cannot go
+    in, the layout: per array ``(tail shape, dtype name)`` or None)."""
+    rows, loose, layout = [], [], []
+    for a in arrays:
+        if a.dtype not in _PACKED_DTYPES:
+            loose.append(a)
+            layout.append(None)
+            continue
+        layout.append((a.shape[1:], a.dtype.name))
+        row = np.ascontiguousarray(a).reshape(n_slots, -1)
+        if a.dtype == np.bool_:
+            whole = np.zeros((n_slots, 4 * _pack_words(a.shape[1:], a.dtype)),
+                             np.bool_)
+            whole[:, :row.shape[1]] = row
+            row = whole
+        rows.append(row.view(np.int32))
+    packed = (np.concatenate(rows, axis=1) if rows
+              else np.zeros((n_slots, 0), np.int32))
+    return packed, loose, tuple(layout)
+
+
+def _unpack_plan_arrays(layout: tuple, packed, loose) -> list:
+    """``_pack_plan_arrays`` undone in the program, on a device's
+    ``[spd, W]`` rows of the pack: static slices at offsets that follow
+    from the layout, bits reinterpreted, nothing converted."""
+    lead, off, out, loose = packed.shape[:1], 0, [], iter(loose)
+    for spec in layout:
+        if spec is None:
+            out.append(next(loose))
+            continue
+        tail, dtype = spec[0], np.dtype(spec[1])
+        n = _pack_words(tail, dtype)
+        words = packed[:, off:off + n]
+        off += n
+        if dtype == np.int64:
+            words = jax.lax.bitcast_convert_type(
+                words.reshape(lead + tail + (2,)), jnp.int64)
+        elif dtype == np.float32:
+            words = jax.lax.bitcast_convert_type(words, jnp.float32)
+        elif dtype == np.bool_:
+            n_bools = int(np.prod(tail, dtype=np.int64))
+            words = jax.lax.bitcast_convert_type(words, jnp.uint8).reshape(
+                lead + (-1,))[:, :n_bools] != 0
+        out.append(words.reshape(lead + tail))
+    return out
+
+
 class PlaneHealth:
     """Per-index execution-plane failure tracking + quarantine.
 
@@ -463,7 +530,8 @@ def _mesh_query_program(mesh: Mesh, holder: _TemplateHolder, k: int,
                         features: frozenset = frozenset(),
                         slice_col: Optional[str] = None,
                         rescore_static: Optional[Tuple[int, str]] = None,
-                        agg_static: tuple = ()):
+                        agg_static: tuple = (),
+                        packing: tuple = ((), (0, 0, 0), ())):
     """One compiled scatter-gather program covering the collector-chain
     semantics of the reference's query phase (QueryPhase.java:179-268) as
     fused mask stages:
@@ -502,7 +570,13 @@ def _mesh_query_program(mesh: Mesh, holder: _TemplateHolder, k: int,
     partial accumulators INSIDE this program (same launch as scoring;
     the masks never leave the device), returned sharded per slot like
     the views. Mutually exclusive with with_views.
+    packing: (``_pack_plan_arrays``' layout of what the launch carries:
+    the main plan's arrays, the post_filter's, the rescore's, then one
+    ``float32[n_slots]`` a traced scalar; how many of each of the
+    three; the scalars' names). It follows from the shapes and features
+    the holder's key already names.
     """
+    layout, n_arrays, scalar_names = packing
     plan = holder.plan
     pf_plan = holder.pf_plan
     rs_plan = holder.rs_plan
@@ -626,8 +700,13 @@ def _mesh_query_program(mesh: Mesh, holder: _TemplateHolder, k: int,
                 agg_static, one_doc, jnp.zeros((1,), bool)))
         return (*rest, agg_parts)
 
-    def per_device(seg, plan_arrays, pf_arrays, rs_arrays, scalars):
+    def per_device(seg, packed, loose):
         dev = jax.lax.axis_index("shards")
+        arrays = iter(_unpack_plan_arrays(layout, packed, loose))
+        plan_arrays, pf_arrays, rs_arrays = (
+            list(itertools.islice(arrays, n)) for n in n_arrays)
+        # (a scalar rode in every slot's row: any of them is it)
+        scalars = {name: a[0] for name, a in zip(scalar_names, arrays)}
         # (no kernel table staged: the scatter plane, no row to offset)
         k_rows = next((seg[name].shape[0] // spd
                        for name in _KERNEL_TABLES if name in seg), 0)
@@ -692,18 +771,17 @@ def _mesh_query_program(mesh: Mesh, holder: _TemplateHolder, k: int,
     n_out = 2 + (2 if with_views else 0) + n_agg_outputs(agg_static)
     mapped = shard_map(
         per_device, mesh=mesh,
-        in_specs=(PS("shards"), PS("shards"), PS("shards"), PS("shards"),
-                  PS()),
+        in_specs=(PS("shards"), PS("shards"), PS("shards")),
         out_specs=(PS("shards"),) * n_out,
         check_vma=False,
     )
 
     @jax.jit
-    def run(seg, plan_arrays, pf_arrays, rs_arrays, scalars):
+    def run(seg, packed, loose):
         # (the scope puts the program's name into the op metadata of
         # every device operation: a trace's fusions say whose they are)
         with jax.named_scope("mesh_query"):
-            outs = mapped(seg, plan_arrays, pf_arrays, rs_arrays, scalars)
+            outs = mapped(seg, packed, loose)
         # the packed answer is replicated (row 0 == row i); the other
         # outputs keep their sharded leading axis
         return (outs[0][0],) + tuple(outs[1:])
@@ -2385,14 +2463,8 @@ class IndexMeshSearch:
                         features=frozenset(features), slice_col=slice_col,
                         rescore_static=rescore_static, tracer=tracer,
                         agg_static=(agg_plan.statics
-                                    if agg_plan is not None else ()))
-                    tel = self._telemetry
-                    if tel is not None:
-                        # occupied over total: the share of its slots a
-                        # query pays for (the program skips the others)
-                        tel.add_counters({
-                            "mesh_slots": executor.n_slots,
-                            "mesh_slots_occupied": len(executor.segments)})
+                                    if agg_plan is not None else ()),
+                        telemetry=self._telemetry)
                     # the plane served: fully re-open it (ends a probe's
                     # quarantine — single-flight contract)
                     self.plane_health.note_success(plane)
@@ -4417,7 +4489,8 @@ class MeshPlanExecutor:
                 features: frozenset = frozenset(),
                 slice_col: Optional[str] = None,
                 rescore_static: Optional[Tuple[int, str]] = None,
-                tracer=None, agg_static: tuple = ()):
+                tracer=NULL_TRACER, agg_static: tuple = (),
+                telemetry=None):
         """plans: one per shard, same query. Returns (packed int32
         [2 + 5k] — ``_unpack_answer`` gives top_keys [k], top_slot [k],
         top_doc [k], total, top_score [k], top_raw [k] —, counts
@@ -4432,10 +4505,6 @@ class MeshPlanExecutor:
         staged doc-value columns reduce inside the program."""
         if len(plans) != len(self.segments):
             raise ValueError("one plan per staged shard required")
-        if tracer is None:
-            from elasticsearch_tpu.search.telemetry import NULL_TRACER
-
-            tracer = NULL_TRACER
         t_stage = tracer.start("staging")
         local_pads = [s.nd_pad for s in self.segments]
         stacked = stack_plans(plans, local_pads, self.nd1, self.n_slots)
@@ -4458,18 +4527,25 @@ class MeshPlanExecutor:
                + f"|s{sort_keys}|v{with_views}"
                + f"|f{sorted(features)}|sl{slice_col}|r{rescore_static}"
                + f"|a{agg_static}")
+        # What the request brings rides the launch as ONE host array (the
+        # jitted call places it as ``shard_map``'s in_specs say): a sharded
+        # ``device_put`` an array ahead of the launch costs ~0.3 ms of
+        # Python each on a v5e's host, a host array of its own inside the
+        # call ~0.14 (PERF.md 6 PR 36).
+        names = tuple(sorted(scalars or ()))
+        packed, loose, layout = _pack_plan_arrays(
+            [*stacked, *stacked_pf, *stacked_rs,
+             *(np.full(self.n_slots, scalars[n], np.float32)
+               for n in names)], self.n_slots)
         run = _mesh_query_program(
             self.mesh,
             _TemplateHolder(_strip_plan(plans[0]), key, pf_tpl, rs_tpl), k,
             spd=self.slots_per_dev,
             sort_keys=sort_keys, with_views=with_views, features=features,
             slice_col=slice_col, rescore_static=rescore_static,
-            agg_static=agg_static)
-        staged_plan = [jax.device_put(a, self._sharding) for a in stacked]
-        staged_pf = [jax.device_put(a, self._sharding) for a in stacked_pf]
-        staged_rs = [jax.device_put(a, self._sharding) for a in stacked_rs]
-        jscalars = {name: jnp.float32(v)
-                    for name, v in (scalars or {}).items()}
+            agg_static=agg_static, packing=(
+                layout, (len(stacked), len(stacked_pf), len(stacked_rs)),
+                names))
         tracer.stop("staging", t_stage)
         wanted = set(sort_keys or ())
         if slice_col is not None:
@@ -4489,5 +4565,13 @@ class MeshPlanExecutor:
         # and compiled again after a later request staged a column.
         seg = {name: a for name, a in self._seg_staged.items()
                if name in wanted or not name.startswith(_ON_DEMAND)}
-        return _launch_locked(tracer, run, seg, staged_plan, staged_pf,
-                              staged_rs, jscalars)
+        outs = _launch_locked(tracer, run, seg, packed, loose)
+        if telemetry is not None:
+            # host arrays the launch carried (the pack, and what could
+            # not go in) / arrays put on the device ahead of it; occupied
+            # slots over all: the share a query pays for
+            telemetry.add_counters({
+                "h2d_arrays": 1 + len(loose), "explicit_puts": 0,
+                "mesh_slots": self.n_slots,
+                "mesh_slots_occupied": len(self.segments)})
+        return outs

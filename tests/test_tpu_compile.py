@@ -437,6 +437,36 @@ def test_serial_mesh_program_has_one_replicated_merge_output(
 # sort, and 3 s at this one: what it refuses does not depend on it)
 LOGS_ND1 = (1 << 12) + 1
 LOGS_T0 = 897436800000
+def test_a_launchs_packed_plan_arrays_unpack_on_the_chip(sds):
+    """``_unpack_plan_arrays`` (ISSUE 36) for the described chip, every
+    dtype the pack takes, at the cell's four slots: an int64 from two
+    words on the emulated 64-bit path, a document-sized bool mask from
+    four to a word. (The two serial cells' own programs, which unpack
+    int32, float32 and int64, compile below.)"""
+    import numpy as np
+
+    from elasticsearch_tpu.parallel import plan_exec
+
+    arrays = [np.zeros((CELL_SLOTS, 2), np.int64),
+              np.zeros((CELL_SLOTS, 65537), bool),
+              np.zeros((CELL_SLOTS, 1, 16), np.float32),
+              np.zeros((CELL_SLOTS, 1, 16), np.int32),
+              np.zeros((CELL_SLOTS,), np.float32)]
+    packed, loose, layout = plan_exec._pack_plan_arrays(arrays, CELL_SLOTS)
+    assert loose == []
+
+    def run(packed, column):
+        bounds, mask, weights, rows, scalar = plan_exec._unpack_plan_arrays(
+            layout, packed, [])
+        hit = (column >= bounds[:, :1]) & (column < bounds[:, 1:]) & mask
+        return jnp.sum(hit, axis=1), weights * scalar[0] + rows
+
+    text = jax.jit(run).lower(
+        sds(packed.shape, jnp.int32),
+        sds((CELL_SLOTS, 65537), jnp.int64)).compile().as_text()
+    assert "f64" not in text
+
+
 LOGS_DOCS = 300
 
 
