@@ -1888,6 +1888,14 @@ class IndexService:
                 "embedding_bytes_streamed_total": (
                     self._mesh_search.embedding_bytes_streamed_total
                     if self._mesh_search is not None else 0),
+                # scored terms as the serial mesh rungs planned them:
+                # block rows expanded for scatter nodes, kernel lanes
+                "plan_blocks_expanded_total": (
+                    self._mesh_search.plan_blocks_expanded_total
+                    if self._mesh_search is not None else 0),
+                "kernel_lanes_planned_total": (
+                    self._mesh_search.kernel_lanes_planned_total
+                    if self._mesh_search is not None else 0),
                 # fused on-device aggregations (ISSUE 13, docs/AGGS.md):
                 # agg'd queries whose whole agg set reduced inside the
                 # mesh program vs those that fell back to the host

@@ -262,6 +262,10 @@ class QueryLane(NamedTuple):
     weight: float  # idf * boost (0 disables the lane)
 
 
+# build_tile_tables' per-lane key offset: far above any doc id (< 2**31)
+_LANE_KEY = np.int64(1) << 40
+
+
 def build_tile_tables(
     lanes: Sequence[QueryLane],
     bmin: np.ndarray,
@@ -277,37 +281,50 @@ def build_tile_tables(
     The kernel DMAs TWO consecutive CB-aligned windows starting at
     align(row_lo, CB) — rows [align(lo), align(lo) + 2*CB) — so any window
     with row_hi - row_lo <= CB is fully covered regardless of where the
-    aligned start lands (it can sit up to CB-1 rows before row_lo)."""
+    aligned start lands (it can sit up to CB-1 rows before row_lo).
+
+    Every live lane (blocks and a nonzero weight) is planned in one pass:
+    the lanes' block runs are gathered into one array whose keys are
+    offset by lane, so one sortedness check and one searchsorted per
+    bound answer every (tile, lane)."""
     w = geom.tile_w
     n_tiles = geom.n_tiles
     t_pad = t_pad or next_pow2(max(len(lanes), 1))
     row_lo = np.zeros((n_tiles, t_pad), dtype=np.int32)
     row_hi = np.zeros((n_tiles, t_pad), dtype=np.int32)
     weights = np.zeros((1, t_pad), dtype=np.float32)
-    tile_lo = np.arange(n_tiles, dtype=np.int64) * w
     need = 1
-    for j, lane in enumerate(lanes):
-        s, c = lane.block_start, lane.block_count
-        if c <= 0 or lane.weight == 0.0:
-            continue
-        tb_min = bmin[s: s + c]
-        tb_max = bmax[s: s + c]
-        if c > 1 and (np.any(np.diff(tb_min) < 0)
-                      or np.any(np.diff(tb_max) < 0)):
+    live = [j for j, lane in enumerate(lanes)
+            if lane.block_count > 0 and lane.weight != 0.0]
+    if live:
+        j = np.asarray(live, np.int64)
+        s = np.asarray([lanes[i].block_start for i in live], np.int64)
+        c = np.asarray([lanes[i].block_count for i in live], np.int64)
+        first_pos = np.cumsum(c) - c  # each run's place in the gather
+        rows = np.arange(int(c.sum())) + np.repeat(s - first_pos, c)
+        # a doc id is under 2**31 (and a block's min/max doc lies in
+        # [-1, 2**31]), so lane-offset keys keep the runs apart: the
+        # gather is sorted exactly where every run is
+        key = np.repeat(j * _LANE_KEY, c)
+        kmin = bmin[rows].astype(np.int64) + key
+        kmax = bmax[rows].astype(np.int64) + key
+        unsorted = (np.diff(kmin) < 0) | (np.diff(kmax) < 0)
+        if unsorted.any():
+            bad = int(np.repeat(j, c)[int(unsorted.argmax())])
             raise ValueError(
-                f"lane {j}: per-block doc ranges not sorted (empty mid-run "
+                f"lane {bad}: per-block doc ranges not sorted (empty mid-run "
                 f"block or unsorted postings) — coverage would be silently "
                 f"wrong")
         # first block whose max_doc >= tile start; first block whose
         # min_doc >= tile end — [first, end) covers the tile
-        first = np.searchsorted(tb_max, tile_lo, side="left")
-        end = np.searchsorted(tb_min, tile_lo + w, side="left")
+        q = (np.arange(n_tiles, dtype=np.int64) * w)[:, None] + j * _LANE_KEY
+        first = np.searchsorted(kmax, q, side="left") - first_pos
+        end = np.searchsorted(kmin, q + w, side="left") - first_pos
         end = np.maximum(end, first)
         row_lo[:, j] = s + first
         row_hi[:, j] = s + end
-        weights[0, j] = lane.weight
-        cov = int((end - first).max()) if c else 0
-        need = max(need, cov)
+        weights[0, j] = [lanes[i].weight for i in live]
+        need = max(need, int((end - first).max()))
     # mosaic requires sublane block sizes divisible by 8; the double-window
     # scheme covers any alignment as long as cov <= cb, and the segment
     # padding (CB_MAX rows) must fit both windows: cb <= CB_MAX // 2

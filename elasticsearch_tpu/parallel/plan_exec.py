@@ -1288,6 +1288,11 @@ class IndexMeshSearch:
         # vector; the program skips the others): over knn_query_total,
         # the slots a query paid for
         self.knn_slots_scanned_total = 0
+        # what the serial mesh rungs planned for scored terms: posting
+        # block rows expanded into scatter arrays (0 while every query's
+        # terms land on the kernel) and lanes of kernel nodes
+        self.plan_blocks_expanded_total = 0
+        self.kernel_lanes_planned_total = 0
         # and the bf16 embedding bytes those passes streamed, a launch
         self.embedding_bytes_streamed_total = 0
         # fused on-device aggregations (ISSUE 13, docs/AGGS.md):
@@ -2456,6 +2461,11 @@ class IndexMeshSearch:
                         # fallback of the aggregations plans a ``filter``
                         # body with it, over the segment's own arrays)
                         ctx.mesh_columns = None
+                    with self._counter_lock:
+                        self.plan_blocks_expanded_total += sum(
+                            c.blocks_expanded for c in ctxs.values())
+                        self.kernel_lanes_planned_total += sum(
+                            c.kernel_lanes for c in ctxs.values())
                     used_pallas = False
                     if session is not None:
                         used_pallas = executor.harmonize_kernel_nodes(

@@ -61,6 +61,10 @@ class ShardQueryContext:
         self.mapper_service = mapper_service
         self.analyzers = mapper_service.analyzers
         self.engine = engine
+        # what score_terms_node planned through this context: posting
+        # block rows expanded for scatter nodes, lanes of kernel nodes
+        self.blocks_expanded = 0
+        self.kernel_lanes = 0
 
     def field_type(self, name: str):
         return self.mapper_service.field_type(name)
@@ -90,29 +94,35 @@ class ShardQueryContext:
         ]
 
 
+def _pow2_len(n, min_len=8):
+    """The padded length of an n-row array: a power of two, at least
+    ``min_len``."""
+    p = max(min_len, 1)
+    while p < n:
+        p *= 2
+    return p
+
+
 def _pad_pow2(lst, pad_value, min_len=8, dtype=None):
-    n = max(min_len, 1)
-    while n < len(lst):
-        n *= 2
-    arr = list(lst) + [pad_value] * (n - len(lst))
+    arr = list(lst) + [pad_value] * (_pow2_len(len(lst), min_len) - len(lst))
     return np.asarray(arr, dtype=dtype)
 
 
 def term_blocks_arrays(segment, weighted_terms, ctx=None):
-    """weighted_terms: list of (field, token, boost). Builds the gather
-    arrays for ScoreTermsNode. When ``ctx`` is given, each field's mapped
+    """weighted_terms: list of (field, token, boost). Resolves each term
+    present in the segment to one lane: ``lanes_meta`` (block_start,
+    block_count, weight, kernel_eligible), all a kernel node reads, and
+    ``lane_params`` (kind, norm row, avgdl, weight, p1, p2, p3), which
+    ``scatter_block_arrays`` repeats over the lane's posting blocks where
+    a ScoreTermsNode is built. When ``ctx`` is given, each field's mapped
     similarity folds its per-term constants into the lane params
     (index/similarity.py); without it, classic BM25 defaults apply."""
-    blocks, weights, rows, avgdls = [], [], [], []
-    p1s, p2s, p3s, kind_ids = [], [], [], []
-    kinds: List[str] = []
-    lanes_meta = []  # (block_start, block_count, weight, kernel_eligible)
-    n_terms_present = 0
+    lanes_meta = []
+    lane_params = []
     for field, token, boost in weighted_terms:
         tid = segment.term_id(field, token)
         if tid < 0:
             continue
-        n_terms_present += 1
         st = segment.field_stats.get(field, {})
         doc_count = st.get("doc_count", 0)
         row = segment.field_norm_idx.get(field, 0)
@@ -128,40 +138,62 @@ def term_blocks_arrays(segment, weighted_terms, ctx=None):
             "avgdl": avgdl,
             "boost": boost,
         })
-        if kind not in kinds:
-            kinds.append(kind)
-        kid = kinds.index(kind)
-        start = int(segment.term_block_start[tid])
         # the pallas tile kernel precomputes per-posting norm factors
         # with default-constant BM25 and the segment's local stats; any
         # other similarity/params must take the scatter path. (No dfs-
         # adjusted avgdl reaches this builder today; if one ever does,
         # its lane must be marked ineligible here.)
-        lanes_meta.append((start, int(segment.term_block_count[tid]),
-                           float(w),
+        lanes_meta.append((int(segment.term_block_start[tid]),
+                           int(segment.term_block_count[tid]), float(w),
                            kind == "bm25" and p1 == K1 and p2 == B))
-        for bi in range(start, start + int(segment.term_block_count[tid])):
-            blocks.append(bi)
-            weights.append(w)
-            rows.append(row)
-            avgdls.append(avgdl)
-            p1s.append(p1)
-            p2s.append(p2)
-            p3s.append(p3)
-            kind_ids.append(kid)
+        lane_params.append((kind, row, avgdl, w, p1, p2, p3))
     return {
-        "q_blocks": _pad_pow2(blocks, 0, dtype=np.int32),
-        "q_weights": _pad_pow2(weights, 0.0, dtype=np.float32),
-        "q_norm_rows": _pad_pow2(rows, 0, dtype=np.int32),
-        "q_avgdl": _pad_pow2(avgdls, 1.0, dtype=np.float32),
-        "q_valid": _pad_pow2([True] * len(blocks), False, dtype=bool),
-        "q_p1": _pad_pow2(p1s, 1.0, dtype=np.float32),
-        "q_p2": _pad_pow2(p2s, 1.0, dtype=np.float32),
-        "q_p3": _pad_pow2(p3s, 0.0, dtype=np.float32),
-        "q_kinds": _pad_pow2(kind_ids, 0, dtype=np.int32),
-        "kinds": tuple(kinds) if kinds else ("bm25",),
-        "n_present": n_terms_present,
+        "n_present": len(lanes_meta),
         "lanes_meta": lanes_meta,
+        "lane_params": lane_params,
+    }
+
+
+def _padded_blocks(arrs) -> int:
+    """The scatter node's lane count: every lane's posting blocks, padded
+    to a power of two (at least 8)."""
+    return _pow2_len(sum(c for _, c, _, _ in arrs["lanes_meta"]))
+
+
+def scatter_block_arrays(arrs):
+    """The gather arrays of ScoreTermsNode: one row per posting block of
+    every lane, carrying its lane's constants, then padding rows that
+    never match. ``kinds`` lists the lanes' similarity kinds in first-seen
+    order and ``q_kinds`` indexes it."""
+    starts = np.array([s for s, _, _, _ in arrs["lanes_meta"]], np.int64)
+    counts = np.array([c for _, c, _, _ in arrs["lanes_meta"]], np.int64)
+    lane_kinds, rows, avgdls, weights, p1s, p2s, p3s = (
+        list(zip(*arrs["lane_params"])) or [()] * 7)
+    kinds = list(dict.fromkeys(lane_kinds))
+    total = int(counts.sum())
+    n = _padded_blocks(arrs)
+
+    def column(values, pad, dtype):
+        out = np.full(n, pad, dtype)
+        out[:total] = np.repeat(np.asarray(values, dtype), counts)
+        return out
+
+    # lane i's rows are start_i, start_i + 1, ...: a row's position in
+    # the expansion, less its lane's first position, plus the lane's start
+    first = np.cumsum(counts) - counts
+    q_blocks = np.zeros(n, np.int32)
+    q_blocks[:total] = np.arange(total) + np.repeat(starts - first, counts)
+    return {
+        "q_blocks": q_blocks,
+        "q_weights": column(weights, 0.0, np.float32),
+        "q_norm_rows": column(rows, 0, np.int32),
+        "q_avgdl": column(avgdls, 1.0, np.float32),
+        "q_valid": column([True] * len(rows), False, bool),
+        "q_p1": column(p1s, 1.0, np.float32),
+        "q_p2": column(p2s, 1.0, np.float32),
+        "q_p3": column(p3s, 0.0, np.float32),
+        "q_kinds": column([kinds.index(k) for k in lane_kinds], 0, np.int32),
+        "kinds": tuple(kinds) if kinds else ("bm25",),
     }
 
 
@@ -179,7 +211,7 @@ def score_terms_node(segment, weighted_terms, min_match=1, ctx=None) -> P.PlanNo
             # unsatisfiable even with every lane valid: emit can never
             # match, but the skeleton must still line up — pin the
             # threshold above the padded lane count
-            min_match = arrs["q_valid"].shape[0] + 1
+            min_match = _padded_blocks(arrs) + 1
     node = None
     if not getattr(ctx, "for_mesh", False):
         node = _pallas_score_terms_node(segment, arrs, min_match)
@@ -192,12 +224,17 @@ def score_terms_node(segment, weighted_terms, min_match=1, ctx=None) -> P.PlanNo
         node = _mesh_pallas_score_terms_node(segment, arrs, min_match,
                                              ctx.mesh_kernel)
     if node is not None:
+        if ctx is not None:
+            ctx.kernel_lanes += arrs["n_present"]
         return node
+    blk = scatter_block_arrays(arrs)
+    if ctx is not None:
+        ctx.blocks_expanded += int(blk["q_valid"].sum())
     return P.ScoreTermsNode(
-        arrs["q_blocks"], arrs["q_weights"], arrs["q_norm_rows"],
-        arrs["q_avgdl"], arrs["q_valid"], min_match,
-        q_p1=arrs["q_p1"], q_p2=arrs["q_p2"], q_p3=arrs["q_p3"],
-        q_kinds=arrs["q_kinds"], kinds=arrs["kinds"],
+        blk["q_blocks"], blk["q_weights"], blk["q_norm_rows"],
+        blk["q_avgdl"], blk["q_valid"], min_match,
+        q_p1=blk["q_p1"], q_p2=blk["q_p2"], q_p3=blk["q_p3"],
+        q_kinds=blk["q_kinds"], kinds=blk["kinds"],
     )
 
 
