@@ -1888,6 +1888,20 @@ class IndexService:
                 "embedding_bytes_streamed_total": (
                     self._mesh_search.embedding_bytes_streamed_total
                     if self._mesh_search is not None else 0),
+                # the mesh's devices a launch ran on, and those holding
+                # a segment: the share of the chips given work
+                "mesh_devices_total": (
+                    self._mesh_search.mesh_devices_total
+                    if self._mesh_search is not None else 0),
+                "mesh_devices_live_total": (
+                    self._mesh_search.mesh_devices_live_total
+                    if self._mesh_search is not None else 0),
+                # where the staged generation's segments sit: device d's
+                # slots holding one at [d] ([] with nothing staged)
+                "mesh_live_slots_per_device": (
+                    self._mesh_search._executor.live_slots_per_device()
+                    if self._mesh_search is not None
+                    and self._mesh_search._executor is not None else []),
                 # scored terms as the serial mesh rungs planned them:
                 # block rows expanded for scatter nodes, kernel lanes
                 "plan_blocks_expanded_total": (

@@ -43,11 +43,14 @@ from elasticsearch_tpu.ops.scoring import B, K1, bm25_idf
 # ---------------------------------------------------------------------------
 
 
-def stack_shard_arrays(segments: List, n_devices: int) -> Dict[str, np.ndarray]:
+def stack_shard_arrays(segments: List, n_devices: int,
+                       slots: Optional[List[int]] = None
+                       ) -> Dict[str, np.ndarray]:
     """Stack one segment per shard into mesh-ready arrays.
 
     All shards pad to the max bucketed shape. Returns host numpy arrays
-    with a leading [n_devices] axis.
+    with a leading [n_devices] axis; segment i in row ``slots[i]`` (row i
+    where None), the other rows dead (an all-false ``live1``).
     """
     if len(segments) > n_devices:
         raise ValueError(f"{len(segments)} shards > {n_devices} devices")
@@ -60,7 +63,8 @@ def stack_shard_arrays(segments: List, n_devices: int) -> Dict[str, np.ndarray]:
     block_tfs = np.zeros((n_devices, n_blocks, blk), dtype=np.float32)
     norms = np.ones((n_devices, n_norm, nd_pad + 1), dtype=np.float32)
     live1 = np.zeros((n_devices, nd_pad + 1), dtype=bool)
-    for i, seg in enumerate(segments):
+    for i, seg in zip(range(len(segments)) if slots is None else slots,
+                      segments):
         bd = seg.block_docs.copy()
         bd[bd == seg.nd_pad] = nd_pad  # re-point sentinel to stacked pad
         block_docs[i, : bd.shape[0]] = bd

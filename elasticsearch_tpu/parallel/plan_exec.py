@@ -371,18 +371,24 @@ _PAD_VALUES = {"z": 0, "o": 1, "n": np.nan, "m1": -1}
 
 
 def stack_plans(plans: List[PlanNode], local_nd_pads: List[int],
-                stacked_nd1: int, n_slots: int) -> List[np.ndarray]:
+                stacked_nd1: int, n_slots: int,
+                slots: Optional[List[int]] = None) -> List[np.ndarray]:
     """Stack per-shard plan arrays to mesh-ready arrays.
 
     Returns a flat list aligned with ``template.flat_arrays()`` where every
     entry has a leading [n_slots] axis (slots = device x segments-packed-
-    per-device). Slots beyond len(plans) replicate shard 0's arrays, for
-    the shapes' sake only: their seg arrays have live1 all-False, so the
-    serial program skips their pass and never reads these rows (the
-    batched programs still score them, against zero kernel frac, and
-    mask the result away).
+    per-device). ``slots``: the slot of each plan's segment (the
+    executor's placement; plan i in slot i where None). Slots that hold
+    no segment replicate shard 0's arrays, for the shapes' sake only:
+    their seg arrays have live1 all-False, so the serial program skips
+    their pass and never reads these rows (the batched programs still
+    score them, against zero kernel frac, and mask the result away).
     """
     _check_same_structure(plans)
+    # the plan whose arrays each slot stacks
+    src = [0] * n_slots
+    for i, slot in enumerate(range(len(plans)) if slots is None else slots):
+        src[slot] = i
     kinds = plans[0].flat_pad_kinds()
     try:
         flats = [[np.asarray(a) for a in p.flat_arrays()] for p in plans]
@@ -399,7 +405,7 @@ def stack_plans(plans: List[PlanNode], local_nd_pads: List[int],
         if kind == "x":
             # non-stackable node — the host per-shard path serves these
             raise PlanStructureMismatch("plan contains non-stackable arrays")
-        parts = [f[i] for f in flats]
+        parts = [flats[j][i] for j in src]
         if kind == "k":
             # kernel tables: stack verbatim, but ONLY when every shard's
             # tables were harmonized to one shape (the kernel trace is
@@ -407,11 +413,8 @@ def stack_plans(plans: List[PlanNode], local_nd_pads: List[int],
             # and the plan must not reach the mesh program)
             if len({(p.shape, str(p.dtype)) for p in parts}) != 1:
                 raise PlanStructureMismatch("kernel table shapes diverge")
-            parts = parts + [parts[0]] * (n_slots - len(parts))
             stacked.append(np.stack(parts))
             continue
-        # replicate shard 0 into unused slots
-        parts = parts + [parts[0]] * (n_slots - len(parts))
         if kind == "s" or parts[0].ndim == 0:
             stacked.append(np.stack([np.asarray(p) for p in parts]))
             continue
@@ -435,8 +438,7 @@ def stack_plans(plans: List[PlanNode], local_nd_pads: List[int],
             if kind == "d":
                 # re-point the shard-local sentinel doc to the stacked
                 # one (replicated filler slots came from shard 0)
-                src_shard = d if d < len(plans) else 0
-                a = np.where(a == local_nd_pads[src_shard], sentinel, a)
+                a = np.where(a == local_nd_pads[src[d]], sentinel, a)
             out[(d,) + tuple(slice(0, s) for s in a.shape)] = a
         stacked.append(out)
     return stacked
@@ -1295,6 +1297,11 @@ class IndexMeshSearch:
         self.kernel_lanes_planned_total = 0
         # and the bf16 embedding bytes those passes streamed, a launch
         self.embedding_bytes_streamed_total = 0
+        # devices of the mesh a launch ran on, and those of them holding
+        # a segment (a slot that is not headroom): over the first, the
+        # share of the chips whose program had work (docs/MESH.md)
+        self.mesh_devices_total = 0
+        self.mesh_devices_live_total = 0
         # fused on-device aggregations (ISSUE 13, docs/AGGS.md):
         # queries whose whole agg set reduced inside the mesh program,
         # vs agg'd mesh queries that fell back to the host reduce over
@@ -1459,7 +1466,7 @@ class IndexMeshSearch:
         staged_counts = {(sid, kid): n
                          for sid, kid, n in self._staged_key}
         slot_of = {(sid, id(seg)): slot
-                   for slot, (sid, seg) in enumerate(old.pairs)}
+                   for slot, (sid, seg) in zip(old.slots, old.pairs)}
         if set(slot_of) != set(staged_counts):
             return None  # key/generation disagree: rebuild from truth
         new_ids = {(sid, id(seg)) for sid, seg in pairs}
@@ -1696,7 +1703,8 @@ class IndexMeshSearch:
                     postings_codec=codec,
                     index_name=self.svc.name,
                     stage_reason=reason,
-                    slots_per_dev=spd),
+                    slots_per_dev=spd,
+                    shard_ids=[sid for sid, _ in pairs]),
                 index=self.svc.name, kind="mesh_slot_tables",
                 plane="mesh")
         except (TaskCancelledException, TimeExceededException):
@@ -1720,7 +1728,6 @@ class IndexMeshSearch:
             return False
         finally:
             self._stage_probing = False
-        staged.pairs = pairs
         if old is not None:
             old.release()
         self._pairs = pairs
@@ -1740,7 +1747,7 @@ class IndexMeshSearch:
         if executor is None:
             return None
         slots = []
-        for slot, (sid, seg) in enumerate(executor.pairs):
+        for slot, (sid, seg) in sorted(zip(executor.slots, executor.pairs)):
             total = int(seg.num_docs)
             live = int(seg.live_doc_count)
             slots.append({
@@ -1755,6 +1762,8 @@ class IndexMeshSearch:
             "slots_per_device": executor.slots_per_dev,
             "free_slots": free,
             "free_slots_per_device": round(free / executor.n_dev, 2),
+            # where the segments sit: device d's live slots at [d]
+            "live_slots_per_device": executor.live_slots_per_device(),
             "slots": slots,
         }
 
@@ -2059,6 +2068,8 @@ class IndexMeshSearch:
             self.query_total += q_batch
             self.pallas_query_total += q_batch
             self.knn_query_total += q_batch
+            self.mesh_devices_total += executor.n_dev
+            self.mesh_devices_live_total += executor.live_devices
             if q_batch > 1:
                 self.batched_launch_total += 1
                 self.batched_query_total += q_batch
@@ -2090,7 +2101,7 @@ class IndexMeshSearch:
             for i in range(min(ks[q], len(keys))):
                 if keys[i] == -np.inf:
                     continue
-                sid, seg = executor.pairs[int(slots[i])]
+                sid, seg = executor.pair_of_slot[int(slots[i])]
                 score = float(scores[i])
                 refs.append(DocRef(sid, seg.name, int(docs[i]), score, ()))
                 if max_score is None:
@@ -2338,8 +2349,7 @@ class IndexMeshSearch:
                 return None  # host path owns the error shape
             try:
                 slice_col = executor.ensure_slice_column(
-                    slice_spec, [sid for sid, _seg in executor.pairs],
-                    len(self.svc.shards))
+                    slice_spec, len(self.svc.shards))
             except Exception:  # noqa: BLE001 — host path owns errors
                 return None
             if slice_col is None:
@@ -2530,12 +2540,14 @@ class IndexMeshSearch:
             ta = int(terminate_after)
             counts = np.asarray(seg_counts)
             by_shard: Dict[int, int] = {}
-            for i, (sid, _seg) in enumerate(executor.pairs):
-                by_shard[sid] = by_shard.get(sid, 0) + int(counts[i])
+            for slot, (sid, _seg) in zip(executor.slots, executor.pairs):
+                by_shard[sid] = by_shard.get(sid, 0) + int(counts[slot])
             total = sum(min(c, ta) for c in by_shard.values())
             terminated_early = any(c >= ta for c in by_shard.values())
         with self._counter_lock:
             self.query_total += 1
+            self.mesh_devices_total += executor.n_dev
+            self.mesh_devices_live_total += executor.live_devices
             if used_pallas:
                 self.pallas_query_total += 1
             if sort_keys is not None:
@@ -2554,7 +2566,7 @@ class IndexMeshSearch:
         for i, (key, slot, d) in enumerate(zip(keys, slots, docs)):
             if key == -np.inf:
                 continue
-            sid, seg = executor.pairs[int(slot)]
+            sid, seg = executor.pair_of_slot[int(slot)]
             score = float(scores[i])
             if sort_keys is None:
                 sv = (score,) if rescore_static is not None else ()
@@ -2600,7 +2612,7 @@ class IndexMeshSearch:
                 )
 
                 aggregations = finalize_fused(agg_plan, agg_outs,
-                                              len(executor.pairs))
+                                              executor.slots)
                 self._note_fused_aggs([agg_plan])
                 tel = self._telemetry
                 if tel is not None:
@@ -2612,11 +2624,12 @@ class IndexMeshSearch:
             else:
                 matched_np, scores_np = agg_outs
                 views = []
-                for i, (sid, seg) in enumerate(executor.pairs):
+                for slot, (sid, seg) in zip(executor.slots,
+                                            executor.pairs):
                     nd1 = seg.nd_pad + 1
                     views.append(SegmentView(
-                        seg, matched_np[i, :nd1], ctxs[sid],
-                        scores_np[i, :nd1]))
+                        seg, matched_np[slot, :nd1], ctxs[sid],
+                        scores_np[slot, :nd1]))
                 aggregations = run_aggregations(agg_specs, views)
                 self._note_agg_fallback(agg_reason or "field_ineligible")
             tracer.stop("aggregate.finalize", t)
@@ -2749,7 +2762,7 @@ class IndexMeshSearch:
         q_pad = next_pow2(q_batch)
         geom = session["geom"]
         n_pairs = len(executor.pairs)
-        # per-member, per-slot kernel lane sets via the same deferred
+        # per-member, per-pair kernel lane sets via the same deferred
         # plan builder the serial mesh path uses — the plan must be
         # EXACTLY one kernel-scored disjunction (no wrapper nodes).
         # Built OUTSIDE the fault-recording try: a malformed member body
@@ -2761,7 +2774,7 @@ class IndexMeshSearch:
             lane_sets = [[None] * q_batch for _ in range(n_pairs)]
             for q, body in enumerate(bodies):
                 qb = parse_query(body.get("query"))
-                for slot, (sid, seg) in enumerate(executor.pairs):
+                for i, (sid, seg) in enumerate(executor.pairs):
                     shard = self.svc.shards[sid]
                     ctx = ShardQueryContext(shard.mapper_service,
                                             engine=shard.engine)
@@ -2774,7 +2787,7 @@ class IndexMeshSearch:
                         # minimum_should_match > 1 needs the dense-counts
                         # variant the fused top-k kernel doesn't emit
                         return None
-                    lane_sets[slot][q] = plan._mesh_lanes
+                    lane_sets[i][q] = plan._mesh_lanes
         except Exception:  # noqa: BLE001 — request-shaped error: serial
             # execution surfaces it per member with the right status
             return None
@@ -2847,8 +2860,8 @@ class IndexMeshSearch:
             # geometry (a dense union on ANY slot shrinks everyone's
             # tile); build_tile_tables_batched owns the union/pad
             # contract — same code the host rung runs
-            unions = [psc.union_query_lanes(lane_sets[slot])[0]
-                      for slot in range(n_pairs)]
+            unions = [psc.union_query_lanes(lanes)[0]
+                      for lanes in lane_sets]
             t_pad = max(next_pow2(max(len(u), 1)) for u in unions)
             sub = geom.tile_sub
             if pruning:
@@ -2877,10 +2890,10 @@ class IndexMeshSearch:
                     geom.nd_pad, sub)
                 try:
                     tables = []
-                    for slot, (sid, seg) in enumerate(executor.pairs):
+                    for lanes, seg in zip(lane_sets, executor.segments):
                         bmin, bmax = session["meta"][id(seg)][:2]
                         tables.append(psc.build_tile_tables_batched(
-                            lane_sets[slot], bmin, bmax, g, t_pad=t_pad))
+                            lanes, bmin, bmax, g, t_pad=t_pad))
                     break
                 except ValueError:
                     if sub <= 32 or g.tile_sub < sub:
@@ -2894,10 +2907,10 @@ class IndexMeshSearch:
             rl = np.zeros((n_slots, n_tiles, t_pad), np.int32)
             rh = np.zeros((n_slots, n_tiles, t_pad), np.int32)
             w_all = np.zeros((n_slots, q_pad, t_pad), np.float32)
-            for slot in range(n_pairs):
-                rl[slot] = tables[slot][0]
-                rh[slot] = tables[slot][1]
-                w_all[slot, : q_batch] = tables[slot][2]
+            for i, slot in enumerate(executor.slots):
+                rl[slot] = tables[i][0]
+                rh[slot] = tables[i][1]
+                w_all[slot, : q_batch] = tables[i][2]
             # filler slots/queries keep zero tables/weights: their live
             # masks are all-dead and zero weights score nothing
             tps = psc.TILES_PER_STEP
@@ -2911,11 +2924,11 @@ class IndexMeshSearch:
                 # tiles by bound, split probe/rest) — the threshold
                 # exchange itself stays on-device in the program
                 plans_p = []
-                for slot in range(n_pairs):
-                    seg = executor.pairs[slot][1]
+                for i, (slot, seg) in enumerate(zip(executor.slots,
+                                                    executor.segments)):
                     bfmax = session["meta"][id(seg)][2]
                     ub = executor.tile_lane_ub_cached(
-                        seg, unions[slot], rl[slot], rh[slot], bfmax,
+                        seg, unions[i], rl[slot], rh[slot], bfmax,
                         g.tile_sub)
                     plan = psc.plan_pruned_tiles(
                         rl[slot], rh[slot], w_all[slot], bfmax, probe,
@@ -2934,7 +2947,7 @@ class IndexMeshSearch:
                 tid_r = np.zeros((n_slots, n_rest), np.int32)
                 bounds_r = np.full((n_slots, n_rest, q_pad), -np.inf,
                                    np.float32)
-                for slot, plan in enumerate(plans_p):
+                for slot, plan in zip(executor.slots, plans_p):
                     rl_p[slot] = plan["rl_probe"]
                     rh_p[slot] = plan["rh_probe"]
                     tid_p[slot] = plan["tid_probe"]
@@ -2947,7 +2960,7 @@ class IndexMeshSearch:
                     q_pad, kk, t_pad, cb, g.tile_sub, tps,
                     session["mode"] == "interpret", codec, probe, n_rest)
                 slot_real = np.zeros(n_slots, np.int32)
-                slot_real[:n_pairs] = 1
+                slot_real[executor.slots] = 1
                 args = corpus + (
                     staged[live_key],
                     jax.device_put(rl_p, sharding),
@@ -3069,6 +3082,8 @@ class IndexMeshSearch:
         with self._counter_lock:
             self.query_total += q_batch
             self.pallas_query_total += q_batch
+            self.mesh_devices_total += executor.n_dev
+            self.mesh_devices_live_total += executor.live_devices
             if q_batch > 1:
                 # the Q==1 pruned fast path is not cross-query batching:
                 # it must not inflate the batching-adoption telemetry
@@ -3098,7 +3113,7 @@ class IndexMeshSearch:
                     continue
                 n = n_agg_outputs(plan.statics)
                 member_aggs[q] = finalize_fused(
-                    plan, agg_raw[pos: pos + n], n_pairs)
+                    plan, agg_raw[pos: pos + n], executor.slots)
                 pos += n
             bt.stop("aggregate", t_aggf)
             self._note_fused_aggs(
@@ -3117,7 +3132,7 @@ class IndexMeshSearch:
                                     docs[q][: ks[q]]):
                 if key == -np.inf or d < 0:
                     continue
-                sid, seg = executor.pairs[int(slot)]
+                sid, seg = executor.pair_of_slot[int(slot)]
                 score = float(key)
                 refs.append(DocRef(sid, seg.name, int(d), score, ()))
                 if max_score is None:
@@ -3158,11 +3173,57 @@ def _knn_live(seg, col) -> np.ndarray:
         np.float32)
 
 
-def _knn_slot_rows(table, n_slots: int):
-    """A flat kNN table as ``[n_slots, rows a slot, width]``, to set
-    whole slots of it by index (delta append, tombstones)."""
+def _slot_rows(table, n_slots: int):
+    """A flat slot table (kernel postings, kNN embeddings and masks) as
+    ``[n_slots, rows a slot, width]``, to set whole slots of it by index
+    (delta append, tombstones)."""
     return table.reshape(n_slots, table.shape[0] // n_slots,
                          table.shape[1])
+
+
+def place_slots(shard_ids: List[int], n_dev: int,
+                spd: Optional[int] = None) -> Tuple[List[int], int]:
+    """Where a generation's (shard, segment) pairs live: the slot of
+    each, in order, and the slots a device holds (``spd``, at least the
+    balanced ``ceil(pairs / n_dev)``; more leaves every device headroom
+    of its own). Device ``d`` owns slots ``[d * spd, (d + 1) * spd)``,
+    as ``shard_map`` splits the leading axis.
+
+    A shard's segments live on its device, ``shard id mod n_dev``, as a
+    data node holds its shards whole; only once that device holds the
+    balanced count does a segment go to the device holding the fewest,
+    so no device runs more passes a query than an even packing would.
+    On a device, slots fill in order. With one device pair ``i`` is slot
+    ``i``."""
+    cap = max(1, -(-len(shard_ids) // n_dev))
+    spd = max(cap, spd or 0)
+    load = [0] * n_dev
+    slots = []
+    for sid in shard_ids:
+        d = sid % n_dev
+        if load[d] >= cap:
+            d = load.index(min(load))
+        slots.append(d * spd + load[d])
+        load[d] += 1
+    return slots, spd
+
+
+def _append_slots(occupied: List[int], shard_ids: List[int], n_dev: int,
+                  spd: int) -> Optional[List[int]]:
+    """Free slots for a refresh's new segments, one a shard id: the
+    first free one on the shard's own device, else on the device with
+    the most free; None where the generation has too few."""
+    taken = set(occupied)
+    free = [[s for s in range(d * spd, (d + 1) * spd) if s not in taken]
+            for d in range(n_dev)]
+    out = []
+    for sid in shard_ids:
+        home = free[sid % n_dev]
+        pool = home if home else max(free, key=len)
+        if not pool:
+            return None
+        out.append(pool.pop(0))
+    return out
 
 
 class MeshPlanExecutor:
@@ -3173,10 +3234,12 @@ class MeshPlanExecutor:
     ``slots_per_dev = ceil(N / n_dev)`` slots in the stacked leading axis
     and the per-device program unrolls its slots — a realistically-
     refreshed index (many NRT segments per shard) stays on the mesh plane
-    instead of silently falling back to the host path. A slot with no
-    live document (padding, delta-staging headroom, a segment deleted
-    whole) has an all-false ``live1`` row: the serial program branches
-    around its pass, the batched and kNN programs mask its result."""
+    instead of silently falling back to the host path. Which slot a
+    segment takes is ``place_slots``': a shard's segments on its own
+    device. A slot with no live document (padding, delta-staging
+    headroom, a segment deleted whole) has an all-false ``live1`` row:
+    the serial program branches around its pass, the batched and kNN
+    programs mask its result."""
 
     _SCOPE_SEQ = itertools.count(1)
 
@@ -3184,7 +3247,8 @@ class MeshPlanExecutor:
                  postings_codec: Optional[str] = None,
                  index_name: Optional[str] = None,
                  stage_reason: str = "initial",
-                 slots_per_dev: Optional[int] = None):
+                 slots_per_dev: Optional[int] = None,
+                 shard_ids: Optional[List[int]] = None):
         from elasticsearch_tpu.parallel.distributed import stack_shard_arrays
         from elasticsearch_tpu.parallel.mesh import shard_mesh
 
@@ -3197,12 +3261,13 @@ class MeshPlanExecutor:
         # first-queries must never share a scope id)
         self.index_name = index_name or "_unassigned"
         self.scope = f"mesh#{next(self._SCOPE_SEQ)}"
-        # (shard_id, segment) per slot — owned by THIS generation so a
-        # query that pinned an executor never reads a concurrently
-        # restaged pair list (IndexMeshSearch._ensure_staged overwrites
-        # with the real shard ids; the positional default serves direct
-        # constructions in tests/bench)
-        self.pairs: List[Tuple[int, object]] = list(enumerate(segments))
+        # (shard_id, segment) of each segment — owned by THIS generation
+        # so a query that pinned an executor never reads a concurrently
+        # restaged pair list (a direct construction without shard ids,
+        # in tests and benches, gives each segment a shard of its own)
+        if shard_ids is None:
+            shard_ids = list(range(len(segments)))
+        self.pairs: List[Tuple[int, object]] = list(zip(shard_ids, segments))
         # armed by the owner via make_evictable AFTER install — a
         # generation under construction is deliberately not evictable
         self._evict_cb = None
@@ -3222,11 +3287,10 @@ class MeshPlanExecutor:
         # stage as dead rows (all-zero live masks) and give incremental
         # refreshes free capacity to delta-append into without a
         # geometry rebuild
-        self.slots_per_dev = max(1, -(-len(segments) // self.n_dev))
-        if slots_per_dev is not None:
-            self.slots_per_dev = max(self.slots_per_dev,
-                                     int(slots_per_dev))
+        self.slots, self.slots_per_dev = place_slots(
+            shard_ids, self.n_dev, slots_per_dev)
         self.n_slots = self.slots_per_dev * self.n_dev
+        self._index_slots()
         # set by release(): a query pinned to a replaced generation may
         # still lazily stage tables — those must NOT re-register under
         # the already-released ledger scope (see _account)
@@ -3236,7 +3300,7 @@ class MeshPlanExecutor:
         # re-registration would misclassify as a restage)
         self._kernel_stage_lock = threading.Lock()
         t0 = _time.monotonic()
-        stacked = stack_shard_arrays(segments, self.n_slots)
+        stacked = stack_shard_arrays(segments, self.n_slots, self.slots)
         self.nd_pad = stacked.pop("nd_pad")
         self.nd1 = self.nd_pad + 1
         sharding = NamedSharding(self.mesh, PS("shards"))
@@ -3343,6 +3407,25 @@ class MeshPlanExecutor:
     # Delta staging (ISSUE 20): incremental append + tombstone deletes
     # ------------------------------------------------------------------
 
+    def _index_slots(self) -> None:
+        """What the placement (``slots``) answers a launch: the pair in
+        each slot (None where none lives), and the devices that hold
+        one."""
+        self.pair_of_slot: List[Optional[Tuple[int, object]]] = \
+            [None] * self.n_slots
+        for slot, pair in zip(self.slots, self.pairs):
+            self.pair_of_slot[slot] = pair
+        self.live_devices = sum(1 for n in self.live_slots_per_device()
+                                if n)
+
+    def live_slots_per_device(self) -> List[int]:
+        """Slots holding a segment, device by device: the passes each
+        device's program pays for a query."""
+        out = [0] * self.n_dev
+        for slot in self.slots:
+            out[slot // self.slots_per_dev] += 1
+        return out
+
     def free_slots(self) -> int:
         """Unoccupied slots in this generation (the append headroom)."""
         return self.n_slots - len(self.segments)
@@ -3417,6 +3500,11 @@ class MeshPlanExecutor:
         new_segs = [seg for _sid, seg in append_pairs]
         if not cls.delta_append_compatible(old, new_segs):
             raise _DeltaIneligible("segment set cannot delta-append")
+        # each into a free slot on its shard's own device where it has one
+        new_slots = _append_slots(old.slots, [sid for sid, _ in append_pairs],
+                                  old.n_dev, old.slots_per_dev)
+        if new_slots is None:
+            raise _DeltaIneligible("no free slot for a new segment")
         self = cls.__new__(cls)
         self.mesh = old.mesh
         self.n_dev = old.n_dev
@@ -3424,6 +3512,7 @@ class MeshPlanExecutor:
         self.scope = f"mesh#{next(cls._SCOPE_SEQ)}"
         self.segments = list(old.segments) + new_segs
         self.pairs = list(old.pairs) + list(append_pairs)
+        self.slots = list(old.slots) + new_slots
         self._evict_cb = None
         # lazy stagings AFTER install classify as refresh (the segment
         # set did change); the construction below registers its delta
@@ -3434,6 +3523,7 @@ class MeshPlanExecutor:
         self.postings_codec = old.postings_codec
         self.slots_per_dev = old.slots_per_dev
         self.n_slots = old.n_slots
+        self._index_slots()
         self.nd_pad = old.nd_pad
         self.nd1 = old.nd1
         self._sharding = old._sharding
@@ -3449,8 +3539,6 @@ class MeshPlanExecutor:
 
         t0 = _time.monotonic()
         base = old._seg_staged
-        first_new = len(old.segments)
-        new_slots = list(range(first_new, len(self.segments)))
         # live-mask rows refresh for appended slots AND tombstoned ones
         live_slots = sorted(set(refresh_slots)) + new_slots
         nd_pad = self.nd_pad
@@ -3479,7 +3567,7 @@ class MeshPlanExecutor:
             nm_rows[j, :, nd_pad] = 1.0
         lv_rows = np.zeros((len(live_slots), nd_pad + 1), bool)
         for j, slot in enumerate(live_slots):
-            seg = self.segments[slot]
+            seg = self.pair_of_slot[slot][1]
             lv_rows[j, : seg.live.shape[0]] = seg.live
         idx_new = jnp.asarray(np.asarray(new_slots, np.int32))
         idx_live = jnp.asarray(np.asarray(live_slots, np.int32))
@@ -3511,16 +3599,13 @@ class MeshPlanExecutor:
 
             geom, codec = kernel["geom"], kernel["codec"]
             k_key = "k_packed" if codec == "packed" else "k_docs"
-            # the flat table's rows per slot: the new slots are
-            # consecutive, so their rows are ONE range of it
             n_rows = int(base[k_key].shape[0]) // self.n_slots
             meta = dict(kernel["meta"])
 
             def with_new_slots(table, rows):
                 return jax.device_put(
-                    jax.lax.dynamic_update_slice(
-                        table, jnp.asarray(rows.reshape(-1, psc.LANE)),
-                        (jnp.int32(first_new * n_rows), jnp.int32(0))),
+                    _slot_rows(table, self.n_slots).at[idx_new].set(
+                        jnp.asarray(rows)).reshape(table.shape),
                     self._sharding)
 
             if codec == "packed":
@@ -3572,7 +3657,7 @@ class MeshPlanExecutor:
                     (len(live_slots), g.n_tiles * psc.LANE, g.tile_sub),
                     np.float32)
                 for j, slot in enumerate(live_slots):
-                    seg = self.segments[slot]
+                    seg = self.pair_of_slot[slot][1]
                     live = np.zeros(g.nd_pad, np.float32)
                     live[: seg.nd_pad] = seg.live.astype(np.float32)
                     lt_rows[j] = psc.build_live_t(live, g)
@@ -3612,7 +3697,7 @@ class MeshPlanExecutor:
                     pkn.vector_scale_column(col.vectors, entry["metric"])
             mk_rows = np.zeros((len(live_slots), nd_knn, 1), np.float32)
             for j, slot in enumerate(live_slots):
-                seg = self.segments[slot]
+                seg = self.pair_of_slot[slot][1]
                 col = seg.vector_columns.get(field)
                 if col is not None:
                     mk_rows[j, : col.vectors.shape[0], 0] = \
@@ -3620,7 +3705,7 @@ class MeshPlanExecutor:
 
             def with_slots(table, idx, rows):
                 return jax.device_put(
-                    _knn_slot_rows(table, self.n_slots).at[idx].set(
+                    _slot_rows(table, self.n_slots).at[idx].set(
                         jnp.asarray(rows)).reshape(table.shape),
                     self._sharding)
 
@@ -3716,7 +3801,7 @@ class MeshPlanExecutor:
             nd_pad = self.nd_pad
             lv_rows = np.zeros((len(slots), nd_pad + 1), bool)
             for j, slot in enumerate(slots):
-                seg = self.segments[slot]
+                seg = self.pair_of_slot[slot][1]
                 lv_rows[j, : seg.live.shape[0]] = seg.live
             updates = {"live1": jax.device_put(
                 self._seg_staged["live1"].at[idx].set(
@@ -3735,7 +3820,7 @@ class MeshPlanExecutor:
                         (len(slots), g.n_tiles * psc.LANE, g.tile_sub),
                         np.float32)
                     for j, slot in enumerate(slots):
-                        seg = self.segments[slot]
+                        seg = self.pair_of_slot[slot][1]
                         live = np.zeros(g.nd_pad, np.float32)
                         live[: seg.nd_pad] = seg.live.astype(np.float32)
                         lt[j] = psc.build_live_t(live, g)
@@ -3751,7 +3836,7 @@ class MeshPlanExecutor:
                 nd_knn = entry["nd_pad"]
                 mk = np.zeros((len(slots), nd_knn, 1), np.float32)
                 for j, slot in enumerate(slots):
-                    seg = self.segments[slot]
+                    seg = self.pair_of_slot[slot][1]
                     col = seg.vector_columns.get(field)
                     if col is not None:
                         mk[j, : col.vectors.shape[0], 0] = \
@@ -3761,7 +3846,7 @@ class MeshPlanExecutor:
                 knn_updates[field] = dict(
                     entry,
                     mask=jax.device_put(
-                        _knn_slot_rows(entry["mask"], self.n_slots)
+                        _slot_rows(entry["mask"], self.n_slots)
                         .at[idx].set(jnp.asarray(mk))
                         .reshape(entry["mask"].shape), self._sharding),
                     slot_live=slot_live,
@@ -3902,7 +3987,7 @@ class MeshPlanExecutor:
                 (self.n_slots, geom.n_tiles * psc.LANE, geom.tile_sub),
                 np.float32)
             meta = {}
-            for i, seg in enumerate(self.segments):
+            for i, seg in zip(self.slots, self.segments):
                 f = seg._block_frac()
                 bmin, bmax = psc.block_min_max(
                     seg.block_docs, seg.block_tfs, seg.nd_pad)
@@ -4060,7 +4145,7 @@ class MeshPlanExecutor:
         emb = np.zeros((self.n_slots, nd_knn, d_pad), ml_dtypes.bfloat16)
         scale = np.zeros((self.n_slots, nd_knn, 1), np.float32)
         mask = np.zeros((self.n_slots, nd_knn, 1), np.float32)
-        for i, seg in enumerate(self.segments):
+        for i, seg in zip(self.slots, self.segments):
             col = seg.vector_columns.get(field)
             if col is None:
                 continue  # slot stays dead (mask all-zero)
@@ -4154,7 +4239,7 @@ class MeshPlanExecutor:
             live_t = np.zeros(
                 (self.n_slots, geom.n_tiles * psc.LANE, geom.tile_sub),
                 np.float32)
-            for i, seg in enumerate(self.segments):
+            for i, seg in zip(self.slots, self.segments):
                 live = np.zeros(geom.nd_pad, np.float32)
                 live[: seg.nd_pad] = seg.live.astype(np.float32)
                 live_t[i] = psc.build_live_t(live, geom)
@@ -4270,7 +4355,7 @@ class MeshPlanExecutor:
         def build():
             out = np.full((self.n_slots, self.nd1), SORTABLE_MISSING,
                           np.int64)
-            for i, c in enumerate(cols):
+            for i, c in zip(self.slots, cols):
                 if c is not None:
                     n = c.exists.shape[0]
                     out[i, :n] = np.where(
@@ -4318,7 +4403,7 @@ class MeshPlanExecutor:
             big = np.float32(3.0e38)
             keys = np.zeros((self.n_slots, self.nd1), np.float32)
             raws = np.zeros((self.n_slots, self.nd1), np.float32)
-            for i, (seg, raw) in enumerate(zip(self.segments, per_seg)):
+            for i, seg, raw in zip(self.slots, self.segments, per_seg):
                 key = np.clip(raw if order == "desc" else -raw, -big, big)
                 keys[i, : seg.nd_pad] = key.astype(np.float32)
                 keys[i, seg.nd_pad:] = -big  # padding never outranks real docs
@@ -4397,7 +4482,6 @@ class MeshPlanExecutor:
                 for r, c in zip(raws, cols)], vocab
 
     def ensure_slice_column(self, slice_spec: dict,
-                            shard_of_device: List[int],
                             num_shards: int) -> Optional[str]:
         """Stage the deterministic slice doc partition as a boolean mask
         column, shard-aware like the host path (SliceBuilder.toFilter's
@@ -4412,9 +4496,8 @@ class MeshPlanExecutor:
         if name in self._seg_staged:
             return name
         out = np.zeros((self.n_slots, self.nd1), bool)
-        for i, seg in enumerate(self.segments):
-            resolved = resolve_slice(slice_spec, shard_of_device[i],
-                                     num_shards)
+        for i, (shard, seg) in zip(self.slots, self.pairs):
+            resolved = resolve_slice(slice_spec, shard, num_shards)
             if resolved == "skip":
                 continue  # all-False row
             if resolved is None:
@@ -4524,19 +4607,20 @@ class MeshPlanExecutor:
             raise ValueError("one plan per staged shard required")
         t_stage = tracer.start("staging")
         local_pads = [s.nd_pad for s in self.segments]
-        stacked = stack_plans(plans, local_pads, self.nd1, self.n_slots)
+        stacked = stack_plans(plans, local_pads, self.nd1, self.n_slots,
+                              self.slots)
         key_parts = [plans[0].key(), _shapes_sig(stacked)]
         stacked_pf: List[np.ndarray] = []
         stacked_rs: List[np.ndarray] = []
         pf_tpl = rs_tpl = None
         if pf_plans:
             stacked_pf = stack_plans(pf_plans, local_pads, self.nd1,
-                                     self.n_slots)
+                                     self.n_slots, self.slots)
             pf_tpl = _strip_plan(pf_plans[0])
             key_parts += ["pf:" + pf_plans[0].key(), _shapes_sig(stacked_pf)]
         if rs_plans:
             stacked_rs = stack_plans(rs_plans, local_pads, self.nd1,
-                                     self.n_slots)
+                                     self.n_slots, self.slots)
             rs_tpl = _strip_plan(rs_plans[0])
             key_parts += ["rs:" + rs_plans[0].key(), _shapes_sig(stacked_rs)]
         key = ("|".join(key_parts)

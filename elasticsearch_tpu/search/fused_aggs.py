@@ -281,9 +281,10 @@ def _metric_field_checks(executor, field: str) -> dict:
 
 def _build_bucket_codes(executor, per_seg_codes) -> np.ndarray:
     """[n_slots, nd1] int32 codes column from per-segment local code
-    arrays (length seg.nd_pad, -1 = no value)."""
+    arrays (length seg.nd_pad, -1 = no value), each at its segment's
+    slot."""
     out = np.full((executor.n_slots, executor.nd1), -1, np.int32)
-    for i, codes in enumerate(per_seg_codes):
+    for i, codes in zip(executor.slots, per_seg_codes):
         if codes is not None:
             out[i, : codes.shape[0]] = codes
     return out
@@ -550,7 +551,7 @@ def _resolve_metric(spec, executor, ops, metas, builds) -> Optional[str]:
                 if base + ".dig" in names:
                     out[base + ".dig"] = np.zeros(
                         (n_slots, nd1, N_DIGITS), np.int16)
-                for i, c in enumerate(cols):
+                for i, c in zip(executor.slots, cols):
                     if c is None:
                         continue
                     n = c.exists.shape[0]
@@ -639,10 +640,10 @@ def resolve_fused_aggs(specs: List[AggSpec], executor, tracer=NULL_TRACER
 
 
 def finalize_fused(plan: FusedAggPlan, outs: List[np.ndarray],
-                   n_real: int) -> dict:
+                   real: List[int]) -> dict:
     """Reduce the program's per-slot partials (``outs``: one
-    [n_slots, ...] array per ``n_agg_outputs`` entry, only the first
-    ``n_real`` slot rows are staged segments) into the response dict —
+    [n_slots, ...] array per ``n_agg_outputs`` entry, of whose rows those
+    at the slots ``real`` are staged segments) into the response dict —
     byte-identical to the host oracle by the module's exactness
     contract (integer counts, bignum sum reconstruction, lexicographic
     min/max merge, shared bucket assembly)."""
@@ -660,8 +661,8 @@ def finalize_fused(plan: FusedAggPlan, outs: List[np.ndarray],
                 result[spec.name] = _finalize_metric(spec, [])
             continue
         if op[0] == "bucket":
-            counts = np.asarray(outs[pos][:n_real],
-                                np.int64).sum(axis=0)
+            counts = np.asarray(outs[pos],
+                                np.int64)[real].sum(axis=0)
             pos += 1
             if kind == "terms":
                 vocab = meta["vocab"]
@@ -687,11 +688,11 @@ def finalize_fused(plan: FusedAggPlan, outs: List[np.ndarray],
             continue
         # metric
         _, _base, want_mm, want_dig = op
-        count = int(np.asarray(outs[pos][:n_real], np.int64).sum())
+        count = int(np.asarray(outs[pos], np.int64)[real].sum())
         pos += 1
         vmin, vmax, total = math.inf, -math.inf, 0.0
         if want_mm:
-            mm = np.asarray(outs[pos][:n_real], np.float64)
+            mm = np.asarray(outs[pos], np.float64)[real]
             pos += 1
             # lexicographic (hi, lo) merge across slots; empty slots
             # carry inf/-inf sentinels and drop here
@@ -704,7 +705,7 @@ def finalize_fused(plan: FusedAggPlan, outs: List[np.ndarray],
                 h, l = max(maxs)
                 vmax = float(h) * MM_SPLIT + float(l)
         if want_dig:
-            digs = np.asarray(outs[pos][:n_real], np.int64)
+            digs = np.asarray(outs[pos], np.int64)[real]
             pos += 1
             tot_u = 0
             for k in range(N_DIGITS):

@@ -166,13 +166,15 @@ class TestDeltaAppend:
             assert after.shape == before.shape  # no geometry rebuild
             first_new = len(old.segments)
             assert len(new.segments) > first_new
+            assert new.slots[:first_new] == old.slots
             # every old slot's rows, and the still-free slots', are as
             # they were; each new slot's rows are its segment's table
             keep = np.ones(after.shape[0], bool)
-            keep[first_new * rows: len(new.segments) * rows] = False
+            for slot in new.slots[first_new:]:
+                keep[slot * rows: (slot + 1) * rows] = False
             np.testing.assert_array_equal(after[keep], before[keep])
-            for slot in range(first_new, len(new.segments)):
-                seg = new.segments[slot]
+            for slot, seg in zip(new.slots[first_new:],
+                                 new.segments[first_new:]):
                 f = seg._block_frac()
                 if codec == "packed":
                     want = psc.pack_segment_blocks(seg.block_docs, f,

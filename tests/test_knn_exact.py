@@ -138,7 +138,7 @@ class _Served:
         rows = np.asarray(entry["emb"].astype(jnp.float32)).reshape(
             ex.n_slots, entry["nd_pad"], -1)
         return {(sid, seg.doc_ids[d]): rows[slot, d]
-                for slot, (sid, seg) in enumerate(ex.pairs)
+                for slot, (sid, seg) in zip(ex.slots, ex.pairs)
                 for d in range(seg.num_docs)}
 
     def planes(self):
