@@ -75,7 +75,8 @@ _plane_logger = logging.getLogger("elasticsearch_tpu.parallel.plane")
 # serialized process-wide, which makes concurrent search traffic safe
 # everywhere. What that costs on TPU is not measured until a concurrent
 # cell reads ``kernel.lock_wait`` (the lock is held through completion,
-# so a second query cannot even enqueue). Staging stays unlocked.
+# which the copy of the outputs a caller reads completes, so a second
+# query cannot even enqueue). Staging stays unlocked.
 _MESH_EXEC_LOCK = threading.Lock()
 
 # one integer per mesh launch, process-wide, taken under the lock: ids
@@ -83,19 +84,30 @@ _MESH_EXEC_LOCK = threading.Lock()
 _LAUNCH_IDS = itertools.count(1)
 
 
-def _launch_locked(tracer, run, *args):
+def _launch_locked(tracer, run, *args, reads=None, telemetry=None):
     """One mesh program under ``_MESH_EXEC_LOCK``, the one launch site
-    of all five programs: the ``kernel`` span and what it is made of —
-    ``kernel.lock_wait`` (asking for the lock to holding it),
-    ``kernel.dispatch`` (the jitted call until it returns: enqueue, and
-    trace + compile on a first call, marked ``first_call``; the launch's
-    id, marked ``launch``, by which ``profile.annotations.launches``
-    puts its edges beside the device's ops in a trace) and
-    ``kernel.device_wait`` (``block_until_ready``). Dispatch is async:
-    the collectives execute after ``run`` returns, so completion must
-    happen INSIDE the lock (callers fetch the results at once anyway).
-    (Asking for the host copies here, ``copy_to_host_async`` before the
-    wait, was measured and left out: 0.11 ms of 8.0, PERF.md 6 PR 30.)"""
+    of all five programs, and the one wait of a launch: the ``kernel``
+    span and what it is made of — ``kernel.lock_wait`` (asking for the
+    lock to holding it), ``kernel.dispatch`` (the jitted call until it
+    returns: enqueue, and trace + compile on a first call, marked
+    ``first_call``; the launch's id, marked ``launch``, by which
+    ``profile.annotations.launches`` puts its edges beside the device's
+    ops in a trace) and ``kernel.device_wait`` (ONE ``jax.device_get``
+    of the outputs the caller reads: every copy is asked for before the
+    first is waited for, and their arrival is the program's completion).
+    Dispatch is async: the collectives execute after ``run`` returns, so
+    completion must happen INSIDE the lock, and the fetch completes it.
+    (A wait for completion and then a fetch of its own paid two
+    completion notices a launch: docs/MESH.md.)
+
+    reads: the indices, in the program's tuple of outputs, of those the
+    caller reads; None: all of them, or the one array a program returns.
+    Returns them as numpy: the one array, or a tuple as long as the
+    program's with None for each output not read, which is never
+    copied. Counts, once a launch, the arrays copied (``d2h_arrays``)
+    and the launch (``fetched_launches``): over ``kernel``'s count, the
+    arrays a launch fetched and the share of launches that waited once
+    (``search.phases.counters``)."""
     t_kernel = tracer.start_parent("kernel")
     try:
         first_call = getattr(run, "first_call_pending", bool)()
@@ -106,28 +118,20 @@ def _launch_locked(tracer, run, *args):
             tracer.mark(t_dispatch, "launch", next(_LAUNCH_IDS))
             outs = run(*args)
             t = tracer.switch("kernel.dispatch", t, "kernel.device_wait")
-            jax.block_until_ready(outs)
+            if reads is not None:
+                outs = tuple(out if i in reads else None
+                             for i, out in enumerate(outs))
+            outs = jax.device_get(outs)
         tracer.stop("kernel.device_wait", t)
         if first_call:
             tracer.mark(t_dispatch, "first_call", True)
     finally:
         tracer.stop("kernel", t_kernel)
-    return outs
-
-
-def _fetch(tracer, outs, telemetry):
-    """A program's output(s) as numpy: ONE array or a sequence of them,
-    every copy to the host asked for before the first is waited for
-    (``jax.device_get``), as the ``merge.d2h`` span. Counts the arrays
-    copied (``search.phases.counters`` ``d2h_arrays_total``): over
-    ``merge.d2h``'s count, the arrays fetched a query."""
-    t = tracer.start("merge.d2h")
-    arrays = jax.device_get(outs)
-    tracer.stop("merge.d2h", t)
     if telemetry is not None:
-        telemetry.add_counters({"d2h_arrays": (
-            1 if isinstance(arrays, np.ndarray) else len(arrays))})
-    return arrays
+        telemetry.add_counters({
+            "d2h_arrays": len(jax.tree_util.tree_leaves(outs)),
+            "fetched_launches": 1})
+    return outs
 
 
 def _pack_answer(keys, slots, docs, total, scores, raws):
@@ -2047,7 +2051,10 @@ class IndexMeshSearch:
                 # deadline before committing to the launch
                 deadline.checkpoint()
             on_kernel_launch(self.svc.name, "knn")
-            outs = _launch_locked(bt, run, *args)
+            # (the batch's answers are ONE array, a row a query: one
+            # transfer, inside the launch's one wait)
+            packed = _launch_locked(bt, run, *args,
+                                    telemetry=self._telemetry)
         except (PlanStructureMismatch, NotImplementedError):
             self._note("mesh_pallas", "shape_mismatch", q_batch)
             return None  # shape ineligibility: next rung, no penalty
@@ -2087,8 +2094,6 @@ class IndexMeshSearch:
                 launch_adds["embedding_bytes_streamed"]
         # (no finally: an exception in here ends the request)
         t_merge = bt.start_parent("merge")
-        # (the batch's answers are ONE array, a row a query: one transfer)
-        packed = _fetch(bt, outs, self._telemetry)
         t_assemble = bt.start("merge.assemble")
         results = []
         for q in range(q_batch):
@@ -2491,7 +2496,8 @@ class IndexMeshSearch:
                         rescore_static=rescore_static, tracer=tracer,
                         agg_static=(agg_plan.statics
                                     if agg_plan is not None else ()),
-                        telemetry=self._telemetry)
+                        telemetry=self._telemetry,
+                        read_counts=bool(terminate_after))
                     # the plane served: fully re-open it (ends a probe's
                     # quarantine — single-flight contract)
                     self.plane_health.note_success(plane)
@@ -2525,9 +2531,9 @@ class IndexMeshSearch:
             return None
         # (no finally: an exception in here ends the request)
         t_merge = tracer.start_parent("merge")
-        # (the answer is ONE array, the total in it: one transfer)
-        packed = _fetch(tracer, outs[0], self._telemetry)
-        seg_counts = outs[1]
+        # (the answer is ONE array, the total in it: the launch fetched
+        # it, and what else this request reads, in its one wait)
+        packed, seg_counts = outs[0], outs[1]
         t_assemble = tracer.start("merge.assemble")
         keys, slots, docs, total, scores, raws = _unpack_answer(packed)
         total = int(total)
@@ -2538,10 +2544,9 @@ class IndexMeshSearch:
         terminated_early = None
         if terminate_after:
             ta = int(terminate_after)
-            counts = np.asarray(seg_counts)
             by_shard: Dict[int, int] = {}
             for slot, (sid, _seg) in zip(executor.slots, executor.pairs):
-                by_shard[sid] = by_shard.get(sid, 0) + int(counts[slot])
+                by_shard[sid] = by_shard.get(sid, 0) + int(seg_counts[slot])
             total = sum(min(c, ta) for c in by_shard.values())
             terminated_early = any(c >= ta for c in by_shard.values())
         with self._counter_lock:
@@ -2597,15 +2602,12 @@ class IndexMeshSearch:
         tracer.stop("merge", t_merge)
         aggregations = None
         if agg_specs:
-            # ``aggregate.fetch``: what the reduce reads of the program's
-            # outputs, device to host (the fused partials, or the masks
-            # and scores of the host fallback), every copy asked for
-            # before the first is waited for; ``aggregate.finalize``: the
-            # reduce on the host
+            # ``aggregate.finalize``: the reduce on the host, over what
+            # the launch fetched of the program's outputs (the fused
+            # partials, or the masks and scores of the host fallback)
             t_agg = tracer.start_parent("aggregate")
-            t = tracer.start("aggregate.fetch")
-            agg_outs = jax.device_get(list(outs[2:]))
-            t = tracer.switch("aggregate.fetch", t, "aggregate.finalize")
+            t = tracer.start("aggregate.finalize")
+            agg_outs = list(outs[2:])
             if agg_plan is not None:
                 from elasticsearch_tpu.search.fused_aggs import (
                     finalize_fused,
@@ -2979,9 +2981,9 @@ class IndexMeshSearch:
                     # honor the deadline before committing to the launch
                     deadline.checkpoint()
                 on_kernel_launch(self.svc.name, "pruned")
-                outs = _launch_locked(bt, run, *args)
-                keys, docs, slots, totals, scored, tiles_total = _fetch(
-                    bt, outs, self._telemetry)
+                keys, docs, slots, totals, scored, tiles_total = \
+                    _launch_locked(bt, run, *args,
+                                   telemetry=self._telemetry)
                 pruned_stats = {
                     "tiles_scored": int(scored),
                     "tiles_pruned": int(tiles_total) - int(scored),
@@ -3026,9 +3028,8 @@ class IndexMeshSearch:
                 if deadline is not None:
                     deadline.checkpoint()
                 on_kernel_launch(self.svc.name, "batched")
-                outs = _launch_locked(bt, run, *args)
-                keys, docs, slots, totals, *agg_raw = _fetch(
-                    bt, outs, self._telemetry)
+                keys, docs, slots, totals, *agg_raw = _launch_locked(
+                    bt, run, *args, telemetry=self._telemetry)
                 wb = 4 if codec == "packed" else 8
                 launch_adds = {
                     "postings_bytes_streamed":
@@ -3049,9 +3050,8 @@ class IndexMeshSearch:
                 if deadline is not None:
                     deadline.checkpoint()
                 on_kernel_launch(self.svc.name, "batched")
-                outs = _launch_locked(bt, run, *args)
-                keys, docs, slots, totals = _fetch(bt, outs,
-                                                   self._telemetry)
+                keys, docs, slots, totals = _launch_locked(
+                    bt, run, *args, telemetry=self._telemetry)
                 wb = 4 if codec == "packed" else 8
                 launch_adds = {
                     "postings_bytes_streamed":
@@ -4590,13 +4590,18 @@ class MeshPlanExecutor:
                 slice_col: Optional[str] = None,
                 rescore_static: Optional[Tuple[int, str]] = None,
                 tracer=NULL_TRACER, agg_static: tuple = (),
-                telemetry=None):
-        """plans: one per shard, same query. Returns (packed int32
-        [2 + 5k] — ``_unpack_answer`` gives top_keys [k], top_slot [k],
-        top_doc [k], total, top_score [k], top_raw [k] —, counts
+                telemetry=None, read_counts: bool = True):
+        """plans: one per shard, same query. Returns, as numpy, (packed
+        int32 [2 + 5k] — ``_unpack_answer`` gives top_keys [k], top_slot
+        [k], top_doc [k], total, top_score [k], top_raw [k] —, counts
         [n_slots] [, matched [n_slots, nd1], scores [n_slots, nd1]]
         [, fused-agg partials...]) — doc ids are in the STACKED doc
         space (valid per-shard ids since every shard zero-bases).
+        read_counts: whether the caller reads ``counts`` (only
+        ``terminate_after`` does); where it does not, ``counts`` is
+        never copied to the host and comes back None. The views and
+        partials exist only where the caller asked for them, and are
+        read.
 
         pf_plans / rs_plans: optional per-shard post_filter and rescore
         query plans; scalars: traced values for `features` and rescore
@@ -4666,7 +4671,14 @@ class MeshPlanExecutor:
         # and compiled again after a later request staged a column.
         seg = {name: a for name, a in self._seg_staged.items()
                if name in wanted or not name.startswith(_ON_DEMAND)}
-        outs = _launch_locked(tracer, run, seg, packed, loose)
+        n_out = 2 + (2 if with_views else 0)
+        if agg_static:
+            from elasticsearch_tpu.search.fused_aggs import n_agg_outputs
+
+            n_out += n_agg_outputs(agg_static)
+        reads = (0, *((1,) if read_counts else ()), *range(2, n_out))
+        outs = _launch_locked(tracer, run, seg, packed, loose, reads=reads,
+                              telemetry=telemetry)
         if telemetry is not None:
             # host arrays the launch carried (the pack, and what could
             # not go in); occupied slots over all: the share a query

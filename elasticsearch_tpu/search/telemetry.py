@@ -61,9 +61,10 @@ from typing import Dict, List, Optional
 #   parse_rewrite  query DSL parse + coordinator rewrites
 #   plan_build     per-shard plan / kernel lane-table construction
 #   staging        host->device transfer of plan arrays / union tables
-#   kernel         device program dispatch -> block_until_ready
-#                  (includes first-call compilation; fused on-device
-#                  agg reduction executes inside this span)
+#   kernel         device program dispatch -> the launch's one wait,
+#                  the copy of the outputs its caller reads (includes
+#                  first-call compilation; fused on-device agg
+#                  reduction executes inside this span)
 #   merge          ICI/host top-k merge + DocRef assembly
 #   aggregate      aggregation reduce OUTSIDE the device program: the
 #                  host-path agg execution over segment views, the mesh

@@ -466,7 +466,6 @@ KNN_SPAN_PARENTS = {
     "kernel.dispatch": "kernel",
     "kernel.device_wait": "kernel",
     "merge": "search.request",
-    "merge.d2h": "merge",
     "merge.assemble": "merge",
     "batch_demux": "search.request",
     "fetch": "search.request",
@@ -514,10 +513,12 @@ def test_the_knn_requests_span_tree_has_the_lexical_shape(monkeypatch):
             "total"]["search"]["spans"]
         assert spans["staging.knn_embeddings"]["count"] == 1
         assert spans["kernel.device_wait"]["count"] == 2
-        assert spans["merge.d2h"]["count"] == 2
+        assert "merge.d2h" not in spans
         counters = served.call("GET", "/vec/_stats")["indices"]["vec"][
             "total"]["search"]["phases"]["counters"]
-        assert counters["d2h_arrays_total"] == 2  # ONE fetch a query
+        # ONE array a query, fetched in the launch's one wait
+        assert counters["d2h_arrays_total"] == 2
+        assert counters["fetched_launches_total"] == spans["kernel"]["count"]
     finally:
         served.close()
 

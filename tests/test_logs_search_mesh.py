@@ -150,12 +150,12 @@ def test_what_a_request_needs_is_staged_once(pair):
     again = spans(mesh)
     assert again["staging.doc_values"] == seen["staging.doc_values"]
     assert again["staging.sort_column"] == seen["staging.sort_column"]
-    # the reduce's two leaves tile their parent: once an aggregating request
+    # the reduce is its finalize, once an aggregating request: what it
+    # reads the launch fetched, so no fetch of its own
     n = again["aggregate"]["count"]
-    assert again["aggregate.fetch"]["count"] == n
     assert again["aggregate.finalize"]["count"] == n
-    assert (again["aggregate.fetch"]["sum_ns"]
-            + again["aggregate.finalize"]["sum_ns"]
+    assert "aggregate.fetch" not in again
+    assert (again["aggregate.finalize"]["sum_ns"]
             <= again["aggregate"]["sum_ns"])
 
 

@@ -1,18 +1,23 @@
-"""A query's answer reaches the host in one transfer (ISSUE 30).
+"""A query's answer reaches the host in one transfer, inside the
+launch's one wait.
 
 The serial mesh program packs its merged answer (top-k keys, slots,
-docs, scores, raw sort values and the total) into ONE ``int32`` array,
-``_fetch`` makes one copy of it, and the host unpacks it into views.
-Held here:
+docs, scores, raw sort values and the total) into ONE ``int32`` array;
+``_launch_locked`` copies it, with whatever else the request reads of
+the program's outputs, in the one ``jax.device_get`` that also waits
+for the program, and the host unpacks it into views. Held here:
 
 - pack and unpack round-trip every bit (``-inf`` keys of unfilled
   ranks, negative zero, totals no float32 or int32 holds);
-- the serial path copies exactly one array a query, whatever the
-  request asks for beside its hits (``d2h_arrays_total`` over
-  ``merge.d2h``'s count is 1.0), and ``merge.d2h`` stays one span
-  under ``merge``;
-- ``_fetch`` adapts to what it is handed: one array, or several whose
-  copies are all asked for before the first is waited for.
+- a launch makes one ``jax.device_get`` and no ``block_until_ready``,
+  of one array or several, all or those the caller names; it counts
+  ``d2h_arrays_total`` and ``fetched_launches_total`` once a launch
+  (over ``kernel``'s count), and opens no ``merge.d2h`` span;
+- the serial path copies exactly the outputs a request reads: the
+  packed answer always, the per-slot counts only under
+  ``terminate_after``, the views or fused partials of its aggregations
+  in the same call;
+- a copy that fails is a plane fault, and the next rung serves.
 
 Kernel paths run in interpret mode on the CPU backend.
 """
@@ -25,8 +30,9 @@ import jax.numpy as jnp
 
 from elasticsearch_tpu.common.settings import Settings
 from elasticsearch_tpu.index.index_service import IndexService
+from elasticsearch_tpu.parallel import plan_exec
 from elasticsearch_tpu.parallel.plan_exec import (
-    _fetch,
+    _launch_locked,
     _pack_answer,
     _unpack_answer,
 )
@@ -97,21 +103,75 @@ def test_pack_refuses_a_row_it_would_have_to_convert(bad):
         jax.jit(_pack_answer)(**rows)
 
 
-# -- _fetch ---------------------------------------------------------------
+# -- _launch_locked: one wait a launch --------------------------------------
 
-def test_fetch_adapts_to_one_array_or_several():
-    tel = SearchTelemetry()
-    one = _fetch(NULL_TRACER, jnp.arange(7, dtype=jnp.int32), tel)
-    assert isinstance(one, np.ndarray) and one.tolist() == list(range(7))
-    assert tel.counters["d2h_arrays_total"] == 1
-    several = _fetch(NULL_TRACER, (jnp.zeros(3), jnp.ones((2, 2)),
-                                   jnp.int32(9)), tel)
-    assert [type(a) for a in several[:2]] == [np.ndarray, np.ndarray]
-    assert int(several[2]) == 9 and several[1].shape == (2, 2)
-    assert tel.counters["d2h_arrays_total"] == 4
-    tracer = QueryTracer()
-    _fetch(tracer, jnp.zeros(2), None)  # no telemetry: only the span
-    assert [r[0] for r in tracer.closed_spans()] == ["merge.d2h"]
+class _Waits:
+    """``plan_exec``'s ``jax``, every ``device_get`` (what it was handed)
+    and every ``block_until_ready`` recorded."""
+
+    def __init__(self, fail=0):
+        self.gets = []
+        self.blocks = 0
+        self.fail = fail  # the first ``fail`` copies raise
+
+    def __getattr__(self, name):
+        return getattr(jax, name)
+
+    def device_get(self, x):
+        self.gets.append(x)
+        if len(self.gets) <= self.fail:
+            raise RuntimeError("injected: the copy to the host failed")
+        return jax.device_get(x)
+
+    def block_until_ready(self, x):
+        self.blocks += 1
+        return jax.block_until_ready(x)
+
+
+LAUNCHES = {
+    # name: (program, reads, what comes back)
+    "one_array": (lambda x: x * 2, None, [[0, 2, 4, 6, 8, 10, 12]]),
+    "several_all": (lambda x: (x[:3] * 0, jnp.ones((2, 2)), x[:1].sum()),
+                    None, [[0, 0, 0], [[1.0, 1.0], [1.0, 1.0]], 0]),
+    "several_some": (lambda x: (x[:3], x * 0, x[-1] + 3), (0, 2),
+                     [[0, 1, 2], None, 9]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(LAUNCHES))
+def test_a_launch_waits_once_for_what_its_caller_reads(monkeypatch, case):
+    program, reads, want = LAUNCHES[case]
+    run = jax.jit(program)
+    x = jnp.arange(7, dtype=jnp.int32)
+    run(x)  # a first call compiles
+    waits = _Waits()
+    monkeypatch.setattr(plan_exec, "jax", waits)
+    tel, tracer = SearchTelemetry(), QueryTracer()
+    for _ in range(2):
+        got = _launch_locked(tracer, run, x, reads=reads, telemetry=tel)
+    assert len(waits.gets) == 2 and waits.blocks == 0
+    outs = [got] if case == "one_array" else list(got)
+    assert [None if o is None else np.asarray(o).tolist()
+            for o in outs] == want
+    assert all(o is None or isinstance(o, (np.ndarray, np.generic))
+               for o in outs)
+    # an output the caller does not read is never handed to the copy
+    handed = waits.gets[-1]
+    if reads is not None:
+        assert [i for i, o in enumerate(handed) if o is not None] == \
+            list(reads)
+    tel.record_spans(tracer)
+    kernels = tel.spans_dict()["kernel"]["count"]
+    copied = sum(o is not None for o in outs)
+    assert kernels == 2
+    assert tel.counters["d2h_arrays_total"] / kernels == copied
+    assert tel.counters["fetched_launches_total"] == kernels
+    assert {r[0] for r in tracer.closed_spans()} == {
+        "kernel", "kernel.lock_wait", "kernel.dispatch",
+        "kernel.device_wait"}
+    # no telemetry: the spans alone
+    _launch_locked(NULL_TRACER, run, x, reads=reads)
+    assert tel.counters["fetched_launches_total"] == kernels
 
 
 # -- the serial path, end to end -----------------------------------------
@@ -171,8 +231,9 @@ def _drained(monkeypatch):
 
 
 def _d2h(idx):
-    return (idx.telemetry.counters.get("d2h_arrays_total", 0),
-            idx.telemetry.spans_dict().get("merge.d2h", {}).get("count", 0))
+    c = idx.telemetry.counters
+    return (c.get("d2h_arrays_total", 0), c.get("fetched_launches_total", 0),
+            idx.telemetry.spans_dict().get("kernel", {}).get("count", 0))
 
 
 MATCH = {"match": {"body": "t0 t1 t2"}}
@@ -202,20 +263,37 @@ def test_serial_path_copies_one_array_a_query(index, monkeypatch, case,
         request.getfixturevalue("host_reduced_aggs")
     drained = _drained(monkeypatch)
     index.search(body)  # a first call compiles
-    arrays0, spans0 = _d2h(index)
+    arrays0, fetched0, kernels0 = _d2h(index)
     del drained[:]
+    waits = _Waits()
+    monkeypatch.setattr(plan_exec, "jax", waits)
     resp = index.search(body)
-    arrays1, spans1 = _d2h(index)
+    arrays1, fetched1, kernels1 = _d2h(index)
     assert resp["_plane"] in ("mesh", "mesh_pallas"), resp["_plane"]
-    assert spans1 - spans0 == 1
-    assert (arrays1 - arrays0) / (spans1 - spans0) == 1.0  # was 6.0
-    # ``merge.d2h`` is exactly one span, under ``merge``
+    assert kernels1 - kernels0 == 1
+    assert fetched1 - fetched0 == kernels1 - kernels0
+    # ONE copy a launch and no other wait: the packed answer (one array
+    # where there were six), the counts only under ``terminate_after``,
+    # and whatever the aggregations reduce, in the same call
+    assert waits.blocks == 0
+    (handed,) = waits.gets
+    read = [i for i, o in enumerate(handed) if o is not None]
+    n_aggs = {"views": 2, "fused_aggs": len(handed) - 2}.get(case, 0)
+    assert len(handed) == 2 + n_aggs
+    assert read == [0, *([1] if case == "terminate_after" else []),
+                    *range(2, 2 + n_aggs)]
+    if case == "fused_aggs":
+        assert n_aggs >= 1
+    assert (arrays1 - arrays0) / (kernels1 - kernels0) == len(read)
+    # no span of its own: ``merge`` holds the assembly alone
     (tracer,) = drained
     rows = {i: (name, parent) for name, _s, _e, parent, _self, _a, i
             in tracer.closed_spans()}
-    d2h = [parent for name, parent in rows.values() if name == "merge.d2h"]
-    assert len(d2h) == 1 and rows[d2h[0]][0] == "merge"
-    assert [n for n, _p in rows.values()].count("merge") == 1
+    names = [n for n, _p in rows.values()]
+    assert "merge.d2h" not in names and "aggregate.fetch" not in names
+    assert names.count("merge") == 1
+    assert {rows[p][0] for n, p in rows.values()
+            if n == "merge.assemble"} == {"merge"}
 
 
 @pytest.mark.parametrize("case", sorted(SERIAL_BODIES))
@@ -249,3 +327,33 @@ def test_batched_launch_counts_its_arrays_once(index):
     out = index.search_batch(bodies)
     assert all(r["_plane"] == "mesh_pallas" for r in out)
     assert index.telemetry.counters["d2h_arrays_total"] - before == 4
+
+
+def test_a_failed_copy_is_a_plane_fault_and_the_next_rung_serves(
+        monkeypatch):
+    """The copy is part of the launch: where it raises, the plane that
+    launched is benched as for any fault of its program, and the request
+    is answered by the next rung."""
+    idx = _build("packedfault")
+    host = _build("packedfaulthost", **{"index.search.mesh": False})
+    try:
+        body = SERIAL_BODIES["plain_top10"]
+        assert idx.search(body)["_plane"] == "mesh_pallas"
+        waits = _Waits(fail=1)
+        monkeypatch.setattr(plan_exec, "jax", waits)
+        got = idx.search(body)
+        assert len(waits.gets) == 2  # the kernel rung's, then the mesh's
+        assert got["_plane"] == "mesh"
+        decisions = idx.search_stats()["phases"]["decisions"]
+        assert decisions.get("mesh_pallas.fault") == 1
+        want = host.search(body)
+        assert got["hits"]["total"] == want["hits"]["total"]
+        # (the scatter rung sums a document's terms in another order
+        # than the host plane: its scores may differ in the last bit)
+        assert [h["_id"] for h in got["hits"]["hits"]] == \
+            [h["_id"] for h in want["hits"]["hits"]]
+        assert [h["_score"] for h in got["hits"]["hits"]] == pytest.approx(
+            [h["_score"] for h in want["hits"]["hits"]], rel=1e-6)
+    finally:
+        idx.close()
+        host.close()

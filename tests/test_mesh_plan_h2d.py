@@ -239,9 +239,9 @@ def test_a_warm_request_puts_nothing_and_the_launch_carries_its_arrays(
     launches = []
     launch = plan_exec._launch_locked
 
-    def recording(tracer, run, *args):
+    def recording(tracer, run, *args, **kwargs):
         launches.append((run, args))
-        return launch(tracer, run, *args)
+        return launch(tracer, run, *args, **kwargs)
 
     carried = []
     pack = plan_exec._pack_plan_arrays

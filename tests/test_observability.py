@@ -494,7 +494,6 @@ SPAN_PARENTS = {
     "kernel.dispatch": "kernel",
     "kernel.device_wait": "kernel",
     "merge": "search.request",
-    "merge.d2h": "merge",
     "merge.assemble": "merge",
     "fetch": "search.request",
     "search.respond": "search.request",
@@ -777,8 +776,7 @@ class TestRequestSpanTree:
                 rows = _rows(t)
                 names = [r[0] for r in rows.values()]
                 for name in ("kernel", "kernel.lock_wait",
-                             "kernel.dispatch", "kernel.device_wait",
-                             "merge.d2h"):
+                             "kernel.dispatch", "kernel.device_wait"):
                     assert names.count(name) == 1, (name, names)
                 kernel = next(i for i, r in rows.items()
                               if r[0] == "kernel")
@@ -786,9 +784,9 @@ class TestRequestSpanTree:
                 assert {r[0] for r in rows.values() if r[3] == kernel} == {
                     "kernel.lock_wait", "kernel.dispatch",
                     "kernel.device_wait"}
-                # the launch's one fetch, once, beside its kernel
-                assert [r[3] for r in rows.values()
-                        if r[0] == "merge.d2h"] == [0]
+                # the launch's one fetch is its one wait, inside
+                # ``kernel.device_wait``: no span of its own
+                assert "merge.d2h" not in names
                 assert t.annotations()["batch_size"] == 3
         finally:
             idx.close()
