@@ -192,6 +192,12 @@ class IndexService:
         self._get_total = 0
         self._refresh_total = 0
         self._host_query_total = 0
+        # convex hybrid requests (``query`` + ``knn``, docs/VECTOR.md):
+        # all of them, those the one-launch mesh program answered, those
+        # the host rung fused, and the mesh launches made for them
+        self._hybrid_stats = dict.fromkeys(
+            ("hybrid_query_total", "hybrid_fused_total",
+             "hybrid_host_fallback_total", "hybrid_launches_total"), 0)
         # legacy _parent metadata field values (ParentFieldMapper):
         # doc_id -> parent id, surfaced via stored_fields [_parent].
         # Values persist with the document (translog/store record
@@ -832,6 +838,18 @@ class IndexService:
             self._maybe_search_slowlog(took_s, body, plane, tracer)
         return resp
 
+    def _mesh_allowed(self, body: dict) -> bool:
+        """Whether a search of every shard's live segments may try the
+        mesh plane: it is on, the request is no scroll (the mesh stages
+        the LIVE segment set), and no copy is quarantined — a
+        quarantined copy must FAIL, not serve: the mesh plane
+        executes all shards as one program and cannot report a per-shard
+        failure, so any corrupt-flagged shard forces the host path,
+        where the flag becomes a failures[] entry."""
+        return (self._mesh_enabled and not body.get("scroll")
+                and not any(getattr(s, "store_corrupted", False)
+                            for s in self.shards.values()))
+
     def _try_mesh_search(self, body: dict, k: int,
                          deadline=None, tracer=None) -> Optional[dict]:
         """Mesh query phase + host fetch phase. None = ineligible."""
@@ -911,23 +929,31 @@ class IndexService:
             return None
         return self._mesh_batch_response(body, out, tracer=tracer)
 
-    def _search_hybrid(self, body: dict, deadline=None) -> dict:
+    # the request keys both sides of a hybrid request's host rung carry:
+    # a hit found only by the vector ranking must be fetched as the
+    # lexical side's are (a _source spec withheld stays withheld)
+    _HYBRID_PASSTHROUGH = ("timeout", "allow_partial_search_results",
+                           "stats", "_source", "docvalue_fields",
+                           "stored_fields", "script_fields", "highlight",
+                           "version")
+
+    def _search_hybrid(self, body: dict, deadline=None,
+                       tracer=None) -> dict:
         """Hybrid ranking: the lexical ``query`` and the ``knn`` section
-        each retrieve a top-``window`` candidate list through their own
-        full plane ladder (mesh_pallas → host, deadlines/cancellation/
-        partial results intact), then fuse:
+        fused as Elasticsearch 8 fuses them:
 
-        - ``rank: {rrf: {...}}`` — reciprocal rank fusion,
-          score = Σ_sides 1 / (rank_constant + rank)  (the reference's
-          RRF retriever);
-        - default — convex score fusion, score = lexical score +
-          knn_boost * knn score (the reference's additive knn+query
-          combination; per-side ``boost`` weights the blend).
-
-        The fused total is a LOWER BOUND (the union's exact count is
-        not computed) — surfaced via the response's ``_total_relation``
-        marker, which the REST layer renders as the
-        track_total_hits-style ``{"value", "relation": "gte"}`` object.
+        - default — convex score fusion (``_search_convex``): the ``k``
+          global nearest neighbours join the query's matches as a
+          disjunction; a hit scores its query score plus ``knn.boost``
+          times its kNN score, and ``hits.total`` counts the union,
+          exactly;
+        - ``rank: {rrf: {...}}`` — reciprocal rank fusion, score =
+          Σ_sides 1 / (rank_constant + rank) (the reference's RRF
+          retriever): each side retrieves a top-``window`` list through
+          its own plane ladder and the lists fuse on the host. Its
+          total is a LOWER BOUND (the union's count is not computed),
+          marked by ``_total_relation: gte``, which the REST layer
+          renders as ``{"value", "relation": "gte"}``.
         """
         import time as _time
 
@@ -965,26 +991,19 @@ class IndexService:
         size = int(body.get("size")) if body.get("size") is not None else 10
         k = max(from_ + size, 1)
         knn_k = int(spec.get("k", 10) or 10)
-        window = max(k, knn_k)
-        if rrf is not None:
-            window = max(window, int(rrf.get("window_size", window)))
-        rank_constant = int(rrf.get("rank_constant", 60)) if rrf else 60
-        knn_boost = float(spec.get("boost", 1.0))
+        if rrf is None:
+            return self._search_convex(body, spec, from_, size, knn_k,
+                                       deadline, tracer, t0)
+        window = max(k, knn_k, int(rrf.get("window_size", k)))
+        rank_constant = int(rrf.get("rank_constant", 60))
 
-        # the knn side must fetch hits with the SAME source filtering /
-        # fetch options as the lexical side: a hit found only by the
-        # vector ranking would otherwise leak fields the request's
-        # _source spec withheld
-        passthrough = ("timeout", "allow_partial_search_results", "stats",
-                       "_source", "docvalue_fields", "stored_fields",
-                       "script_fields", "highlight", "version")
         lex_body = {key: v for key, v in body.items()
                     if key not in ("knn", "rank", "from", "size")}
         lex_body["size"] = window
         knn_body = {"query": {"knn": {key: v for key, v in spec.items()
                                       if key != "boost"}},
                     "size": window}
-        for key in passthrough:
+        for key in self._HYBRID_PASSTHROUGH:
             if key in body:
                 knn_body[key] = body[key]
         lex_resp = self._search_uncached(lex_body, deadline=deadline)
@@ -995,51 +1014,18 @@ class IndexService:
                     for i, h in enumerate(resp["hits"]["hits"])}
 
         lex_hits, knn_hits = ranked(lex_resp), ranked(knn_resp)
-        if rrf is None:
-            # convex (additive) fusion follows the reference's knn+query
-            # semantics: only the k GLOBAL nearest neighbors contribute
-            # a vector score — a doc ranked past k by similarity gets 0
-            # from the knn side even though the window fetched more
-            knn_hits = {doc_id: (r, h) for doc_id, (r, h)
-                        in knn_hits.items() if r <= knn_k}
         fused = []
         for doc_id in set(lex_hits) | set(knn_hits):
             lex_rank, lex_hit = lex_hits.get(doc_id, (None, None))
             knn_rank, knn_hit = knn_hits.get(doc_id, (None, None))
-            if rrf is not None:
-                score = sum(1.0 / (rank_constant + r)
-                            for r in (lex_rank, knn_rank) if r is not None)
-            else:
-                score = ((lex_hit["_score"] or 0.0)
-                         if lex_hit is not None else 0.0) \
-                    + knn_boost * ((knn_hit["_score"] or 0.0)
-                                   if knn_hit is not None else 0.0)
+            score = sum(1.0 / (rank_constant + r)
+                        for r in (lex_rank, knn_rank) if r is not None)
             hit = dict(lex_hit if lex_hit is not None else knn_hit)
             hit["_score"] = float(score)
             hit.pop("sort", None)
             fused.append(hit)
         fused.sort(key=lambda h: (-h["_score"], h["_id"]))
         page = fused[from_: from_ + size] if size >= 0 else fused[from_:]
-
-        # shard header: both sides query the SAME shards, so merge the
-        # failure sets dedup'd by shard id — failed == len(failures) and
-        # successful + failed == total stay internally consistent even
-        # when a shard failed on both sides
-        shards = dict(lex_resp["_shards"])
-        seen = set()
-        failures = []
-        for f in (list(lex_resp["_shards"].get("failures") or [])
-                  + list(knn_resp["_shards"].get("failures") or [])):
-            key = (f.get("index"), f.get("shard"))
-            if key not in seen:
-                seen.add(key)
-                failures.append(f)
-        shards["failed"] = len(failures)
-        shards["successful"] = max(
-            int(shards.get("total", len(self.shards))) - len(failures), 0)
-        shards.pop("failures", None)
-        if failures:
-            shards["failures"] = failures
         total = max(int(lex_resp["hits"]["total"]),
                     int(knn_resp["hits"]["total"]))
         resp = {
@@ -1050,11 +1036,11 @@ class IndexService:
             # per-side execution-plane observability + fusion mode
             "_hybrid": {"lexical_plane": lex_resp.get("_plane", "host"),
                         "knn_plane": knn_resp.get("_plane", "host"),
-                        "fusion": "rrf" if rrf is not None else "convex"},
+                        "fusion": "rrf"},
             # union count not computed: the fused total is a documented
             # lower bound (REST renders {"value", "relation": "gte"})
             "_total_relation": "gte",
-            "_shards": shards,
+            "_shards": self._merged_shards([lex_resp, knn_resp]),
             "hits": {"total": total,
                      "max_score": (page[0]["_score"] if page else None),
                      "hits": page},
@@ -1066,6 +1052,187 @@ class IndexService:
         for key in ("aggregations", "suggest"):
             if key in lex_resp:
                 resp[key] = lex_resp[key]
+        return resp
+
+    def _merged_shards(self, resps: list) -> dict:
+        """The shard header of a response fused from sub-searches of the
+        SAME shards: failures dedup'd by shard, so failed ==
+        len(failures) and successful + failed == total stay consistent
+        even where a shard failed on every side."""
+        shards = dict(resps[0]["_shards"])
+        seen = set()
+        failures = []
+        for f in (f for r in resps
+                  for f in (r["_shards"].get("failures") or [])):
+            key = (f.get("index"), f.get("shard"))
+            if key not in seen:
+                seen.add(key)
+                failures.append(f)
+        shards["failed"] = len(failures)
+        shards["successful"] = max(
+            int(shards.get("total", len(self.shards))) - len(failures), 0)
+        shards.pop("failures", None)
+        if failures:
+            shards["failures"] = failures
+        return shards
+
+    def _count_hybrid(self, **adds) -> None:
+        with self._stats_lock:
+            for name, n in adds.items():
+                self._hybrid_stats[name] += n
+
+    def _search_convex(self, body: dict, spec: dict, from_: int, size: int,
+                       knn_k: int, deadline, tracer, t0: float) -> dict:
+        """A convex hybrid request, exact on either rung: ONE launch of
+        the mesh hybrid program (``IndexMeshSearch.query_hybrid``) where
+        the mesh plane serves, else the host rung (``_host_convex``).
+        Either way one response, counted and profiled as one request;
+        ``hits.total`` is the union's exact count (``_total_relation:
+        eq``, rendered ``{"value", "relation": "eq"}``)."""
+        import time as _time
+
+        from elasticsearch_tpu.search.cancellation import (
+            TimeExceededException,
+        )
+        from elasticsearch_tpu.search.telemetry import NULL_TRACER
+
+        if tracer is None:
+            tracer = NULL_TRACER
+        self._count_hybrid(hybrid_query_total=1)
+        launched = tracer.launch_count()
+        resp = None
+        if self._mesh_allowed(body):
+            try:
+                resp = self._try_mesh_hybrid(body, spec, from_, size,
+                                             deadline, tracer)
+            except TimeExceededException:
+                resp = None  # the host rung's sub-searches time out too
+        if resp is None:
+            resp = self._host_convex(body, spec, from_, size, knn_k,
+                                     deadline, tracer)
+        # the mesh launches made for it, where they were made (a faulted
+        # rung's too)
+        self._count_hybrid(
+            hybrid_launches_total=tracer.launch_count() - launched)
+        took_s = _time.monotonic() - t0
+        resp["took"] = int(took_s * 1000)
+        return self._finish_query_response(resp, body, tracer,
+                                           resp["_plane"], took_s)
+
+    def _try_mesh_hybrid(self, body: dict, spec: dict, from_: int,
+                         size: int, deadline, tracer) -> Optional[dict]:
+        """The convex hybrid's query phase as one mesh launch, and its
+        fetch phase. None = ineligible (the host rung serves)."""
+        if self._mesh_search is None:
+            from elasticsearch_tpu.parallel.plan_exec import IndexMeshSearch
+
+            self._mesh_search = IndexMeshSearch(self)
+        lex_body = {key: v for key, v in body.items() if key != "knn"}
+        out = self._mesh_search.query_hybrid(
+            lex_body, spec, max(from_ + size, 1), deadline=deadline,
+            tracer=tracer)
+        if out is None:
+            return None
+        self._count_hybrid(hybrid_fused_total=1)
+        refs = out["refs"]
+        refs = refs[from_: from_ + size] if size >= 0 else refs[from_:]
+        t_fetch = tracer.start("fetch")
+        hits = fetch_hits(refs, self.shards, body, self.name)
+        tracer.stop("fetch", t_fetch)
+        return {
+            "timed_out": False,
+            "_plane": out["plane"],
+            "_hybrid": {"lexical_plane": out["plane"],
+                        "knn_plane": "mesh_pallas", "fusion": "convex"},
+            "_total_relation": "eq",
+            "_shards": {"total": len(self.shards),
+                        "successful": len(self.shards),
+                        "skipped": 0, "failed": 0},
+            "hits": {"total": out["total"], "max_score": out["max_score"],
+                     "hits": hits},
+        }
+
+    def _host_convex(self, body: dict, spec: dict, from_: int, size: int,
+                     knn_k: int, deadline, tracer) -> dict:
+        """The convex hybrid on the host rung, with the mesh program's
+        answer: the lexical top ``from + size + k`` (at most ``k`` of
+        them winners, so the page's other hits are among them), the
+        ``k`` winners, and those of the winners past the lexical window
+        scored lexically through an ``ids`` filter; a hit's score is the
+        program's float32 sum, and the union total the lexical total
+        plus the winners that matched no term. The fusion is the
+        ``hybrid.fuse`` span."""
+        import numpy as np
+
+        sub = {key: body[key] for key in self._HYBRID_PASSTHROUGH
+               if key in body}
+        lex_body = {key: v for key, v in body.items()
+                    if key not in ("knn", "rank", "from", "size",
+                                   "profile")}
+        lex_body["size"] = from_ + max(size, 0) + knn_k
+        knn_body = dict(sub, query={"knn": {key: v for key, v in spec.items()
+                                            if key != "boost"}},
+                        size=knn_k)
+        # (each sub-search has a tracer of its own, read for its
+        # launches and drained nowhere)
+        tracers = []
+
+        def sub_search(sub_body):
+            tracers.append(self._tracer())
+            return self._search_uncached(sub_body, deadline=deadline,
+                                         tracer=tracers[-1])
+
+        resps = [sub_search(lex_body), sub_search(knn_body)]
+        t_fuse = tracer.start("hybrid.fuse")
+        lex_hits = resps[0]["hits"]["hits"]
+        winners = resps[1]["hits"]["hits"][:knn_k]
+        lexical = {h["_id"]: h for h in lex_hits}
+        unseen = [h["_id"] for h in winners if h["_id"] not in lexical]
+        if unseen:
+            resps.append(sub_search(dict(
+                sub, size=len(unseen), query={"bool": {
+                    "must": [body["query"]],
+                    "filter": [{"ids": {"values": unseen}}]}})))
+            lexical.update((h["_id"], h) for h in resps[2]["hits"]["hits"])
+        boost = np.float32(spec.get("boost", 1.0))
+        knn_part = {h["_id"]: boost * np.float32(h["_score"] or 0.0)
+                    for h in winners}
+        fused = []
+        for hit in {**{h["_id"]: h for h in winners},
+                    **{h["_id"]: h for h in lex_hits}}.values():
+            doc_id = hit["_id"]
+            lex_score = (np.float32(lexical[doc_id]["_score"] or 0.0)
+                         if doc_id in lexical else np.float32(0.0))
+            hit = dict(lexical.get(doc_id, hit))
+            hit["_score"] = float(lex_score
+                                  + knn_part.get(doc_id, np.float32(0.0)))
+            hit.pop("sort", None)
+            fused.append(hit)
+        fused.sort(key=lambda h: (-h["_score"], h["_id"]))
+        page = fused[from_: from_ + size] if size >= 0 else fused[from_:]
+        total = int(resps[0]["hits"]["total"]) + sum(
+            1 for h in winners if h["_id"] not in lexical)
+        tracer.stop("hybrid.fuse", t_fuse)
+        self._count_hybrid(
+            hybrid_host_fallback_total=1,
+            hybrid_launches_total=sum(t.launch_count() for t in tracers))
+        resp = {
+            "timed_out": any(bool(r.get("timed_out")) for r in resps),
+            "_plane": "host",
+            "_hybrid": {"lexical_plane": resps[0].get("_plane", "host"),
+                        "knn_plane": resps[1].get("_plane", "host"),
+                        "fusion": "convex"},
+            "_total_relation": "eq",
+            "_shards": self._merged_shards(resps),
+            "hits": {"total": total,
+                     "max_score": fused[0]["_score"] if fused else None,
+                     "hits": page},
+        }
+        # aggregations/suggest are the lexical side's, computed over the
+        # query's whole match set — docs/VECTOR.md
+        for key in ("aggregations", "suggest"):
+            if key in resps[0]:
+                resp[key] = resps[0][key]
         return resp
 
     def search(self, body: Optional[dict] = None,
@@ -1249,7 +1416,8 @@ class IndexService:
                     "[knn] must be an object with [field] and "
                     "[query_vector]")
             if body.get("query") is not None:
-                return self._search_hybrid(body, deadline=deadline)
+                return self._search_hybrid(body, deadline=deadline,
+                                           tracer=tracer)
             body = dict(body)
             spec = body.pop("knn")
             if body.pop("rank", None) is not None:
@@ -1273,16 +1441,8 @@ class IndexService:
         # merge in-XLA); fallback is the per-shard host merge below.
         # Pinned (scroll) searches stay on the host path: the mesh stages
         # the LIVE segment set.
-        if (self._mesh_enabled and not skip_mesh
-                and preference_shards is None
-                and pinned_segments is None and not body.get("scroll")
-                # a quarantined copy must FAIL, not serve (ISSUE 16):
-                # the mesh plane executes all shards as one program and
-                # cannot report a per-shard failure, so any corrupt-
-                # flagged shard forces the host path below where the
-                # flag becomes a failures[] entry
-                and not any(getattr(s, "store_corrupted", False)
-                            for s in self.shards.values())):
+        if (not skip_mesh and preference_shards is None
+                and pinned_segments is None and self._mesh_allowed(body)):
             try:
                 knn_clause = _pure_knn_mesh_clause(body)
                 if knn_clause is not None:
@@ -1857,6 +2017,10 @@ class IndexService:
                     self._mesh_search.pallas_query_total
                     if self._mesh_search is not None else 0),
                 "host_query_total": self._host_query_total,
+                # convex hybrid requests: served by the one-launch mesh
+                # program, fused on the host rung, and the mesh launches
+                # made for them (docs/VECTOR.md)
+                **self._hybrid_stats,
                 "pallas_segments_total": sum(
                     s["search"]["planes"]["pallas_segments_total"]
                     for s in shard_stats.values()),

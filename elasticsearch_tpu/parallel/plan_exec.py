@@ -544,7 +544,8 @@ def _mesh_query_program(mesh: Mesh, holder: _TemplateHolder, k: int,
                         slice_col: Optional[str] = None,
                         rescore_static: Optional[Tuple[int, str]] = None,
                         agg_static: tuple = (),
-                        packing: tuple = ((), (0, 0, 0), ())):
+                        packing: tuple = ((), (0, 0, 0), ()),
+                        knn: Optional[tuple] = None):
     """One compiled scatter-gather program covering the collector-chain
     semantics of the reference's query phase (QueryPhase.java:179-268) as
     fused mask stages:
@@ -588,6 +589,16 @@ def _mesh_query_program(mesh: Mesh, holder: _TemplateHolder, k: int,
     ``float32[n_slots]`` a traced scalar; how many of each of the
     three; the scalars' names). It follows from the shapes and features
     the holder's key already names.
+    knn: a hybrid request's kNN side, which makes this the hybrid
+    program (family ``hybrid``), ``(kk, k, sub, d_pad, nd_knn, metric,
+    interpret)``: before the slots' passes, the request's global
+    top-``k`` by raw similarity over the flat embedding tables the
+    launch takes after ``loose`` (``_knn_top``); in each slot's pass,
+    each of those winners that is the slot's adds ``knn_boost *
+    hit_score`` to its document's score and joins ``matched``, so the
+    top-k, the total and the merge are those of the union. The query
+    row and the boost ride the pack as the scalars ``knn_query`` and
+    ``knn_boost``.
     """
     layout, n_arrays, scalar_names = packing
     plan = holder.plan
@@ -595,13 +606,22 @@ def _mesh_query_program(mesh: Mesh, holder: _TemplateHolder, k: int,
     rs_plan = holder.rs_plan
 
     def per_slot(seg, row_base, plan_arrays, pf_arrays, rs_arrays,
-                 scalars):
+                 scalars, fuse=None):
         """One segment's query phase: emit -> mask stages -> local top-k.
+        ``fuse``: the kNN winners that are this slot's, ``(docs, adds)``
+        (a winner of another slot at a document past the slot's end).
         Returns (loc_keys, loc_docs, loc_scores, loc_raw|None,
         local_count, (agg_matched, scores) | (), agg_parts)."""
         ctx = EmitCtx(seg, plan_arrays, row_base)
         scores, matched = plan.emit(ctx)
         matched = matched & seg["live1"]
+        if fuse is not None:
+            # the union: a winner that matched no term scores its kNN
+            # part alone (out-of-range documents are dropped)
+            w_docs, w_adds = fuse
+            scores = jnp.where(matched, scores, jnp.float32(0.0))
+            scores = scores.at[w_docs].add(w_adds, mode="drop")
+            matched = matched.at[w_docs].set(True, mode="drop")
         # stage order mirrors the host path (search/service.py query()):
         # min_score and slice filter BEFORE aggs see the mask;
         # post_filter only narrows hits+total, never aggregations
@@ -713,7 +733,7 @@ def _mesh_query_program(mesh: Mesh, holder: _TemplateHolder, k: int,
                 agg_static, one_doc, jnp.zeros((1,), bool)))
         return (*rest, agg_parts)
 
-    def per_device(seg, packed, loose):
+    def per_device(seg, packed, loose, *knn_tables):
         dev = jax.lax.axis_index("shards")
         arrays = iter(_unpack_plan_arrays(layout, packed, loose))
         plan_arrays, pf_arrays, rs_arrays = (
@@ -723,14 +743,33 @@ def _mesh_query_program(mesh: Mesh, holder: _TemplateHolder, k: int,
         # (no kernel table staged: the scatter plane, no row to offset)
         k_rows = next((seg[name].shape[0] // spd
                        for name in _KERNEL_TABLES if name in seg), 0)
+        winners = None
+        if knn is not None:
+            from elasticsearch_tpu.ops import pallas_knn as pkn
+
+            kk, k_knn, sub, _d_pad, nd_knn, metric, interpret = knn
+            top_s, top_slot, top_d, _ = _knn_top(
+                *knn_tables, scalars["knn_query"][None], spd=spd, q_pad=1,
+                kk=kk, sub=sub, nd_knn=nd_knn, interpret=interpret)
+            w_sims = top_s[0, :k_knn]
+            winners = (jnp.where(w_sims == -jnp.inf, -1, top_slot[0, :k_knn]),
+                       top_d[0, :k_knn],
+                       scalars["knn_boost"]
+                       * pkn.hit_score(w_sims, metric, jnp))
 
         def slot(i):
             seg_i = {name: a if name in _KERNEL_TABLES else a[i]
                      for name, a in seg.items()}
+            fuse = None
+            if winners is not None:
+                w_slot, w_docs, w_adds = winners
+                mine = w_slot == dev.astype(jnp.int32) * jnp.int32(spd) + i
+                fuse = (jnp.where(mine, w_docs, seg_i["live1"].shape[0]),
+                        jnp.where(mine, w_adds, jnp.float32(0.0)))
             return per_slot(
                 seg_i, i * k_rows, [a[i] for a in plan_arrays],
                 [a[i] for a in pf_arrays], [a[i] for a in rs_arrays],
-                scalars)
+                scalars, fuse)
 
         slot_out = _live_slots_only(
             spd, lambda i: jnp.any(seg["live1"][i]), slot,
@@ -782,19 +821,23 @@ def _mesh_query_program(mesh: Mesh, holder: _TemplateHolder, k: int,
     from elasticsearch_tpu.search.fused_aggs import n_agg_outputs
 
     n_out = 2 + (2 if with_views else 0) + n_agg_outputs(agg_static)
+    # (a hybrid launch also takes the flat embeddings, scale and mask)
+    n_in = 3 + (3 if knn is not None else 0)
     mapped = shard_map(
         per_device, mesh=mesh,
-        in_specs=(PS("shards"), PS("shards"), PS("shards")),
+        in_specs=(PS("shards"),) * n_in,
         out_specs=(PS("shards"),) * n_out,
         check_vma=False,
     )
+    family, scope = (("serial", "mesh_query") if knn is None
+                     else ("hybrid", "mesh_hybrid"))
 
     @jax.jit
-    def run(seg, packed, loose):
+    def run(seg, packed, loose, *knn_tables):
         # (the scope puts the program's name into the op metadata of
         # every device operation: a trace's fusions say whose they are)
-        with jax.named_scope("mesh_query"):
-            outs = mapped(seg, packed, loose)
+        with jax.named_scope(scope):
+            outs = mapped(seg, packed, loose, *knn_tables)
         # the packed answer is replicated (row 0 == row i); the other
         # outputs keep their sharded leading axis
         return (outs[0][0],) + tuple(outs[1:])
@@ -805,8 +848,8 @@ def _mesh_query_program(mesh: Mesh, holder: _TemplateHolder, k: int,
     )
 
     return instrument_program(
-        run, "serial",
-        variant_key("serial", holder._key, len(mesh.devices)))
+        run, family,
+        variant_key(family, holder._key, len(mesh.devices)))
 
 
 def _shapes_sig(arrays) -> str:
@@ -1148,6 +1191,51 @@ def _mesh_batched_pruned_program(mesh: Mesh, spd: int, q_batch: int,
                     cb, sub, tps, interpret, codec, probe, n_rest))
 
 
+def _knn_top(emb, scale, mask, qv, *, spd: int, q_pad: int, kk: int,
+             sub: int, nd_knn: int, interpret: bool):
+    """Inside a mesh program, every query's exact top-``kk`` by raw
+    similarity over the live slots of ALL devices: per slot ONE
+    ``knn_score_tiles`` pass over its rows of the flat tables, read in
+    place (a slot whose mask holds no live vector skips it,
+    ``_live_slots_only``), then the slots' candidates over ICI (one
+    ``all_gather`` each) and one ``top_k``, ties to the earlier (device,
+    slot, rank). Returns (sims, global slots, docs) ``[q_pad, kk']``,
+    replicated, and the live vectors' count (query-independent: the
+    psum of the staged mask)."""
+    from elasticsearch_tpu.ops import pallas_knn as pkn
+
+    dev = jax.lax.axis_index("shards")
+    slot_mask = mask.reshape(spd, nd_knn)  # (a view: no copy)
+
+    def slot(i):
+        ts, td = pkn.knn_score_tiles(
+            emb, scale, mask, qv, sub=sub, k=kk, q_batch=q_pad,
+            interpret=interpret,
+            row_base=_slot_row_base(emb, i, spd), n_rows=nd_knn)
+        s_i, d_i = pkn.merge_knn_topk(ts, td, kk)  # [q_pad, kk']
+        return s_i, d_i, jnp.sum(slot_mask[i]).astype(jnp.int32)
+
+    slot_out = _live_slots_only(
+        spd, lambda i: jnp.any(slot_mask[i] > 0), slot)
+    cs = jnp.concatenate([o[0] for o in slot_out], axis=1)
+    cd = jnp.concatenate([o[1] for o in slot_out], axis=1)
+    cslot = jnp.concatenate([
+        jnp.zeros(o[0].shape, jnp.int32)
+        + (dev.astype(jnp.int32) * jnp.int32(spd) + jnp.int32(i))
+        for i, o in enumerate(slot_out)], axis=1)
+    total = jax.lax.psum(sum(o[2] for o in slot_out), "shards")
+    all_s = jax.lax.all_gather(cs, "shards")
+    all_d = jax.lax.all_gather(cd, "shards")
+    all_slot = jax.lax.all_gather(cslot, "shards")
+    pool_s = all_s.transpose(1, 0, 2).reshape(q_pad, -1)
+    pool_d = all_d.transpose(1, 0, 2).reshape(q_pad, -1)
+    pool_slot = all_slot.transpose(1, 0, 2).reshape(q_pad, -1)
+    top_s, top_i = jax.lax.top_k(pool_s, min(kk, pool_s.shape[1]))
+    top_d = jnp.take_along_axis(pool_d, top_i, axis=1)
+    top_slot = jnp.take_along_axis(pool_slot, top_i, axis=1)
+    return top_s, top_slot, top_d, total
+
+
 @functools.lru_cache(maxsize=32)
 def _mesh_knn_program(mesh: Mesh, spd: int, q_pad: int, kk: int,
                       sub: int, d_pad: int, nd_knn: int, metric: str,
@@ -1173,35 +1261,9 @@ def _mesh_knn_program(mesh: Mesh, spd: int, q_pad: int, kk: int,
     from elasticsearch_tpu.ops import pallas_knn as pkn
 
     def per_device(emb, scale, mask, qv):
-        dev = jax.lax.axis_index("shards")
-        slot_mask = mask.reshape(spd, nd_knn)  # (a view: no copy)
-
-        def slot(i):
-            ts, td = pkn.knn_score_tiles(
-                emb, scale, mask, qv, sub=sub, k=kk, q_batch=q_pad,
-                interpret=interpret,
-                row_base=_slot_row_base(emb, i, spd), n_rows=nd_knn)
-            s_i, d_i = pkn.merge_knn_topk(ts, td, kk)  # [q_pad, kk']
-            return s_i, d_i, jnp.sum(slot_mask[i]).astype(jnp.int32)
-
-        slot_out = _live_slots_only(
-            spd, lambda i: jnp.any(slot_mask[i] > 0), slot)
-        cs = jnp.concatenate([o[0] for o in slot_out], axis=1)
-        cd = jnp.concatenate([o[1] for o in slot_out], axis=1)
-        cslot = jnp.concatenate([
-            jnp.zeros(o[0].shape, jnp.int32)
-            + (dev.astype(jnp.int32) * jnp.int32(spd) + jnp.int32(i))
-            for i, o in enumerate(slot_out)], axis=1)
-        total = jax.lax.psum(sum(o[2] for o in slot_out), "shards")
-        all_s = jax.lax.all_gather(cs, "shards")
-        all_d = jax.lax.all_gather(cd, "shards")
-        all_slot = jax.lax.all_gather(cslot, "shards")
-        pool_s = all_s.transpose(1, 0, 2).reshape(q_pad, -1)
-        pool_d = all_d.transpose(1, 0, 2).reshape(q_pad, -1)
-        pool_slot = all_slot.transpose(1, 0, 2).reshape(q_pad, -1)
-        top_s, top_i = jax.lax.top_k(pool_s, min(kk, pool_s.shape[1]))
-        top_d = jnp.take_along_axis(pool_d, top_i, axis=1)
-        top_slot = jnp.take_along_axis(pool_slot, top_i, axis=1)
+        top_s, top_slot, top_d, total = _knn_top(
+            emb, scale, mask, qv, spd=spd, q_pad=q_pad, kk=kk, sub=sub,
+            nd_knn=nd_knn, interpret=interpret)
         scores = pkn.hit_score(top_s, metric, jnp)
         total = total.astype(jnp.int64)
         # (the sixth row carries a field sort's raw values in the serial
@@ -1952,7 +2014,6 @@ class IndexMeshSearch:
         from elasticsearch_tpu.index.segment import next_pow2
         from elasticsearch_tpu.mapper.field_types import DenseVectorFieldType
         from elasticsearch_tpu.ops import pallas_knn as pkn
-        from elasticsearch_tpu.ops import pallas_scoring as psc
         from elasticsearch_tpu.search.service import DocRef
         from elasticsearch_tpu.testing.disruption import (
             on_kernel_launch,
@@ -2007,27 +2068,16 @@ class IndexMeshSearch:
             if executor is None:
                 self._note("host", "knn_staging_unavailable", len(specs))
                 return None
-            session = executor.ensure_knn(field, ft.dims, ft.similarity,
-                                          tracer=bt)
-            if session is None:
-                reason = executor.kernel_denied_reason
-                self._note("host", reason or "knn_staging_unavailable",
-                           len(specs))
-                if reason == "staging_fault":
-                    # a terminal classified staging fault: bench the
-                    # plane so peers don't re-pay the staging attempt per
-                    # query (the post-cooldown probe restages —
-                    # docs/RESILIENCE.md)
-                    self.plane_health.record_failure(
-                        "mesh_pallas", reason="staging_fault")
+            staged = self._knn_session(executor, field, ft, sub_pref, bt,
+                                       len(specs))
+            if staged is None:
                 return None
+            session, tile_sub = staged
             q_batch = len(specs)
             q_pad = next_pow2(q_batch)
             kk = next_pow2(max(max(ks), 1))
             d_pad = session["d_pad"]
             nd_knn = session["nd_pad"]
-            g = psc.tile_geometry(
-                nd_knn, pkn.knn_tile_sub(nd_knn, d_pad, sub_pref))
             qmat = np.zeros((q_pad, d_pad), np.float32)
             for q, qvec in enumerate(qvecs):
                 qmat[q] = pkn.normalize_query(qvec, ft.similarity, d_pad)
@@ -2042,7 +2092,7 @@ class IndexMeshSearch:
             on_plane_execute(self.svc.name, "mesh_pallas")
             run = _mesh_knn_program(
                 executor.mesh, executor.slots_per_dev,
-                q_pad, kk, g.tile_sub, d_pad, nd_knn, ft.similarity,
+                q_pad, kk, tile_sub, d_pad, nd_knn, ft.similarity,
                 session["mode"] == "interpret")
             args = (session["emb"], session["scale"], session["mask"],
                     jnp.asarray(qmat))
@@ -2225,7 +2275,103 @@ class IndexMeshSearch:
         oriented = anchor if order == "desc" else -anchor
         return float(np.clip(oriented, -big, big))
 
-    def query(self, body: dict, k: int, deadline=None, tracer=None):
+    # request keys a hybrid request (``query_hybrid``) may carry besides
+    # ``query`` and ``knn``: paging, deadlines, and what only the fetch
+    # phase reads; anything else is the host rung's
+    HYBRID_KEYS = frozenset({
+        "query", "size", "from", "timeout", "allow_partial_search_results",
+        "stats", "profile", "_source", "docvalue_fields", "stored_fields",
+        "version",
+    })
+    # the ``knn`` parameters its program covers (no ``filter``, no name)
+    HYBRID_KNN_KEYS = frozenset({"field", "query_vector", "k",
+                                 "num_candidates", "boost"})
+
+    def query_hybrid(self, body: dict, spec: dict, k: int, deadline=None,
+                     tracer=None):
+        """A hybrid request, ``query`` (``body``, without ``knn``) and
+        ``knn`` (``spec``) fused as Elasticsearch fuses them without a
+        ``rank``: the ``spec["k"]`` global nearest neighbours join the
+        query's matches, and a hit scores its query score plus
+        ``boost`` times its kNN score. ONE launch of the hybrid program
+        (``_mesh_query_program`` with ``knn``) through ``query``'s
+        ladder, so the request has the lexical request's spans. Returns
+        ``query``'s answer, whose ``total`` is the union's exact count,
+        or None where the program cannot serve it (the host rung then
+        does)."""
+        from elasticsearch_tpu.common.errors import MapperParsingException
+        from elasticsearch_tpu.mapper.field_types import DenseVectorFieldType
+
+        if (any(key not in self.HYBRID_KEYS for key in body)
+                or any(key not in self.HYBRID_KNN_KEYS for key in spec)):
+            self._note("host", "hybrid_ineligible")
+            return None
+        try:
+            ft = self.svc.mapper_service.field_type(str(spec["field"]))
+            if not isinstance(ft, DenseVectorFieldType):
+                return None
+            qvec = ft.parse_vector(spec["query_vector"])
+            knn = {"field": str(spec["field"]), "ft": ft, "qvec": qvec,
+                   "k": int(spec.get("k", 10) or 10),
+                   "boost": float(spec.get("boost", 1.0))}
+        except (KeyError, TypeError, ValueError, MapperParsingException):
+            return None  # the host rung owns the request's error
+        return self.query(body, k, deadline=deadline, tracer=tracer,
+                          knn=knn)
+
+    def _knn_session(self, executor, field: str, ft, sub_pref: int, tracer,
+                     n: int = 1):
+        """``executor``'s kNN plane of ``field`` (``ensure_knn``: staged
+        once a generation) and its kernel's tile sublanes, or None where
+        it cannot serve, the reason noted for ``n`` queries."""
+        from elasticsearch_tpu.ops import pallas_knn as pkn
+        from elasticsearch_tpu.ops import pallas_scoring as psc
+
+        session = executor.ensure_knn(field, ft.dims, ft.similarity,
+                                      tracer=tracer)
+        if session is None:
+            reason = executor.kernel_denied_reason
+            self._note("host", reason or "knn_staging_unavailable", n)
+            if reason == "staging_fault":
+                # a terminal classified staging fault: bench the plane
+                # so peers don't re-pay the staging attempt per query
+                # (the post-cooldown probe restages — docs/RESILIENCE.md)
+                self.plane_health.record_failure(
+                    "mesh_pallas", reason="staging_fault")
+            return None
+        nd_knn, d_pad = session["nd_pad"], session["d_pad"]
+        return session, psc.tile_geometry(
+            nd_knn, pkn.knn_tile_sub(nd_knn, d_pad, sub_pref)).tile_sub
+
+    def _stage_knn_side(self, knn: dict, executor, scalars: dict, tracer):
+        """A hybrid request's kNN side on the staged generation: the
+        field's embeddings (``_knn_session``), the query row and the
+        boost as the launch's scalars ``knn_query`` and ``knn_boost``.
+        Returns ``execute``'s ``knn`` argument, or None where the kNN
+        plane cannot serve (reason noted)."""
+        from elasticsearch_tpu.index.segment import next_pow2
+        from elasticsearch_tpu.ops import pallas_knn as pkn
+
+        enabled, sub_pref = self._knn_config()
+        if not enabled:
+            self._note("host", "knn_disabled")
+            return None
+        ft = knn["ft"]
+        staged = self._knn_session(executor, knn["field"], ft, sub_pref,
+                                   tracer)
+        if staged is None:
+            return None
+        session, tile_sub = staged
+        d_pad = session["d_pad"]
+        scalars["knn_query"] = pkn.normalize_query(knn["qvec"],
+                                                   ft.similarity, d_pad)
+        scalars["knn_boost"] = knn["boost"]
+        return session, (next_pow2(max(knn["k"], 1)), knn["k"], tile_sub,
+                         d_pad, session["nd_pad"], ft.similarity,
+                         session["mode"] == "interpret")
+
+    def query(self, body: dict, k: int, deadline=None, tracer=None,
+              knn: Optional[dict] = None):
         """Returns {total, refs, max_score, aggregations,
         terminated_early} or None if ineligible.
         deadline: SearchDeadline — checkpointed between staging steps and
@@ -2234,7 +2380,9 @@ class IndexMeshSearch:
         TaskCancelledException).
         tracer: QueryTracer — phase spans (parse_rewrite / plan_build /
         staging / kernel / merge) recorded against whichever plane ends
-        up serving (docs/OBSERVABILITY.md)."""
+        up serving (docs/OBSERVABILITY.md).
+        knn: a hybrid request's kNN side, as ``query_hybrid`` parsed it:
+        the launch is the hybrid program's."""
         from elasticsearch_tpu.search.aggregations import (
             SegmentView,
             parse_aggs,
@@ -2285,7 +2433,8 @@ class IndexMeshSearch:
             self.plane_health.cooldown_s = settings.get_time(
                 "index.search.plane_quarantine.cooldown", 60.0)
         pruning_on, _probe = self._pruning_config()
-        if (pruning_on and isinstance(body.get("query"), dict)
+        if (pruning_on and knn is None
+                and isinstance(body.get("query"), dict)
                 and all(key in self.BATCHABLE_KEYS for key in body)
                 and int(body.get("size", 10) if body.get("size")
                         is not None else 10) > 0
@@ -2387,6 +2536,11 @@ class IndexMeshSearch:
         pf_qb = (parse_query(body["post_filter"])
                  if body.get("post_filter") else None)
         tracer.stop("parse_rewrite", t_parse)
+        knn_side = None
+        if knn is not None:
+            knn_side = self._stage_knn_side(knn, executor, scalars, tracer)
+            if knn_side is None:
+                return None
         # plane ladder: try the tile-kernel plane first (one fast plane
         # for distributed queries — the reference runs the same BulkScorer
         # hot loop on every shard), falling back to the scatter mesh when
@@ -2497,7 +2651,7 @@ class IndexMeshSearch:
                         agg_static=(agg_plan.statics
                                     if agg_plan is not None else ()),
                         telemetry=self._telemetry,
-                        read_counts=bool(terminate_after))
+                        read_counts=bool(terminate_after), knn=knn_side)
                     # the plane served: fully re-open it (ends a probe's
                     # quarantine — single-flight contract)
                     self.plane_health.note_success(plane)
@@ -4590,7 +4744,8 @@ class MeshPlanExecutor:
                 slice_col: Optional[str] = None,
                 rescore_static: Optional[Tuple[int, str]] = None,
                 tracer=NULL_TRACER, agg_static: tuple = (),
-                telemetry=None, read_counts: bool = True):
+                telemetry=None, read_counts: bool = True,
+                knn: Optional[tuple] = None):
         """plans: one per shard, same query. Returns, as numpy, (packed
         int32 [2 + 5k] — ``_unpack_answer`` gives top_keys [k], top_slot
         [k], top_doc [k], total, top_score [k], top_raw [k] —, counts
@@ -4607,7 +4762,11 @@ class MeshPlanExecutor:
         query plans; scalars: traced values for `features` and rescore
         weights (compiled once per feature SET, not per value).
         agg_static: fused-agg descriptors (search/fused_aggs.py) whose
-        staged doc-value columns reduce inside the program."""
+        staged doc-value columns reduce inside the program.
+        knn: a hybrid request's kNN side, ``(session, statics)``: the
+        field's ``ensure_knn`` session and ``_mesh_query_program``'s
+        ``knn``; its query row and boost are ``scalars`` ``knn_query``
+        and ``knn_boost``."""
         if len(plans) != len(self.segments):
             raise ValueError("one plan per staged shard required")
         t_stage = tracer.start("staging")
@@ -4632,26 +4791,33 @@ class MeshPlanExecutor:
                + f"|k{k}|n{self.n_dev}|p{self.slots_per_dev}"
                + f"|s{sort_keys}|v{with_views}"
                + f"|f{sorted(features)}|sl{slice_col}|r{rescore_static}"
-               + f"|a{agg_static}")
+               + f"|a{agg_static}"
+               + (f"|knn{knn[1]}" if knn is not None else ""))
         # What the request brings rides the launch as ONE host array (the
         # jitted call places it as ``shard_map``'s in_specs say): a sharded
         # ``device_put`` an array ahead of the launch costs ~0.3 ms of
         # Python each on a v5e's host, a host array of its own inside the
         # call ~0.14 (PERF.md 6 PR 36).
+        # (a scalar is a float32 a slot, a vector a row a slot)
         names = tuple(sorted(scalars or ()))
         packed, loose, layout = _pack_plan_arrays(
             [*stacked, *stacked_pf, *stacked_rs,
-             *(np.full(self.n_slots, scalars[n], np.float32)
+             *(np.broadcast_to(np.asarray(scalars[n], np.float32),
+                               (self.n_slots, *np.shape(scalars[n])))
                for n in names)], self.n_slots)
+        holder = _TemplateHolder(_strip_plan(plans[0]), key, pf_tpl, rs_tpl)
+        packing = (layout, (len(stacked), len(stacked_pf), len(stacked_rs)),
+                   names)
         run = _mesh_query_program(
-            self.mesh,
-            _TemplateHolder(_strip_plan(plans[0]), key, pf_tpl, rs_tpl), k,
-            spd=self.slots_per_dev,
-            sort_keys=sort_keys, with_views=with_views, features=features,
-            slice_col=slice_col, rescore_static=rescore_static,
-            agg_static=agg_static, packing=(
-                layout, (len(stacked), len(stacked_pf), len(stacked_rs)),
-                names))
+            self.mesh, holder, k, spd=self.slots_per_dev,
+            sort_keys=sort_keys, with_views=with_views,
+            features=features, slice_col=slice_col,
+            rescore_static=rescore_static, agg_static=agg_static,
+            packing=packing, knn=None if knn is None else knn[1])
+        # (a hybrid launch also takes the field's flat embeddings, scale
+        # and mask)
+        knn_tables = () if knn is None else tuple(
+            knn[0][name] for name in ("emb", "scale", "mask"))
         tracer.stop("staging", t_stage)
         wanted = set(sort_keys or ())
         if slice_col is not None:
@@ -4677,8 +4843,8 @@ class MeshPlanExecutor:
 
             n_out += n_agg_outputs(agg_static)
         reads = (0, *((1,) if read_counts else ()), *range(2, n_out))
-        outs = _launch_locked(tracer, run, seg, packed, loose, reads=reads,
-                              telemetry=telemetry)
+        outs = _launch_locked(tracer, run, seg, packed, loose, *knn_tables,
+                              reads=reads, telemetry=telemetry)
         if telemetry is not None:
             # host arrays the launch carried (the pack, and what could
             # not go in); occupied slots over all: the share a query

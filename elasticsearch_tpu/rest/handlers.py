@@ -741,11 +741,12 @@ def _search(node, req):
 def _render_total_hits(resp, body) -> None:
     """track_total_hits-style REST surfacing of inexact totals: the 6.x
     response keeps ``hits.total`` a bare int, but block-max pruned
-    scoring (docs/PRUNING.md) and hybrid kNN fusion (docs/VECTOR.md)
+    scoring (docs/PRUNING.md) and hybrid RRF fusion (docs/VECTOR.md)
     report LOWER BOUNDS — previously visible only through the
     response-internal ``_pruned``/``_total_relation`` markers. Whenever
-    the total is inexact, or the request explicitly asked with
-    ``track_total_hits``, it renders as the modern object form
+    the total is inexact, the response states its relation (a convex
+    hybrid's exact union count: ``eq``), or the request explicitly asked
+    with ``track_total_hits``, it renders as the modern object form
     ``{"value": N, "relation": "eq"|"gte"}``. Passing
     ``track_total_hits: true`` (or the reference's integer-threshold
     form — totals here are exact whenever the count ran exhaustively,
@@ -759,12 +760,12 @@ def _render_total_hits(resp, body) -> None:
     pruned = resp.get("_pruned")
     if isinstance(pruned, dict) and pruned.get("total_relation"):
         relation = str(pruned["total_relation"])
-    elif resp.get("_total_relation") == "gte":
-        relation = "gte"
+    elif resp.get("_total_relation"):
+        relation = str(resp["_total_relation"])
     tth = (body or {}).get("track_total_hits")
     opted_in = tth is True or (isinstance(tth, int)
                                and not isinstance(tth, bool) and tth > 0)
-    if relation != "eq" or opted_in:
+    if relation != "eq" or opted_in or resp.get("_total_relation"):
         hits["total"] = {"value": hits["total"], "relation": relation}
 
 

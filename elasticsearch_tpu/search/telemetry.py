@@ -355,6 +355,16 @@ class QueryTracer:
             out["spans_dropped"] = self.spans_dropped
         return out
 
+    def launch_count(self) -> int:
+        """The mesh launches this request made: its ``kernel.dispatch``
+        spans that carry a ``launch`` id (``plan_exec._launch_locked``)
+        and ended, the program dispatched, whether or not its outputs
+        then reached the host."""
+        return sum(1 for i in range(self._n)
+                   if self._spans[i][_NAME] == "kernel.dispatch"
+                   and self._spans[i][_END]
+                   and "launch" in (self._spans[i][_ATTRS] or ()))
+
     def launches(self) -> List[list]:
         """``[launch, dispatch_start_ns, wait_start_ns, wait_end_ns]``
         of every mesh launch this request waited on, in
@@ -439,6 +449,9 @@ class _NullTracer:
 
     def annotations(self) -> dict:
         return {}
+
+    def launch_count(self) -> int:
+        return 0
 
     def launches(self) -> list:
         return []

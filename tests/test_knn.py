@@ -320,13 +320,27 @@ class TestKnnSearch:
         want = [d for d, _ in sorted(scores.items(),
                                      key=lambda kv: (-kv[1], kv[0]))][:10]
         assert [h["_id"] for h in r["hits"]["hits"]] == want
-        # convex fusion (no rank): additive scores
+        # convex fusion (no rank), exactly: the k winners join the
+        # query's matches, a hit scores its BM25 score plus boost times
+        # its kNN score, and hits.total counts the union
         rc = idx.search({"query": {"match": {"body": "term1"}},
                          "knn": {"field": "emb",
                                  "query_vector": q.tolist(), "k": 10},
                          "size": 5})
         assert rc["_hybrid"]["fusion"] == "convex"
-        assert len(rc["hits"]["hits"]) == 5
+        assert rc["_total_relation"] == "eq"
+        every = idx.search({"query": {"match": {"body": "term1"}},
+                            "size": len(vecs)})["hits"]
+        fused = {h["_id"]: np.float32(h["_score"]) for h in every["hits"]}
+        knn_s, knn_i = pkn.reference_knn_topk(
+            pkn.bf16_round(vecs), np.ones(len(vecs), bool), q, 10)
+        for doc, score in zip(knn_i, knn_s):
+            fused[str(doc)] = fused.get(str(doc), np.float32(0)) + score
+        want = sorted(fused.items(), key=lambda kv: (-kv[1], kv[0]))[:5]
+        assert rc["hits"]["total"] == len(fused) > every["total"]
+        assert [h["_id"] for h in rc["hits"]["hits"]] == [d for d, _ in want]
+        np.testing.assert_allclose([h["_score"] for h in rc["hits"]["hits"]],
+                                   [s for _, s in want], rtol=1e-6)
         idx.close()
 
     def test_knn_filter_restricts_candidates(self):

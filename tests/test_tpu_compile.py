@@ -619,3 +619,92 @@ def test_knn_mesh_program_reads_the_flat_embeddings_in_place(
     assert passes == []
     out, = jax.tree_util.tree_leaves(compiled.out_info)
     assert out.shape == (1, 2 + 5 * k) and out.dtype == jnp.int32
+
+
+# msmarco-hybrid-serial's staged embeddings on its one chip: 4 slots (2
+# live, 2 of headroom) of 32,768 rows (25,000 documents a shard), 768 wide
+HYBRID_SLOT_ROWS = 1 << 15
+
+
+def test_hybrid_mesh_program_compiles_for_one_chip(topo, monkeypatch):
+    """The hybrid program (``_mesh_query_program`` with ``knn``) as a
+    small 2-shard index launches it, rebuilt for one described chip with
+    the cell's flat embedding table: one kNN kernel and one tile kernel a
+    slot, each behind a conditional that skips a slot with no live
+    document or vector, no pass over a slot's embeddings outside the
+    kernel, and ONE packed answer first among the outputs."""
+    import re
+
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as PS
+
+    from elasticsearch_tpu.common.settings import Settings
+    from elasticsearch_tpu.index.index_service import IndexService
+    from elasticsearch_tpu.parallel import plan_exec
+    from elasticsearch_tpu.parallel.mesh import shard_mesh
+
+    monkeypatch.setenv("ES_TPU_PALLAS", "interpret")
+    build_program, seen = _recording_programs(monkeypatch)
+    idx = IndexService("tpucompile-hybrid", Settings({
+        "index.number_of_shards": 2, "index.refresh_interval": -1,
+    }), mapping={"properties": {
+        "body": {"type": "text", "analyzer": "whitespace"},
+        "emb": {"type": "dense_vector", "dims": KNN_D_PAD,
+                "similarity": "max_inner_product"}}})
+    idx._mesh_search = plan_exec.IndexMeshSearch(idx, mesh=shard_mesh(1))
+    rng = np.random.RandomState(6)
+    vecs = np.round(rng.standard_normal((600, KNN_D_PAD)) / 28, 6)
+    try:
+        for d in range(600):
+            idx.index_doc(str(d), {"body": " ".join(
+                f"w{t}" for t in rng.zipf(1.3, 12) % 200),
+                "emb": vecs[d].tolist()})
+        idx.refresh()
+        resp = idx.search({"query": {"match": {"body": "w1 w2 w3"}},
+                           "knn": {"field": "emb", "k": 10, "boost": 10,
+                                   "query_vector": vecs[7].tolist()},
+                           "size": 10})
+    finally:
+        idx.close()
+    assert resp["_plane"] == "mesh_pallas"
+    kk, k_knn, _sub, d_pad, _nd, metric, _interp = seen["kwargs"]["knn"]
+    spd = seen["kwargs"]["spd"]
+    assert (spd, d_pad) == (CELL_SLOTS, KNN_D_PAD)
+    sub = pkn.knn_tile_sub(HYBRID_SLOT_ROWS, KNN_D_PAD)
+    mesh = Mesh(np.asarray(topo.devices[:1]), ("shards",))
+    holder = seen["holder"]
+    program = build_program(
+        mesh, plan_exec._TemplateHolder(_for_tpu(holder.plan),
+                                        holder._key + "|tpu1"),
+        *seen["args"], **dict(seen["kwargs"], knn=(
+            kk, k_knn, sub, KNN_D_PAD, HYBRID_SLOT_ROWS, metric, False)))
+    sharded = NamedSharding(mesh, PS("shards"))
+
+    def on(a, shape=None):
+        return jax.ShapeDtypeStruct(np.shape(a) if shape is None else shape,
+                                    a.dtype, sharding=sharded)
+
+    seg, packed, loose, emb, scale, mask = seen["arrays"]
+    rows = spd * HYBRID_SLOT_ROWS
+    compiled = program.__wrapped__.lower(
+        jax.tree_util.tree_map(on, seg), on(packed),
+        jax.tree_util.tree_map(on, loose), on(emb, (rows, KNN_D_PAD)),
+        on(scale, (rows, 1)), on(mask, (rows, 1))).compile()
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines()
+             if "custom-call(" in line and "tpu_custom_call" in line]
+    assert sum("knn_tiles" in line for line in calls) == spd
+    assert len(calls) == 2 * spd
+    assert _conditionals(text) == 2 * spd
+    assert all(line in _branch_bodies(text) for line in calls)
+    wide = re.compile(r"bf16\[\d+,%d\]" % KNN_D_PAD)
+    assert [line.strip()[:160] for line in text.splitlines()
+            if wide.search(line)
+            and re.match(r"\s*(ROOT )?%[\w.-]+ = ", line)
+            and not any(op in line for op in (
+                " parameter(", " bitcast(", " tuple(",
+                " get-tuple-element(", "tpu_custom_call",
+                " conditional("))] == []
+    k = seen["args"][0]
+    packed_out = jax.tree_util.tree_leaves(compiled.out_info)[0]
+    assert (packed_out.shape, packed_out.dtype) == ((2 + 5 * k,), jnp.int32)
