@@ -2074,6 +2074,14 @@ class IndexService:
                 "kernel_lanes_planned_total": (
                     self._mesh_search.kernel_lanes_planned_total
                     if self._mesh_search is not None else 0),
+                # the kernel node groups' one-pass tile tables, and the
+                # geometry ladder's rungs below the staged tile
+                "kernel_table_passes_total": (
+                    self._mesh_search.kernel_table_passes_total
+                    if self._mesh_search is not None else 0),
+                "kernel_ladder_rungs_total": (
+                    self._mesh_search.kernel_ladder_rungs_total
+                    if self._mesh_search is not None else 0),
                 # fused on-device aggregations (ISSUE 13, docs/AGGS.md):
                 # agg'd queries whose whole agg set reduced inside the
                 # mesh program vs those that fell back to the host

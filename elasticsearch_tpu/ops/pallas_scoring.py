@@ -262,8 +262,116 @@ class QueryLane(NamedTuple):
     weight: float  # idf * boost (0 disables the lane)
 
 
-# build_tile_tables' per-lane key offset: far above any doc id (< 2**31)
+# build_tile_tables_sets' per-lane key offset: far above any doc id
+# (< 2**31)
 _LANE_KEY = np.int64(1) << 40
+
+
+def build_tile_tables_sets(
+    lane_sets: Sequence[Sequence[QueryLane]],
+    bmin: np.ndarray,
+    bmax: np.ndarray,
+    geom: TileGeometry,
+    t_pad: Optional[int] = None,
+    cb: Optional[int] = None,
+    bases: Optional[Sequence[int]] = None,
+    ladder: bool = False,
+) -> Tuple[np.ndarray, np.ndarray, np.ndarray, int, TileGeometry]:
+    """Host-side query planning for N lane sets at once, on one shared
+    geometry and ``t_pad``: per (set, tile, lane) the absolute block-row
+    window [row_lo, row_hi) covering the tile's doc range. Set i's lanes
+    index ``bmin``/``bmax`` from row ``bases[i]`` (0 for every set when
+    None), so several segments' block bounds may lie in one array, and
+    its row windows are relative to that base. Returns (row_lo, row_hi
+    [N, n_tiles, t_pad] i32, weights [N, 1, t_pad] f32, CB, geometry)
+    where CB is the uniform pow2 window bucket of all N sets.
+
+    The kernel DMAs TWO consecutive CB-aligned windows starting at
+    align(row_lo, CB) — rows [align(lo), align(lo) + 2*CB) — so any window
+    with row_hi - row_lo <= CB is fully covered regardless of where the
+    aligned start lands (it can sit up to CB-1 rows before row_lo).
+
+    Every live lane (blocks and a nonzero weight) of every set is
+    planned in one pass: the lanes' block runs are gathered into one
+    array whose keys are offset by (set, lane), so one sortedness check
+    and one searchsorted per bound answer every (set, tile, lane).
+    ``ladder``: where the covering window exceeds the kernel bound at
+    ``geom``, halve the tile until it fits (the geometry ladder: big
+    tiles are fastest, as the per-grid-step cost dominates), reusing the
+    gather; a rung costs the two searchsorted calls. The ladder ends in
+    the bound's ValueError at tile_sub 32 (where non-overlapping sorted
+    block ranges always fit: need <= sub + 2 blocks) or where the doc
+    space shrinks the tile below the rung asked for."""
+    n_sets = len(lane_sets)
+    t_pad = t_pad or next_pow2(max(max(map(len, lane_sets), default=0), 1))
+    # one row a live lane: (set * t_pad + lane, block_start, count, weight)
+    live = np.array(
+        [(i * t_pad + j, lane.block_start, lane.block_count, lane.weight)
+         for i, lanes in enumerate(lane_sets)
+         for j, lane in enumerate(lanes)
+         if lane.block_count > 0 and lane.weight != 0.0],
+        np.float64).reshape(-1, 4)
+    lane_id = live[:, 0].astype(np.int64)
+    s = live[:, 1].astype(np.int64)
+    c = live[:, 2].astype(np.int64)
+    first_pos = np.cumsum(c) - c  # each run's place in the gather
+    base = 0 if bases is None else np.asarray(bases, np.int64)[
+        lane_id // t_pad]
+    rows = np.arange(int(c.sum())) + np.repeat(base + s - first_pos, c)
+    # a doc id is under 2**31 (and a block's min/max doc lies in
+    # [-1, 2**31]), so lane-offset keys keep the runs apart: the
+    # gather is sorted exactly where every run is
+    key = lane_id * _LANE_KEY
+    run_key = np.repeat(key, c)
+    kmin = bmin[rows].astype(np.int64) + run_key
+    kmax = bmax[rows].astype(np.int64) + run_key
+    unsorted = (np.diff(kmin) < 0) | (np.diff(kmax) < 0)
+    if unsorted.any():
+        bad = int(np.repeat(lane_id, c)[int(unsorted.argmax())])
+        where = "" if n_sets == 1 else f"set {bad // t_pad}, "
+        raise ValueError(
+            f"{where}lane {bad % t_pad}: per-block doc ranges not sorted "
+            f"(empty mid-run block or unsorted postings) — coverage would "
+            f"be silently wrong")
+    sub, g = geom.tile_sub, geom
+    while True:
+        # first block whose max_doc >= tile start; first block whose
+        # min_doc >= tile end — [first, end) covers the tile
+        w = g.tile_w
+        q = (np.arange(g.n_tiles, dtype=np.int64) * w)[:, None] + key
+        first = np.searchsorted(kmax, q, side="left") - first_pos
+        end = np.searchsorted(kmin, q + w, side="left") - first_pos
+        end = np.maximum(end, first)
+        need = max(1, int((end - first).max())) if len(key) else 1
+        # mosaic requires sublane block sizes divisible by 8; the
+        # double-window scheme covers any alignment as long as cov <= cb,
+        # and the segment padding (CB_MAX rows) must fit both windows:
+        # cb <= CB_MAX // 2
+        cb_req = next_pow2(max(need, 8))
+        if cb_req <= CB_MAX // 2:
+            break
+        if not ladder or sub <= 32 or g.tile_sub < sub:
+            raise ValueError(
+                f"per-tile covering window of {need} blocks exceeds the "
+                f"kernel bound {CB_MAX // 2}; use a smaller tile_sub")
+        sub //= 2
+        g = tile_geometry(geom.nd_pad, sub)
+    if cb is not None:
+        if cb < cb_req:
+            raise ValueError(f"cb={cb} too small, need {cb_req}")
+        if cb > CB_MAX // 2 or cb & (cb - 1):
+            raise ValueError(
+                f"cb={cb} must be a power of two <= {CB_MAX // 2} (the "
+                f"second DMA window must stay inside the sentinel padding)")
+        cb_req = cb
+    row_lo = np.zeros((n_sets, g.n_tiles, t_pad), dtype=np.int32)
+    row_hi = np.zeros((n_sets, g.n_tiles, t_pad), dtype=np.int32)
+    weights = np.zeros((n_sets, 1, t_pad), dtype=np.float32)
+    at = (lane_id // t_pad, slice(None), lane_id % t_pad)
+    row_lo[at] = (s + first).T
+    row_hi[at] = (s + end).T
+    weights[at] = live[:, 3:]
+    return row_lo, row_hi, weights, cb_req, g
 
 
 def build_tile_tables(
@@ -274,74 +382,12 @@ def build_tile_tables(
     t_pad: Optional[int] = None,
     cb: Optional[int] = None,
 ) -> Tuple[np.ndarray, np.ndarray, np.ndarray, int]:
-    """Host-side query planning: per (tile, lane) the absolute block-row
-    window [row_lo, row_hi) covering the tile's doc range, padded to
-    T_pad lanes. Returns (row_lo, row_hi [n_tiles, T_pad] i32,
-    weights [1, T_pad] f32, CB) where CB is the uniform pow2 window bucket.
-    The kernel DMAs TWO consecutive CB-aligned windows starting at
-    align(row_lo, CB) — rows [align(lo), align(lo) + 2*CB) — so any window
-    with row_hi - row_lo <= CB is fully covered regardless of where the
-    aligned start lands (it can sit up to CB-1 rows before row_lo).
-
-    Every live lane (blocks and a nonzero weight) is planned in one pass:
-    the lanes' block runs are gathered into one array whose keys are
-    offset by lane, so one sortedness check and one searchsorted per
-    bound answer every (tile, lane)."""
-    w = geom.tile_w
-    n_tiles = geom.n_tiles
-    t_pad = t_pad or next_pow2(max(len(lanes), 1))
-    row_lo = np.zeros((n_tiles, t_pad), dtype=np.int32)
-    row_hi = np.zeros((n_tiles, t_pad), dtype=np.int32)
-    weights = np.zeros((1, t_pad), dtype=np.float32)
-    need = 1
-    live = [j for j, lane in enumerate(lanes)
-            if lane.block_count > 0 and lane.weight != 0.0]
-    if live:
-        j = np.asarray(live, np.int64)
-        s = np.asarray([lanes[i].block_start for i in live], np.int64)
-        c = np.asarray([lanes[i].block_count for i in live], np.int64)
-        first_pos = np.cumsum(c) - c  # each run's place in the gather
-        rows = np.arange(int(c.sum())) + np.repeat(s - first_pos, c)
-        # a doc id is under 2**31 (and a block's min/max doc lies in
-        # [-1, 2**31]), so lane-offset keys keep the runs apart: the
-        # gather is sorted exactly where every run is
-        key = np.repeat(j * _LANE_KEY, c)
-        kmin = bmin[rows].astype(np.int64) + key
-        kmax = bmax[rows].astype(np.int64) + key
-        unsorted = (np.diff(kmin) < 0) | (np.diff(kmax) < 0)
-        if unsorted.any():
-            bad = int(np.repeat(j, c)[int(unsorted.argmax())])
-            raise ValueError(
-                f"lane {bad}: per-block doc ranges not sorted (empty mid-run "
-                f"block or unsorted postings) — coverage would be silently "
-                f"wrong")
-        # first block whose max_doc >= tile start; first block whose
-        # min_doc >= tile end — [first, end) covers the tile
-        q = (np.arange(n_tiles, dtype=np.int64) * w)[:, None] + j * _LANE_KEY
-        first = np.searchsorted(kmax, q, side="left") - first_pos
-        end = np.searchsorted(kmin, q + w, side="left") - first_pos
-        end = np.maximum(end, first)
-        row_lo[:, j] = s + first
-        row_hi[:, j] = s + end
-        weights[0, j] = [lanes[i].weight for i in live]
-        need = max(need, int((end - first).max()))
-    # mosaic requires sublane block sizes divisible by 8; the double-window
-    # scheme covers any alignment as long as cov <= cb, and the segment
-    # padding (CB_MAX rows) must fit both windows: cb <= CB_MAX // 2
-    cb_req = next_pow2(max(need, 8))
-    if cb_req > CB_MAX // 2:
-        raise ValueError(
-            f"per-tile covering window of {need} blocks exceeds the kernel "
-            f"bound {CB_MAX // 2}; use a smaller tile_sub")
-    if cb is not None:
-        if cb < cb_req:
-            raise ValueError(f"cb={cb} too small, need {cb_req}")
-        if cb > CB_MAX // 2 or cb & (cb - 1):
-            raise ValueError(
-                f"cb={cb} must be a power of two <= {CB_MAX // 2} (the "
-                f"second DMA window must stay inside the sentinel padding)")
-        cb_req = cb
-    return row_lo, row_hi, weights, cb_req
+    """``build_tile_tables_sets`` for one lane set at ``geom`` (no
+    ladder): (row_lo, row_hi [n_tiles, T_pad] i32, weights [1, T_pad]
+    f32, CB)."""
+    row_lo, row_hi, weights, cb, _ = build_tile_tables_sets(
+        [lanes], bmin, bmax, geom, t_pad=t_pad, cb=cb)
+    return row_lo[0], row_hi[0], weights[0], cb
 
 
 def union_query_lanes(

@@ -1361,6 +1361,11 @@ class IndexMeshSearch:
         # terms land on the kernel) and lanes of kernel nodes
         self.plan_blocks_expanded_total = 0
         self.kernel_lanes_planned_total = 0
+        # and how the kernel nodes' tile tables were built: one pass over
+        # every pair a kernel node group, and the geometry ladder's rungs
+        # below the staged tile those passes descended
+        self.kernel_table_passes_total = 0
+        self.kernel_ladder_rungs_total = 0
         # and the bf16 embedding bytes those passes streamed, a launch
         self.embedding_bytes_streamed_total = 0
         # devices of the mesh a launch ran on, and those of them holding
@@ -2637,8 +2642,12 @@ class IndexMeshSearch:
                             c.kernel_lanes for c in ctxs.values())
                     used_pallas = False
                     if session is not None:
-                        used_pallas = executor.harmonize_kernel_nodes(
-                            plans) > 0
+                        passes, rungs = executor.harmonize_kernel_nodes(
+                            plans)
+                        used_pallas = passes > 0
+                        with self._counter_lock:
+                            self.kernel_table_passes_total += passes
+                            self.kernel_ladder_rungs_total += rungs
                     tracer.stop("plan_build", t_plan)
                     on_kernel_launch(self.svc.name, plane)
                     outs = executor.execute(
@@ -3014,10 +3023,11 @@ class IndexMeshSearch:
             t_stage = bt.start("staging")
             # shared batched tables: per-slot unions on ONE collective
             # geometry (a dense union on ANY slot shrinks everyone's
-            # tile); build_tile_tables_batched owns the union/pad
-            # contract — same code the host rung runs
-            unions = [psc.union_query_lanes(lanes)[0]
-                      for lanes in lane_sets]
+            # tile), every pair's planned in one pass as the serial
+            # path's (build_tile_tables_sets); union_query_lanes owns
+            # the union and its per-query weights — as the host rung's
+            unions, union_w = zip(*(psc.union_query_lanes(lanes)
+                                    for lanes in lane_sets))
             t_pad = max(next_pow2(max(len(u), 1)) for u in unions)
             sub = geom.tile_sub
             if pruning:
@@ -3041,32 +3051,27 @@ class IndexMeshSearch:
                     sub = sub_p
                 else:
                     pruning = False  # corpus too small to prune here
-            while True:
-                g = geom if sub == geom.tile_sub else psc.tile_geometry(
-                    geom.nd_pad, sub)
-                try:
-                    tables = []
-                    for lanes, seg in zip(lane_sets, executor.segments):
-                        bmin, bmax = session["meta"][id(seg)][:2]
-                        tables.append(psc.build_tile_tables_batched(
-                            lanes, bmin, bmax, g, t_pad=t_pad))
-                    break
-                except ValueError:
-                    if sub <= 32 or g.tile_sub < sub:
-                        return None  # no shared geometry: host rung
-                    sub //= 2
-            cb = max(t[3] for t in tables)
+            try:
+                rl_pairs, rh_pairs, _, cb, g = psc.build_tile_tables_sets(
+                    unions, *session["bounds"],
+                    geom if sub == geom.tile_sub else psc.tile_geometry(
+                        geom.nd_pad, sub),
+                    t_pad=t_pad, bases=[session["bound_base"][id(seg)]
+                                        for seg in executor.segments],
+                    ladder=True)
+            except ValueError:
+                return None  # no shared geometry: host rung
             live_key = ("k_live_t" if g.tile_sub == geom.tile_sub
                         else executor.ensure_kernel_live(g.tile_sub))
             n_slots = executor.n_slots
-            n_tiles = tables[0][0].shape[0]
+            n_tiles = g.n_tiles
             rl = np.zeros((n_slots, n_tiles, t_pad), np.int32)
             rh = np.zeros((n_slots, n_tiles, t_pad), np.int32)
             w_all = np.zeros((n_slots, q_pad, t_pad), np.float32)
             for i, slot in enumerate(executor.slots):
-                rl[slot] = tables[i][0]
-                rh[slot] = tables[i][1]
-                w_all[slot, : q_batch] = tables[i][2]
+                rl[slot] = rl_pairs[i]
+                rh[slot] = rh_pairs[i]
+                w_all[slot, : q_batch, : union_w[i].shape[1]] = union_w[i]
             # filler slots/queries keep zero tables/weights: their live
             # masks are all-dead and zero weights score nothing
             tps = psc.TILES_PER_STEP
@@ -3480,7 +3485,7 @@ class MeshPlanExecutor:
         self.sort_meta: Dict[str, dict] = {}
         self._numeric_ords: Dict[str, np.ndarray] = {}
         # lazily-staged tile-kernel plane (ensure_kernel): False =
-        # unavailable, dict = {geom, meta: {id(seg): (bmin, bmax)}, mode}
+        # unavailable, dict = _kernel_session's
         self._kernel = None
         # set when the HBM budget (not a fault) turned a staging away —
         # the ladder reports the demotion as decision reason hbm_budget.
@@ -3879,8 +3884,8 @@ class MeshPlanExecutor:
         self._seg_staged = staged
         self._knn = knn_new
         if kernel is not None:
-            self._kernel = {"geom": kernel["geom"], "meta": meta,
-                            "codec": kernel["codec"]}
+            self._kernel = self._kernel_session(kernel["geom"], meta,
+                                                kernel["codec"])
         dur = (_time.monotonic() - t0) * 1000.0
         self._account(
             "mesh_slot_tables", "seg_stacked",
@@ -4116,6 +4121,31 @@ class MeshPlanExecutor:
                     return None
         return dict(self._kernel, mode=mode)
 
+    def _kernel_session(self, geom, meta: dict, codec: str) -> dict:
+        """The staged kernel plane's host context: the shared geometry,
+        the codec, ONE flat int64 copy of every segment's per-block doc
+        bounds (``bounds``) with each segment's first row in it
+        (``bound_base``), and each segment's (bmin, bmax, bfmax) under
+        ``meta``, its bounds views of the flat copy (held once). A
+        request's tile-table pass gathers every pair's lane runs from
+        the flat copy with one fancy-index."""
+        segs = [seg for seg in self.segments if id(seg) in meta]
+        bounds = tuple(
+            np.concatenate([np.zeros(0, np.int64)]
+                           + [meta[id(seg)][k] for seg in segs])
+            for k in (0, 1))
+        session = {"geom": geom, "codec": codec, "bounds": bounds,
+                   "meta": {}, "bound_base": {}}
+        base = 0
+        for seg in segs:
+            end = base + len(meta[id(seg)][0])
+            session["meta"][id(seg)] = (bounds[0][base:end],
+                                        bounds[1][base:end],
+                                        meta[id(seg)][2])
+            session["bound_base"][id(seg)] = base
+            base = end
+        return session
+
     def _stage_kernel_plane(self, geom, codec: str, n_rows: int) -> None:
         """One staging ATTEMPT of the kernel plane (runs inside
         run_staged's retry loop — the injection hooks below re-consult
@@ -4191,7 +4221,7 @@ class MeshPlanExecutor:
         # (register-then-commit — the ledger never holds bytes for a
         # generation that failed to install)
         self.postings_codec = codec
-        self._kernel = {"geom": geom, "meta": meta, "codec": codec}
+        self._kernel = self._kernel_session(geom, meta, codec)
         dur = (_time.monotonic() - t0) * 1000.0
         self._account(kind_postings, "k_postings",
                       self.postings_bytes_staged, duration_ms=dur)
@@ -4406,12 +4436,17 @@ class MeshPlanExecutor:
                           duration_ms=(_time.monotonic() - t0) * 1000.0)
         return key
 
-    def harmonize_kernel_nodes(self, plans: List[PlanNode]) -> int:
+    def harmonize_kernel_nodes(self, plans: List[PlanNode]
+                               ) -> Tuple[int, int]:
         """Finalize every deferred mesh kernel node so table shapes agree
         across the whole segment set: one (tile_sub, t_pad, cb) for each
         aligned node group, chosen by the geometry ladder collectively
-        (a dense term on ANY shard shrinks everyone's tile). Returns the
-        number of kernel node groups finalized; raises
+        (a dense term on ANY shard shrinks everyone's tile). A group's
+        tables are ONE ``build_tile_tables_sets`` pass over every pair,
+        reading the session's flat block bounds, and each rung of the
+        ladder reuses its gather; each node gets views of the group's
+        arrays. Returns (kernel node groups finalized, ladder rungs
+        descended below the staged tile over them); raises
         PlanStructureMismatch when no shared geometry exists (caller
         retries with scatter nodes)."""
         from elasticsearch_tpu.index.segment import next_pow2
@@ -4431,41 +4466,37 @@ class MeshPlanExecutor:
 
         walk(plans)
         if not groups:
-            return 0
+            return 0, 0
         session = self._kernel
         if not isinstance(session, dict):
             raise PlanStructureMismatch("kernel plane not staged")
         geom = session["geom"]
+        bmin, bmax = session["bounds"]
         tps = psc.TILES_PER_STEP
+        rungs = 0
         for nodes in groups:
             if any(n._mesh_lanes is None for n in nodes):
                 raise PlanStructureMismatch(
                     "kernel/scatter node mix across shards")
-            t_pad = max(next_pow2(max(len(n._mesh_lanes), 1))
-                        for n in nodes)
-            sub = geom.tile_sub
-            while True:
-                g = geom if sub == geom.tile_sub else psc.tile_geometry(
-                    geom.nd_pad, sub)
-                try:
-                    tables = [psc.build_tile_tables(
-                        n._mesh_lanes, n._mesh_bmin, n._mesh_bmax, g,
-                        t_pad=t_pad) for n in nodes]
-                    break
-                except ValueError:
-                    # covering window exceeded the kernel bound somewhere
-                    # (or malformed ranges at the ladder floor)
-                    if sub <= 32 or g.tile_sub < sub:
-                        raise PlanStructureMismatch(
-                            "no shared kernel geometry for this query")
-                    sub //= 2
-            cb = max(t[3] for t in tables)
+            lane_sets = [n._mesh_lanes for n in nodes]
+            t_pad = next_pow2(max(max(map(len, lane_sets)), 1))
+            try:
+                row_lo, row_hi, weights, cb, g = psc.build_tile_tables_sets(
+                    lane_sets, bmin, bmax, geom, t_pad=t_pad,
+                    bases=[n._mesh_base for n in nodes], ladder=True)
+            except ValueError:
+                # the covering window exceeded the kernel bound somewhere
+                # at the ladder's floor, or malformed ranges
+                raise PlanStructureMismatch(
+                    "no shared kernel geometry for this query") from None
+            rungs += (geom.tile_sub // g.tile_sub).bit_length() - 1
             live_key = ("k_live_t" if g.tile_sub == geom.tile_sub
                         else self.ensure_kernel_live(g.tile_sub))
-            for n, (rl, rh, w, _cb) in zip(nodes, tables):
-                n.finalize_mesh(rl, rh, w, cb=cb, sub=g.tile_sub,
-                                live_key=live_key, tiles_per_step=tps)
-        return len(groups)
+            for i, n in enumerate(nodes):
+                n.finalize_mesh(row_lo[i], row_hi[i], weights[i], cb=cb,
+                                sub=g.tile_sub, live_key=live_key,
+                                tiles_per_step=tps)
+        return len(groups), rungs
 
     def numeric_ordinals(self, field: str) -> np.ndarray:
         """Sorted distinct values of a numeric field over the staged

@@ -262,18 +262,18 @@ class PallasScoreTermsNode(PlanNode):
         # reads the bit-packed word array and decodes in-kernel
         self.codec = codec
         self._mesh_lanes = None
-        self._mesh_bmin = None
-        self._mesh_bmax = None
+        self._mesh_base = None
 
     @classmethod
-    def mesh_deferred(cls, lanes, bmin, bmax, min_match, *,
+    def mesh_deferred(cls, lanes, base: int, min_match, *,
                       interpret: bool,
                       codec: str = "raw") -> "PallasScoreTermsNode":
         """Node for the MESH plane with table building deferred: lanes are
         shard-local, but table geometry (tile count, t_pad, cb, sub) must
         be uniform across the whole stacked segment set and is only known
-        once every shard's plan exists. ``bmin``/``bmax`` are the shard
-        segment's per-block doc ranges (tile-size independent)."""
+        once every shard's plan exists. ``base`` is the shard segment's
+        first row in the kernel session's flat per-block doc ranges
+        (tile-size independent)."""
         self = cls.__new__(cls)
         self.row_lo = self.row_hi = self.kweights = None
         self.min_match = np.float32(min_match)
@@ -284,8 +284,7 @@ class PallasScoreTermsNode(PlanNode):
         self.tiles_per_step = 1
         self.codec = codec
         self._mesh_lanes = list(lanes)
-        self._mesh_bmin = bmin
-        self._mesh_bmax = bmax
+        self._mesh_base = base
         return self
 
     def finalize_mesh(self, row_lo, row_hi, kweights, *, cb: int, sub: int,

@@ -263,24 +263,15 @@ def _pallas_score_terms_node(segment, arrs, min_match):
     from elasticsearch_tpu.ops import pallas_scoring as psc
 
     qlanes = [psc.QueryLane(s, c, w) for s, c, w, _ in lanes]
-    # geometry ladder: big tiles are fastest (per-grid-step overhead
-    # dominates), but a dense term's per-tile covering window can exceed
-    # the kernel bound there — retry with smaller tiles. Non-overlapping
-    # sorted block ranges guarantee the window fits at tile_sub <= 32
-    # (need <= sub + 2 blocks), so the ladder always terminates on the
-    # kernel path for any well-formed segment.
-    sub = geom.tile_sub
-    while True:
-        g = geom if sub == geom.tile_sub else psc.tile_geometry(
-            geom.nd_pad, sub)
-        try:
-            row_lo, row_hi, kweights, cb = psc.build_tile_tables(
-                qlanes, segment.kernel_bmin, segment.kernel_bmax, g)
-            break
-        except ValueError:
-            if sub <= 32 or g.tile_sub < sub:
-                return None  # malformed ranges; scatter path handles it
-            sub //= 2
+    # the geometry ladder: a dense term's per-tile covering window can
+    # exceed the kernel bound at the staged tile — smaller tiles serve it
+    try:
+        row_lo, row_hi, kweights, cb, g = psc.build_tile_tables_sets(
+            [qlanes], segment.kernel_bmin, segment.kernel_bmax, geom,
+            ladder=True)
+    except ValueError:
+        return None  # malformed ranges; scatter path handles it
+    row_lo, row_hi, kweights = row_lo[0], row_hi[0], kweights[0]
     live_key = ("k_live_t" if g.tile_sub == geom.tile_sub
                 else segment.kernel_live_t_for(g.tile_sub))
     node = P.PallasScoreTermsNode(
@@ -297,11 +288,11 @@ def _pallas_score_terms_node(segment, arrs, min_match):
 
 def _mesh_pallas_score_terms_node(segment, arrs, min_match, session):
     """Stackable tile-kernel node for the MESH data plane. ``session`` is
-    the executor's staged-kernel context ({geom, meta: {id(segment):
-    (bmin, bmax)}, mode}). Same lane eligibility rules as the host path
-    (_pallas_score_terms_node), but an EMPTY lane set stays on the kernel:
-    a term missing from one shard's dictionary must not flip that shard's
-    node type (the skeleton must match across the mesh)."""
+    the executor's staged-kernel context (``MeshPlanExecutor.
+    _kernel_session``, with ``mode``). Same lane eligibility rules as the
+    host path (_pallas_score_terms_node), but an EMPTY lane set stays on
+    the kernel: a term missing from one shard's dictionary must not flip
+    that shard's node type (the skeleton must match across the mesh)."""
     from elasticsearch_tpu.ops import pallas_scoring as psc
 
     lanes = arrs["lanes_meta"]
@@ -309,12 +300,12 @@ def _mesh_pallas_score_terms_node(segment, arrs, min_match, session):
         return None
     if not all(w > 0 for _, _, w, _ in lanes):
         return None  # see _pallas_score_terms_node: score>0 match rule
-    meta = session["meta"].get(id(segment))
-    if meta is None:
+    base = session["bound_base"].get(id(segment))
+    if base is None:
         return None  # segment not part of the staged mesh set
     qlanes = [psc.QueryLane(s, c, w) for s, c, w, _ in lanes]
     return P.PallasScoreTermsNode.mesh_deferred(
-        qlanes, meta[0], meta[1], min_match,
+        qlanes, base, min_match,
         interpret=(session["mode"] == "interpret"),
         codec=session.get("codec", "raw"))
 

@@ -13,6 +13,15 @@ replaced, kept below as oracles:
   ``t_pad``, a given ``cb``), on several geometries and a ladder rung
   below the staged one; each ``ValueError`` still raises, with the same
   lane named;
+- ``build_tile_tables_sets`` plans every (shard, segment) pair of a
+  kernel node group in one pass over one flat array of block bounds:
+  each pair's tables equal the per-pair loop's for 1, 2, 3 and 8 pairs
+  (a pair with no live lane among them), and where a dense term forces
+  one or two rungs down, the rung, ``cb`` and each node's ``sub`` and
+  ``live_key`` that ``harmonize_kernel_nodes`` sets are the per-pair
+  ladder's; an unsorted run, or blocks no rung fits, is a
+  ``PlanStructureMismatch`` as before; ``_stats`` counts one pass a
+  kernel node group and the rungs descended;
 - the scatter node's nine arrays and ``kinds`` equal the per-block
   loop's bit for bit: BM25 at default and other ``k1``/``b``, a
   similarity that reads ``ttf``, several kinds in one node, a term one
@@ -273,6 +282,197 @@ class TestTileTablesOnePass:
         want = oracle_tile_tables(union, bmin, bmax, geom, t_pad=t_pad)
         assert_tables_equal((rl, rh, want[2], cb), want)
         assert w.shape == (3, t_pad)
+
+
+# ---------------------------------------------------------------------------
+# build_tile_tables_sets: every (shard, segment) pair's tables in one pass
+# ---------------------------------------------------------------------------
+
+
+def oracle_harmonize(lane_sets, bounds, geom):
+    """The per-pair ladder harmonize_kernel_nodes ran before one pass
+    served every pair: each rung rebuilt every pair's tables. Returns
+    (tables a pair, shared cb, geometry), or raises
+    PlanStructureMismatch where no rung serves."""
+    t_pad = max(next_pow2(max(len(lanes), 1)) for lanes in lane_sets)
+    sub = geom.tile_sub
+    while True:
+        g = geom if sub == geom.tile_sub else tile_geometry(geom.nd_pad, sub)
+        try:
+            tables = [oracle_tile_tables(lanes, bmin, bmax, g, t_pad=t_pad)
+                      for lanes, (bmin, bmax) in zip(lane_sets, bounds)]
+            break
+        except ValueError:
+            if sub <= 32 or g.tile_sub < sub:
+                raise plan_exec.PlanStructureMismatch("no shared geometry")
+            sub //= 2
+    return tables, max(t[3] for t in tables), g
+
+
+def flat(bounds):
+    """One array of every pair's block bounds, and each pair's first row
+    in it: what the kernel session keeps as ``bounds``/``bound_base``."""
+    bases = np.cumsum([0] + [len(b) for b, _ in bounds[:-1]])
+    return (np.concatenate([b for b, _ in bounds]),
+            np.concatenate([b for _, b in bounds]), [int(b) for b in bases])
+
+
+def dense_run(first_doc, n_docs):
+    """Block bounds of a term in every document of [first_doc, first_doc
+    + n_docs), 128 postings a block."""
+    docs = np.arange(first_doc, first_doc + n_docs)
+    nb = -(-n_docs // LANE)
+    return (docs[::LANE].astype(np.int64),
+            docs[np.minimum(np.arange(1, nb + 1) * LANE, n_docs) - 1]
+            .astype(np.int64))
+
+
+class _Staged:
+    """What harmonize_kernel_nodes reads of a staged executor: the kernel
+    session's geometry and flat bounds, and the ladder's live masks."""
+
+    harmonize = plan_exec.MeshPlanExecutor.harmonize_kernel_nodes
+
+    def __init__(self, geom, bmin, bmax):
+        self._kernel = {"geom": geom, "bounds": (bmin, bmax)}
+
+    def ensure_kernel_live(self, sub):
+        return f"k_live_t_{sub}"
+
+
+def deferred(lane_sets, bases):
+    return [P.PallasScoreTermsNode.mesh_deferred(lanes, base, 1,
+                                                 interpret=True)
+            for lanes, base in zip(lane_sets, bases)]
+
+
+class TestTileTablesAcrossPairs:
+    @pytest.mark.parametrize("seed", range(3))
+    @pytest.mark.parametrize("n_pairs", [1, 2, 3, 8])
+    def test_random_pairs_match_the_per_pair_loop(self, seed, n_pairs):
+        rng = np.random.RandomState(200 + seed)
+        bounds, sets = [], []
+        for i in range(n_pairs):
+            bmin, bmax, runs, nd_pad = block_runs(rng, 3000 + 500 * i, 12,
+                                                  400)
+            bounds.append((bmin, bmax))
+            lanes = random_lanes(rng, runs, rng.randint(1, 9))
+            if i == 1:  # a pair with no live lane: empty runs, weight 0
+                lanes = [QueryLane(s, 0, 1.0) if k % 2 else
+                         QueryLane(s, c, 0.0)
+                         for k, (s, c, _) in enumerate(lanes)]
+            sets.append(lanes)
+        bmin, bmax, bases = flat(bounds)
+        geom = tile_geometry(8192, 4)
+        t_pad = max(next_pow2(max(len(lanes), 1)) for lanes in sets)
+        for given in (None, 2 * t_pad):
+            want = [oracle_tile_tables(lanes, b0, b1, geom,
+                                       t_pad=given or t_pad)
+                    for lanes, (b0, b1) in zip(sets, bounds)]
+            rl, rh, w, cb, g = psc.build_tile_tables_sets(
+                sets, bmin, bmax, geom, t_pad=given, bases=bases)
+            assert g == geom and cb == max(t[3] for t in want)
+            assert rl.shape == (n_pairs, geom.n_tiles, given or t_pad)
+            for i, t in enumerate(want):
+                assert_tables_equal((rl[i], rh[i], w[i], t[3]), t)
+        # a given cb at or above every pair's need is the one returned
+        rl, rh, w, cb, _ = psc.build_tile_tables_sets(
+            sets, bmin, bmax, geom, cb=32, bases=bases)
+        assert cb == 32
+        for i, (lanes, (b0, b1)) in enumerate(zip(sets, bounds)):
+            assert_tables_equal((rl[i], rh[i], w[i], cb), oracle_tile_tables(
+                lanes, b0, b1, geom, t_pad=t_pad, cb=32))
+        if n_pairs > 1:
+            assert not w[1].any() and not rl[1].any() and not rh[1].any()
+
+    @pytest.mark.parametrize("n_pairs", [1, 2, 3, 8])
+    @pytest.mark.parametrize("first_doc,rungs", [(0, 0), (0, 1), (1, 2)])
+    def test_dense_term_takes_the_per_pair_ladder_rung(self, n_pairs,
+                                                       first_doc, rungs):
+        """A dense term on ONE pair shrinks every pair's tile: 16,384
+        aligned documents fit at half the staged tile, one document later
+        they need a quarter. The pass chooses the rung, the shared cb, the
+        tables and each node's sub and live_key as the per-pair ladder."""
+        rng = np.random.RandomState(300 + n_pairs)
+        bounds, sets = [], []
+        for i in range(n_pairs):
+            bmin, bmax, runs, _ = block_runs(rng, 5000, 10, 600)
+            lanes = random_lanes(rng, runs, 4)
+            if i == n_pairs - 1 and rungs:
+                dmin, dmax = dense_run(first_doc, 1 << 14)
+                lanes.append(QueryLane(len(bmin), len(dmin), 1.25))
+                bmin = np.concatenate([bmin, dmin])
+                bmax = np.concatenate([bmax, dmax])
+            bounds.append((bmin, bmax))
+            sets.append(lanes)
+        staged = tile_geometry(1 << 15, 128)
+        want, want_cb, want_g = oracle_harmonize(sets, bounds, staged)
+        assert want_g.tile_sub == 128 >> rungs
+        bmin, bmax, bases = flat(bounds)
+        rl, rh, w, cb, g = psc.build_tile_tables_sets(
+            sets, bmin, bmax, staged, t_pad=want[0][0].shape[1],
+            bases=bases, ladder=True)
+        assert g == want_g and cb == want_cb
+        nodes = deferred(sets, bases)
+        assert _Staged(staged, bmin, bmax).harmonize(nodes) == (1, rungs)
+        live_key = "k_live_t" if not rungs else f"k_live_t_{128 >> rungs}"
+        for i, (node, t) in enumerate(zip(nodes, want)):
+            assert_tables_equal((rl[i], rh[i], w[i], cb), t[:3] + (cb,))
+            assert_tables_equal((node.row_lo, node.row_hi, node.kweights,
+                                 node.cb), t[:3] + (want_cb,))
+            assert (node.sub, node.live_key) == (want_g.tile_sub, live_key)
+            assert node.key() == nodes[0].key()
+            # a node holds views of the group's arrays, not copies
+            assert node.row_lo.base is not None
+
+    def test_without_the_ladder_the_staged_tile_raises(self):
+        dmin, dmax = dense_run(0, 1 << 14)
+        with pytest.raises(ValueError, match="exceeds the kernel bound"):
+            psc.build_tile_tables_sets(
+                [[QueryLane(0, len(dmin), 1.0)]], dmin, dmax,
+                tile_geometry(1 << 15, 128))
+
+    def test_no_rung_fits_overlapping_blocks(self):
+        """Blocks that each span the whole doc space are sorted but fit no
+        rung: the ladder walks to its floor and the group falls back."""
+        n = 100
+        bmin = np.zeros(n, np.int64)
+        bmax = np.full(n, (1 << 15) - 1, np.int64)
+        sets = [[QueryLane(0, n, 1.0)], [QueryLane(0, 2, 1.0)]]
+        bounds = [(bmin, bmax), (bmin[:2], bmax[:2])]
+        staged = tile_geometry(1 << 15, 128)
+        with pytest.raises(plan_exec.PlanStructureMismatch):
+            oracle_harmonize(sets, bounds, staged)
+        fb0, fb1, bases = flat(bounds)
+        with pytest.raises(ValueError, match="exceeds the kernel bound"):
+            psc.build_tile_tables_sets(sets, fb0, fb1, staged, bases=bases,
+                                       ladder=True)
+        with pytest.raises(plan_exec.PlanStructureMismatch):
+            _Staged(staged, fb0, fb1).harmonize(deferred(sets, bases))
+
+    @pytest.mark.parametrize("which", ["bmin", "bmax"])
+    def test_unsorted_run_is_a_plan_structure_mismatch(self, which):
+        rng = np.random.RandomState(17)
+        bounds, sets = [], []
+        for i in range(3):
+            bmin, bmax, runs, _ = block_runs(rng, 4000, 8, 500)
+            bounds.append((bmin.copy(), bmax.copy()))
+            sets.append([QueryLane(*runs[1], 1.0), QueryLane(*runs[4], 2.0)])
+        s, c = sets[2][1][:2]  # a dense run of the third pair
+        arr = bounds[2][0 if which == "bmin" else 1]
+        arr[s + 1], arr[s + 2] = arr[s + 2], arr[s + 1]
+        staged = tile_geometry(4096, 4)
+        with pytest.raises(ValueError, match="lane 1: per-block doc"):
+            oracle_tile_tables(sets[2], *bounds[2], staged)
+        with pytest.raises(plan_exec.PlanStructureMismatch):
+            oracle_harmonize(sets, bounds, staged)
+        bmin, bmax, bases = flat(bounds)
+        with pytest.raises(ValueError, match="set 2, lane 1: per-block doc "
+                                             "ranges not sorted"):
+            psc.build_tile_tables_sets(sets, bmin, bmax, staged, bases=bases,
+                                       ladder=True)
+        with pytest.raises(plan_exec.PlanStructureMismatch):
+            _Staged(staged, bmin, bmax).harmonize(deferred(sets, bases))
 
 
 class TestTileTableErrors:
@@ -536,3 +736,56 @@ class TestKernelPlaneExpandsNoBlock:
         assert blk["q_valid"].tolist() == (
             [True] * n_real + [False] * (len(blocks) - n_real))
         assert blk["kinds"] == ("bm25",)
+
+
+# ---------------------------------------------------------------------------
+# The counters of the one pass
+# ---------------------------------------------------------------------------
+
+# two shards of ~9,000 documents: each pads to 16,384 (one staged tile of
+# 128 sublanes), where "dense", in every document, needs ~71 blocks; at half
+# the tile the first 8,192 documents take exactly 64: one rung down
+DENSE_DOCS = 18_000
+
+
+@pytest.fixture(scope="module")
+def dense_index():
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("ES_TPU_PALLAS", "interpret")
+        idx = IndexService("lanes-dense", Settings({
+            "index.number_of_shards": 2, "index.refresh_interval": -1,
+            "index.translog.durability": "async",
+            "index.search.mesh": True}), mapping={"properties": {
+                "body": {"type": "text", "analyzer": "whitespace"}}})
+        idx._mesh_search = plan_exec.IndexMeshSearch(idx, mesh=shard_mesh(1))
+        for d in range(DENSE_DOCS):
+            idx.index_doc(str(d), {"body": "dense sparse" if d % 97 == 0
+                                   else "dense"})
+        idx.refresh()
+        yield idx
+        idx.close()
+
+
+class TestTablePassCounters:
+    @pytest.mark.parametrize("query,total,passes,rungs", [
+        ({"match": {"body": "sparse"}}, -(-DENSE_DOCS // 97), 1, 0),
+        ({"match": {"body": "dense"}}, DENSE_DOCS, 1, 1),
+        ({"bool": {"should": [{"match": {"body": "sparse"}},
+                              {"match": {"body": "dense"}}]}},
+         DENSE_DOCS, 2, 1),
+    ], ids=["sparse", "dense", "two_groups"])
+    def test_one_pass_a_group_and_the_rungs_it_descended(
+            self, dense_index, query, total, passes, rungs):
+        ms = dense_index._mesh_search
+        before = (ms.kernel_table_passes_total, ms.kernel_ladder_rungs_total)
+        got = dense_index.search({"query": query, "size": 3})
+        assert got["_plane"] == "mesh_pallas"
+        assert got["hits"]["total"] == total
+        assert (ms.kernel_table_passes_total - before[0],
+                ms.kernel_ladder_rungs_total - before[1]) == (passes, rungs)
+        planes = dense_index.search_stats()["planes"]
+        assert planes["kernel_table_passes_total"] == (
+            ms.kernel_table_passes_total)
+        assert planes["kernel_ladder_rungs_total"] == (
+            ms.kernel_ladder_rungs_total)
+
